@@ -265,8 +265,8 @@ def run() -> list[tuple[str, float, str]]:
     Pp = 1 + nbp
     ks = jax.random.split(key, 3)
     qc = jax.random.normal(ks[0], (Bc, Cc, Hc, dhc), jnp.float32)
-    kpool = jax.random.normal(ks[1], (Pp, bsp, Khc, dhc), jnp.float32)
-    vpool = jax.random.normal(ks[2], (Pp, bsp, Khc, dhc), jnp.float32)
+    kpool = jax.random.normal(ks[1], (Pp, Khc, bsp, dhc), jnp.float32)
+    vpool = jax.random.normal(ks[2], (Pp, Khc, bsp, dhc), jnp.float32)
     btp = jnp.arange(1, Pp, dtype=jnp.int32)[None, :]
     start0 = 3 * bsp  # chunk attends prior blocks + itself
 

@@ -13,16 +13,8 @@ cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-PYTEST_ARGS=()
-# Optional dep: property tests need hypothesis; skip the file when the
-# container doesn't ship it (matches the seed environment).
-if ! python -c "import hypothesis" >/dev/null 2>&1; then
-  echo "[verify] hypothesis not installed; skipping tests/test_properties.py"
-  PYTEST_ARGS+=("--ignore=tests/test_properties.py")
-fi
-
-echo "[verify] tier-1: python -m pytest -x -q ${PYTEST_ARGS[*]:-} $*"
-python -m pytest -x -q "${PYTEST_ARGS[@]}" "$@"
+echo "[verify] tier-1: python -m pytest -x -q $*"
+python -m pytest -x -q "$@"
 
 echo "[verify] dispatch parity on a forced 8-device CPU mesh"
 # The expert-parallel sorted dispatch (moe.ep="a2a", shard_map ragged

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ArchConfig, MoECfg
@@ -201,10 +200,10 @@ def sorted_dispatch_ep(
     in_specs.append(w_spec)
     weights.append(wo)
 
-    fn = shard_map(
-        local_fn, mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(token_axes, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(xg, tok, eid, w, *weights)

@@ -18,6 +18,8 @@ import dataclasses
 
 import numpy as np
 
+SUCCESSORS = 4  # likely successors per (cluster, token) row
+
 
 @dataclasses.dataclass(frozen=True)
 class ClusteredBigramTask:
@@ -26,23 +28,41 @@ class ClusteredBigramTask:
     concentration: float = 0.3  # lower => peakier (more learnable) bigrams
     seed: int = 1234
 
-    def tables(self) -> np.ndarray:
-        """(K, V, V) row-stochastic transition tables (deterministic)."""
+    def tables(self) -> tuple[np.ndarray, np.ndarray]:
+        """Sparse row-stochastic transition tables (deterministic):
+        ``(succ, prob)``, each (K, V, SUCCESSORS) — a row's successor
+        ids and their probabilities.
+
+        Peaky rows: each row keeps its top-SUCCESSORS tokens under iid
+        Gumbel logits / concentration, renormalized. Both are drawn
+        directly, in O(SUCCESSORS) per row: the kept ids are uniform
+        distinct ids, and the n largest of V iid Gumbels are -log of
+        the n smallest of V iid Exp(1), whose spacings are Exp(1) /
+        (V - j) (Rényi). A dense (K, V, V) table at a published vocab
+        (49k) would take ~150 GB.
+        """
         rng = np.random.Generator(np.random.Philox(self.seed))
-        V, K = self.vocab_size, self.n_clusters
-        # Peaky rows: each token has a handful of likely successors.
-        logits = rng.gumbel(size=(K, V, V)) * (1.0 / self.concentration)
-        # keep top-4 successors per row, renormalize
-        kth = np.partition(logits, -4, axis=-1)[..., -4:-3]
-        logits = np.where(logits >= kth, logits, -np.inf)
-        z = logits - logits.max(-1, keepdims=True)
-        p = np.exp(z)
-        return p / p.sum(-1, keepdims=True)
+        V, K, n = self.vocab_size, self.n_clusters, SUCCESSORS
+        rows = K * V
+        succ = np.empty((rows, n), np.int64)
+        for i in range(n):
+            # uniform over the V - i ids not yet taken: step past each
+            # taken id, in ascending order
+            x = rng.integers(0, V - i, size=rows)
+            for taken in np.sort(succ[:, :i], axis=1).T:
+                x += x >= taken
+            succ[:, i] = x
+        e = np.cumsum(rng.exponential(size=(rows, n)) / (V - np.arange(n)),
+                      axis=1)
+        logits = -np.log(e) / self.concentration  # descending per row
+        p = np.exp(logits - logits[:, :1])
+        p /= p.sum(-1, keepdims=True)
+        return succ.reshape(K, V, n), p.reshape(K, V, n)
 
     def sample(self, batch: int, seq_len: int, step: int,
                stream: int = 0) -> np.ndarray:
         """(batch, seq_len+1) token ids; column 0 encodes the cluster."""
-        tables = _cached_tables(self)
+        succ, prob = _cached_tables(self)
         rng = np.random.Generator(
             np.random.Philox(key=self.seed + 1,
                              counter=[0, 0, stream, step])
@@ -56,16 +76,16 @@ class ClusteredBigramTask:
         # vectorized ancestral sampling
         u = rng.random(size=(batch, seq_len))
         for t in range(1, seq_len):
-            rows = tables[clusters, toks[:, t]]  # (batch, V)
-            cdf = np.cumsum(rows, axis=-1)
-            toks[:, t + 1] = (u[:, t - 1, None] > cdf).sum(-1)
+            cdf = np.cumsum(prob[clusters, toks[:, t]], axis=-1)
+            j = np.minimum((u[:, t - 1, None] > cdf).sum(-1), SUCCESSORS - 1)
+            toks[:, t + 1] = succ[clusters, toks[:, t], j]
         return toks
 
 
 _TABLE_CACHE: dict = {}
 
 
-def _cached_tables(task: ClusteredBigramTask) -> np.ndarray:
+def _cached_tables(task: ClusteredBigramTask):
     key = (task.vocab_size, task.n_clusters, task.concentration, task.seed)
     if key not in _TABLE_CACHE:
         _TABLE_CACHE[key] = task.tables()

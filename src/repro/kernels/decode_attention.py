@@ -2,9 +2,11 @@
 
 The serving hot path: one query token per sequence slot attending over
 that slot's KV cache, which lives as fixed-size blocks scattered through
-a global pool ``(num_blocks, block_size, Kh, dh)`` (repro/serve paged KV
-cache). Each slot's blocks are named by a **block table** ``(B, nb)`` of
-pool block ids; sequences are ragged (per-slot ``lengths``), so dense
+a global pool ``(num_blocks, Kh, block_size, dh)`` (repro/serve paged KV
+cache; head-major so a block's ``(block_size, dh)`` slab is the trailing
+pair Mosaic tiles). Each slot's blocks are named by a **block table**
+``(B, nb)`` of pool block ids; sequences are ragged (per-slot
+``lengths``), so dense
 ``(B, max_len)`` cache reads would stream ``max_len`` bytes per slot no
 matter how short the sequence is.
 
@@ -18,7 +20,7 @@ no gather materialization, reads scale with ``ceil(length/bs)`` blocks.
 * grid ``(B, Kh, nb)``, block index innermost; the GQA query group
   ``(G, dh)`` with ``G = H // Kh`` rides along as the kernel tile.
 * online softmax over the block walk: running ``(m, l, acc)`` in VMEM
-  scratch (``(G,)``, ``(G,)``, ``(G, dh)`` f32), exactly the flash
+  scratch (``(G, 1)``, ``(G, 1)``, ``(G, dh)`` f32), exactly the flash
   forward residual structure; the output tile is written once at the
   last block step.
 * ragged lengths: blocks past ``ceil(length/bs)`` are **dead** — their
@@ -81,9 +83,9 @@ def _decode_kernel(bt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (G, dh)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bs, dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -93,16 +95,16 @@ def _decode_kernel(bt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
         mask = kv_pos < ln_ref[b]
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_acc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        m_prev = m_acc[...]  # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(mask, jnp.exp(s - m_safe[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(
             jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0
         )
         m_acc[...] = m_new
-        l_acc[...] = l_acc[...] * alpha + p.sum(axis=-1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+        l_acc[...] = l_acc[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -113,14 +115,14 @@ def _decode_kernel(bt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
         # Rows with no valid key (length 0) keep l == 0: emit zeros, the
         # continuous-batching engine never reads free slots' outputs.
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(
     q, k_pool, v_pool, block_tables, lengths, *, interpret: bool = False,
 ):
-    """q: (B, H, dh); k_pool/v_pool: (P, bs, Kh, dh) global block pools;
+    """q: (B, H, dh); k_pool/v_pool: (P, Kh, bs, dh) global block pools;
     block_tables: (B, nb) int32 pool block ids; lengths: (B,) int32 valid
     kv tokens per slot. Returns (B, H, dh) in q's dtype.
 
@@ -129,21 +131,11 @@ def paged_decode_attention_pallas(
     decode oracle.
     """
     B, H, dh = q.shape
-    P, bs, Kh, _ = k_pool.shape
+    P, Kh, bs, _ = k_pool.shape
     if H % Kh:
         raise ValueError(f"H ({H}) must be a multiple of Kh ({Kh})")
     G = H // Kh
     nb = block_tables.shape[1]
-    if not interpret and (dh % 128 or bs % 8):
-        # Same spirit as tiling.check_mxu_alignment: fail loudly instead
-        # of an opaque Mosaic lowering error. bs only needs the f32
-        # sublane floor (8) — the score tile (G, bs) is VPU work; dh is
-        # the MXU lane dim of both matmuls.
-        raise ValueError(
-            "compiled paged decode needs head_dim % 128 == 0 and "
-            f"block_size % 8 == 0; got dh={dh}, block_size={bs}. "
-            "Run interpret=True for CPU validation."
-        )
     qg = q.reshape(B, Kh, G, dh)
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
@@ -154,7 +146,7 @@ def paged_decode_attention_pallas(
         # pins to bt[b, 0], one fetch, compute skipped anyway).
         nlive = (ln[b] + bs - 1) // bs
         jj = jnp.minimum(j, jnp.maximum(nlive - 1, 0))
-        return (bt[b, jj], 0, kh, 0)
+        return (bt[b, jj], kh, 0, 0)
 
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -163,15 +155,15 @@ def paged_decode_attention_pallas(
             pl.BlockSpec(
                 (1, 1, G, dh), lambda b, kh, j, bt, ln: (b, kh, 0, 0)
             ),
-            pl.BlockSpec((1, bs, 1, dh), kv_map),
-            pl.BlockSpec((1, bs, 1, dh), kv_map),
+            pl.BlockSpec((1, 1, bs, dh), kv_map),
+            pl.BlockSpec((1, 1, bs, dh), kv_map),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, G, dh), lambda b, kh, j, bt, ln: (b, kh, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, dh), jnp.float32),
         ],
     )

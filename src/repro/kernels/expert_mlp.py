@@ -49,7 +49,10 @@ from repro.kernels.tiling import (
     check_mxu_alignment,
     clamp_tile,
     tune_expert_tiles,
+    VMEM_LIMIT_BYTES,
 )
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _act_fn(name: str):
@@ -189,6 +192,7 @@ def expert_ffn_pallas(
         out_shape=jax.ShapeDtypeStruct((E, capp, dp), xe.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     if pc or pd:
         out = out[:, :cap, :d]
@@ -400,6 +404,7 @@ def _expert_ffn_pallas_bwd(xe, wi, wg, wo, dy, *, act: str,
         out_shape=jax.ShapeDtypeStruct((E, capp, dp), xe.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
 
     # ---- dW: grid (E, nf, nc), cap innermost ----------------------------
@@ -462,6 +467,7 @@ def _expert_ffn_pallas_bwd(xe, wi, wg, wo, dy, *, act: str,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     if gated:
         dwi, dwg, dwo = dws

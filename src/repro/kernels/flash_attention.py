@@ -13,6 +13,11 @@ q(512*dh) + k/v(2*512*dh) + s/p(2*512*512*4B=2MB) + acc(512*dh*4B)
 
 GQA is handled in the index map: query head h reads kv head h // group.
 
+Layout: the callers' (B, S, H, dh) tensors are transposed to head-major
+(B, H, S, dh) around the kernels, so every block is (1, 1, tile, dh) with
+(tile, dh) as the trailing pair Mosaic tiles; the per-row residuals
+(lse, Δ) ride as (B, H, S, 1) columns for the same reason.
+
 Backward (``flash_attention_pallas_vjp``): the forward additionally
 returns the online-softmax log-sum-exp per query row (lse = m + log l),
 the only residual beyond the op's own inputs/outputs. Score tiles are
@@ -103,9 +108,9 @@ def _kernel(q_ref, k_ref, v_ref, qoff_ref, kvlen_ref, o_ref, lse_ref,
         l_acc[...] = jnp.zeros_like(l_acc)
         acc[...] = jnp.zeros_like(acc)
 
-    q = q_ref[0, :, 0, :]  # (bq, dh)
-    k = k_ref[0, :, 0, :]  # (bk, dh)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0, 0]  # (bq, dh)
+    k = k_ref[0, 0]  # (bk, dh)
+    v = v_ref[0, 0]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -115,15 +120,15 @@ def _kernel(q_ref, k_ref, v_ref, qoff_ref, kvlen_ref, o_ref, lse_ref,
                       bq=bq, bk=bk, causal=causal)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_acc[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_acc[...]  # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])
+    p = jnp.exp(s - m_safe)
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
     m_acc[...] = m_new
-    l_acc[...] = l_acc[...] * alpha + p.sum(axis=-1)
-    acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+    l_acc[...] = l_acc[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -139,7 +144,21 @@ def _kernel(q_ref, k_ref, v_ref, qoff_ref, kvlen_ref, o_ref, lse_ref,
                 l > 0.0, m_acc[...] + jnp.log(l), jnp.inf
             )
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
+
+
+def _heads_major(x, pad):
+    """(B, S, H, dh) -> (B, H, S + pad, dh): the kernels' block layout.
+    Mosaic tiles the last two block dims, so (seq tile, dh) must be the
+    trailing pair — a (1, bq, 1, dh) block on (B, S, H, dh) is refused."""
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    return x.transpose(0, 2, 1, 3)
+
+
+def _seq_major(x, n):
+    """Inverse of :func:`_heads_major`, dropping the padded rows."""
+    return x.transpose(0, 2, 1, 3)[:, :n]
 
 
 @functools.partial(
@@ -163,29 +182,29 @@ def flash_attention_pallas(
     G = H // Kh
     bq, bk = _clamp_qk_tiles(bq, bk, Sq, Skv, dh, interpret)
     pq, pk = (-Sq) % bq, (-Skv) % bk
-    if pq:
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    Sqp, Skvp = Sq + pq, Skv + pk
-    nq, nk = Sqp // bq, Skvp // bk
+    qh = _heads_major(q, pq)
+    kh, vh = _heads_major(k, pk), _heads_major(v, pk)
+    nq, nk = qh.shape[2] // bq, kh.shape[2] // bk
     if kv_len is None:
         kv_len = Skv
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
     q_offset = jnp.asarray(q_offset, jnp.int32).reshape(1)
 
     grid = (B, H, nq, nk)
-    out_specs = pl.BlockSpec((1, bq, 1, dh), lambda b, h, qi, ki: (b, qi, h, 0))
-    out_shape = jax.ShapeDtypeStruct((B, Sqp, H, dh), q.dtype)
+    q_spec = pl.BlockSpec((1, 1, bq, dh), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, dh), lambda b, h, qi, ki: (b, h // G, ki, 0)
+    )
+    out_specs = q_spec
+    out_shape = jax.ShapeDtypeStruct(qh.shape, q.dtype)
     if return_residuals:
         out_specs = [
             out_specs,
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ]
         out_shape = [
             out_shape,
-            jax.ShapeDtypeStruct((B, H, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct(qh.shape[:3] + (1,), jnp.float32),
         ]
 
     def kernel(*refs):
@@ -204,33 +223,23 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec(
-                (1, bk, 1, dh), lambda b, h, qi, ki: (b, ki, h // G, 0)
-            ),
-            pl.BlockSpec(
-                (1, bk, 1, dh), lambda b, h, qi, ki: (b, ki, h // G, 0)
-            ),
+            q_spec, kv_spec, kv_spec,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, q_offset, kv_len)
+    )(qh, kh, vh, q_offset, kv_len)
     if return_residuals:
         out, lse = out
-        if pq:
-            out = out[:, :Sq]
-        return out, lse
-    if pq:
-        out = out[:, :Sq]
-    return out
+        return _seq_major(out, Sq), lse[..., 0]
+    return _seq_major(out, Sq)
 
 
 # ---------------------------------------------------------------------------
@@ -238,18 +247,19 @@ def flash_attention_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p_ds(q, k, v, do, lse_row, delta_row, mask, scale):
-    """Recompute one (bq, bk) probability tile and its score gradient."""
+def _recompute_p_ds(q, k, v, do, lse_col, delta_col, mask, scale):
+    """Recompute one (bq, bk) probability tile and its score gradient;
+    ``lse_col`` / ``delta_col`` are the tile's (bq, 1) row residuals."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
-    p = jnp.where(mask, jnp.exp(s - lse_row[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_col), 0.0)
     dp = jax.lax.dot_general(  # do @ v^T
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta_row[:, None])
+    ds = p * (dp - delta_col)
     return p, ds
 
 
@@ -268,9 +278,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         mask = _tile_mask(qoff_ref, kvlen_ref, qi, ki,
                           bq=bq, bk=bk, causal=causal)
-        k = k_ref[0, :, 0, :]
+        k = k_ref[0, 0]
         _, ds = _recompute_p_ds(
-            q_ref[0, :, 0, :], k, v_ref[0, :, 0, :], do_ref[0, :, 0, :],
+            q_ref[0, 0], k, v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], mask, scale,
         )
         dq_acc[...] += jnp.dot(
@@ -279,7 +289,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -300,10 +310,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         mask = _tile_mask(qoff_ref, kvlen_ref, qi, ki,
                           bq=bq, bk=bk, causal=causal)
-        q = q_ref[0, :, 0, :]
-        do = do_ref[0, :, 0, :]
+        q = q_ref[0, 0]
+        do = do_ref[0, 0]
         p, ds = _recompute_p_ds(
-            q, k_ref[0, :, 0, :], v_ref[0, :, 0, :], do,
+            q, k_ref[0, 0], v_ref[0, 0], do,
             lse_ref[0, 0], delta_ref[0, 0], mask, scale,
         )
         pT_dot = functools.partial(  # tile^T @ rows -> (bk, dh)
@@ -316,8 +326,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(t == ng * nq - 1)
     def _():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(
@@ -337,102 +347,70 @@ def _flash_attention_pallas_bwd(
     bq, bk = _clamp_qk_tiles(bq, bk, Sq, Skv, dh, interpret)
     pq, pk = (-Sq) % bq, (-Skv) % bk
 
-    # Δ = rowsum(dO * O): elementwise, done outside the kernels.
-    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    delta = delta.transpose(0, 2, 1)  # (B, H, Sq)
-    if pq:
-        # Padded q rows carry dO == 0, so Δ == 0 and every tile they touch
-        # contributes zero to dk/dv; their dq rows are sliced off below.
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-        do = jnp.pad(do, ((0, 0), (0, pq), (0, 0), (0, 0)))
-        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pq)))
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    Sqp, Skvp = Sq + pq, Skv + pk
-    nq, nk = Sqp // bq, Skvp // bk
+    # Δ = rowsum(dO * O): elementwise, done outside the kernels. Padded q
+    # rows carry dO == 0, so Δ == 0 and every tile they touch contributes
+    # zero to dk/dv; their dq rows are sliced off below.
+    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(
+        -1, keepdims=True
+    )  # (B, Sq, H, 1)
+    delta = _heads_major(delta, pq)  # (B, H, Sqp, 1)
+    qh, doh = _heads_major(q, pq), _heads_major(do, pq)
+    kh, vh = _heads_major(k, pk), _heads_major(v, pk)
+    lse = lse[..., None]  # (B, H, Sqp, 1)
+    nq, nk = qh.shape[2] // bq, kh.shape[2] // bk
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
     q_offset = jnp.asarray(q_offset, jnp.int32).reshape(1)
+    smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
 
-    row_specs = [  # q-row-indexed inputs, shared by both kernels
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
-
-    dq = pl.pallas_call(
+    q_rows = pl.BlockSpec((1, 1, bq, dh), lambda b, h, qi, ki: (b, h, qi, 0))
+    q_col = pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_rows = pl.BlockSpec(
+        (1, 1, bk, dh), lambda b, h, qi, ki: (b, h // G, ki, 0)
+    )
+    dqh = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk
         ),
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec(
-                (1, bk, 1, dh), lambda b, h, qi, ki: (b, ki, h // G, 0)
-            ),
-            pl.BlockSpec(
-                (1, bk, 1, dh), lambda b, h, qi, ki: (b, ki, h // G, 0)
-            ),
-            pl.BlockSpec((1, bq, 1, dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-        ] + row_specs,
-        out_specs=pl.BlockSpec(
-            (1, bq, 1, dh), lambda b, h, qi, ki: (b, qi, h, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Sqp, H, dh), q.dtype),
+        in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_col, q_col] + smem,
+        out_specs=q_rows,
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta, q_offset, kv_len)
+    )(qh, kh, vh, doh, lse, delta, q_offset, kv_len)
 
     # dk/dv: the last grid axis sweeps (group member g, q tile qi); the
     # index maps translate t -> (query head kh*G + g, row tile qi).
     h_of = lambda kh, t, G=G, nq=nq: kh * G + t // nq
     qi_of = lambda t, nq=nq: jax.lax.rem(t, nq)
-    dk, dv = pl.pallas_call(
+    g_rows = pl.BlockSpec(
+        (1, 1, bq, dh), lambda b, kh, ki, t: (b, h_of(kh, t), qi_of(t), 0)
+    )
+    g_col = pl.BlockSpec(
+        (1, 1, bq, 1), lambda b, kh, ki, t: (b, h_of(kh, t), qi_of(t), 0)
+    )
+    own_rows = pl.BlockSpec(
+        (1, 1, bk, dh), lambda b, kh, ki, t: (b, kh, ki, 0)
+    )
+    dkh, dvh = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
             nq=nq, ng=G,
         ),
         grid=(B, Kh, nk, G * nq),
-        in_specs=[
-            pl.BlockSpec(
-                (1, bq, 1, dh),
-                lambda b, kh, ki, t: (b, qi_of(t), h_of(kh, t), 0),
-            ),
-            pl.BlockSpec((1, bk, 1, dh), lambda b, kh, ki, t: (b, ki, kh, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda b, kh, ki, t: (b, ki, kh, 0)),
-            pl.BlockSpec(
-                (1, bq, 1, dh),
-                lambda b, kh, ki, t: (b, qi_of(t), h_of(kh, t), 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bq), lambda b, kh, ki, t: (b, h_of(kh, t), qi_of(t))
-            ),
-            pl.BlockSpec(
-                (1, 1, bq), lambda b, kh, ki, t: (b, h_of(kh, t), qi_of(t))
-            ),
-        ] + row_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, 1, dh), lambda b, kh, ki, t: (b, ki, kh, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda b, kh, ki, t: (b, ki, kh, 0)),
-        ],
+        in_specs=[g_rows, own_rows, own_rows, g_rows, g_col, g_col] + smem,
+        out_specs=[own_rows, own_rows],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Skvp, Kh, dh), k.dtype),
-            jax.ShapeDtypeStruct((B, Skvp, Kh, dh), v.dtype),
+            jax.ShapeDtypeStruct(kh.shape, k.dtype),
+            jax.ShapeDtypeStruct(vh.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dh), jnp.float32),
             pltpu.VMEM((bk, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta, q_offset, kv_len)
-
-    if pq:
-        dq = dq[:, :Sq]
-    if pk:
-        dk = dk[:, :Skv]
-        dv = dv[:, :Skv]
-    return dq, dk, dv
+    )(qh, kh, vh, doh, lse, delta, q_offset, kv_len)
+    return _seq_major(dqh, Sq), _seq_major(dkh, Skv), _seq_major(dvh, Skv)
 
 
 @functools.lru_cache(maxsize=None)

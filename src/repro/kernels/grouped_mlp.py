@@ -83,7 +83,10 @@ from repro.kernels.tiling import (
     check_mxu_alignment,
     clamp_tile,
     tune_expert_tiles,
+    VMEM_LIMIT_BYTES,
 )
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _act_fn(name: str):
@@ -392,6 +395,7 @@ def _grouped_mlp_pallas_tables(
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((G, M, dp), xs.dtype),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(be, bl, pl_tbl, *args)
     if pd:
         out = out[:, :, :d]
@@ -625,6 +629,7 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((G, M, dp), xs.dtype),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(be, bl, pl_tbl, *args)
 
     # ---- dW: grid (G, nf, nb), row-blocks innermost --------------------
@@ -715,6 +720,7 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
         grid_spec=gs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(be, bl, pl_tbl, *args)
     if gated:
         dwi_pg, dwg_pg, dwo_pg = dws

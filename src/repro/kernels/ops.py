@@ -5,7 +5,10 @@ Every op takes ``implementation``:
 * ``"xla"``     — jnp einsum / chunked-scan path. Used on CPU, for dry-run
                   lowering, and as the production fallback.
 * ``"pallas"``  — the Pallas TPU kernel (pl.pallas_call with BlockSpec VMEM
-                  tiling). On CPU it runs in interpret mode for validation.
+                  tiling). Compiled on TPU; on CPU it runs in interpret
+                  mode for validation; any other backend raises. The
+                  mode is decided per call (``_interpret``), never at
+                  import, so importing this module touches no device.
                   Grad-enabled: expert FFN and flash attention route
                   through ``jax.custom_vjp`` wrappers whose backward passes
                   are themselves fused Pallas kernels, so ``jax.grad``
@@ -24,7 +27,20 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
-INTERPRET_DEFAULT = jax.default_backend() == "cpu"
+
+def _interpret() -> bool:
+    """Pallas mode for this call: compiled on TPU, interpreted on CPU
+    (validation only). Any other backend has no Pallas TPU kernels, and
+    interpreting there would hide the device behind a slow emulation."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"implementation='pallas' needs a TPU (or the CPU interpreter); "
+        f"the default backend is {backend!r} — use 'xla'"
+    )
 
 
 def default_implementation() -> str:
@@ -53,7 +69,7 @@ def expert_ffn(xe, wi, wg, wo, *, act: str = "silu", implementation="xla"):
         G, E, cap, d = xe.shape
         y = jax.vmap(
             lambda x: expert_mlp.expert_ffn_pallas_vjp(
-                x, wi, wg, wo, act=act, interpret=INTERPRET_DEFAULT
+                x, wi, wg, wo, act=act, interpret=_interpret()
             )
         )(xe)
         return y[0] if squeeze else y
@@ -95,7 +111,7 @@ def grouped_mlp(xs, wi, wg, wo, group_sizes, *, act: str = "silu",
 
         return gm.grouped_mlp_pallas_vjp(
             xs, wi, wg, wo, group_sizes, act=act, bm=block,
-            interpret=INTERPRET_DEFAULT,
+            interpret=_interpret(),
         )
     return _grouped_mlp_xla(xs, wi, wg, wo, group_sizes, act=act,
                             block=block)
@@ -136,7 +152,7 @@ def flash_attention(
 
         return fa.flash_attention_pallas_vjp(
             q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-            interpret=INTERPRET_DEFAULT,
+            interpret=_interpret(),
         )
     from repro.models.attention import flash_attention as fa_xla
 
@@ -152,7 +168,7 @@ def decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     the continuous-batching serving hot path (repro/serve).
 
     q: (B, 1, H, dh) one query token per sequence slot;
-    k_pool/v_pool: (P, bs, Kh, dh) global KV block pools;
+    k_pool/v_pool: (P, Kh, bs, dh) global KV block pools;
     block_tables: (B, nb) int32 pool block ids per slot;
     lengths: (B,) int32 valid kv tokens per slot (0 = free slot ->
     exact-zero output).
@@ -173,16 +189,24 @@ def decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
         y = da.paged_decode_attention_pallas(
             q[:, 0], k_pool, v_pool, block_tables, lengths,
-            interpret=INTERPRET_DEFAULT,
+            interpret=_interpret(),
         )
         return y[:, None]
     from repro.models.attention import _decode_attention
 
+    return _decode_attention(
+        q, _gather_blocks(k_pool, block_tables),
+        _gather_blocks(v_pool, block_tables), lengths,
+    )
+
+
+def _gather_blocks(pool, block_tables):
+    """Dense ``(B, nb*bs, Kh, dh)`` view of each row's blocks of a
+    head-major ``(P, Kh, bs, dh)`` pool — the XLA fallbacks' read."""
     B, nb = block_tables.shape
-    bs = k_pool.shape[1]
-    k = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
-    v = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
-    return _decode_attention(q, k, v, lengths)
+    _, Kh, bs, dh = pool.shape
+    blocks = pool[block_tables]  # (B, nb, Kh, bs, dh)
+    return blocks.transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, Kh, dh)
 
 
 def prefill_attention(q, k_pool, v_pool, block_tables, starts, lens, *,
@@ -191,7 +215,7 @@ def prefill_attention(q, k_pool, v_pool, block_tables, starts, lens, *,
     — the prefill lane of the mixed serve step (repro/serve).
 
     q: (NC, C, H, dh) — NC chunks of C consecutive prompt tokens, one
-    request each; k_pool/v_pool: (P, bs, Kh, dh) global KV block pools
+    request each; k_pool/v_pool: (P, Kh, bs, dh) global KV block pools
     with the chunk's own k/v ALREADY written (the mixed step writes both
     lanes through one scatter before attention); block_tables: (NC, nb)
     int32 pool block ids of each chunk's slot; starts: (NC,) int32
@@ -216,21 +240,20 @@ def prefill_attention(q, k_pool, v_pool, block_tables, starts, lens, *,
 
         return pp.paged_prefill_attention_pallas(
             q, k_pool, v_pool, block_tables, starts, lens,
-            interpret=INTERPRET_DEFAULT,
+            interpret=_interpret(),
         )
     NC, C, H, dh = q.shape
-    bs, Kh = k_pool.shape[1], k_pool.shape[2]
-    nb = block_tables.shape[1]
+    Kh = k_pool.shape[1]
     G = H // Kh
-    k = k_pool[block_tables].reshape(NC, nb * bs, Kh, dh)
-    v = v_pool[block_tables].reshape(NC, nb * bs, Kh, dh)
+    k = _gather_blocks(k_pool, block_tables)
+    v = _gather_blocks(v_pool, block_tables)
     qg = q.reshape(NC, C, Kh, G, dh)
     s = jnp.einsum(
         "bqkgd,btkd->bkgqt", qg, k, preferred_element_type=jnp.float32
     ) * dh ** -0.5
     q_pos = starts[:, None] + jnp.arange(C)[None, :]          # (NC, C)
     valid_q = jnp.arange(C)[None, :] < lens[:, None]
-    kv_pos = jnp.arange(nb * bs)
+    kv_pos = jnp.arange(k.shape[1])
     mask = (
         valid_q[:, :, None]
         & (kv_pos[None, None, :] <= q_pos[:, :, None])
@@ -283,7 +306,7 @@ def rwkv6(r, k, v, w, u, *, initial_state=None, chunk=64,
 
         return rwkv6_kernel.rwkv6_pallas(
             r, k, v, w, u, initial_state=initial_state, chunk=chunk,
-            interpret=INTERPRET_DEFAULT,
+            interpret=_interpret(),
         )
     return _rwkv6_chunked_xla(
         r, k, v, w, u, initial_state=initial_state, chunk=chunk

@@ -14,25 +14,22 @@ over DCN. Axes:
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-
-    _MESH_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: every axis is Auto already
-    AxisType = None
-    _MESH_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_MESH_KW(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU multi-device tests."""
-    return jax.make_mesh(shape, axes, **_MESH_KW(len(axes)))
+    """Small mesh: CPU multi-device tests and the 2x2 chip mesh."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def ep_degree(mesh) -> int:

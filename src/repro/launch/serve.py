@@ -55,6 +55,8 @@ tick-phase spans, scheduler counters, end-of-run histogram summaries —
 metric reference in ``src/repro/obs/README.md``) to PATH in solo and
 fleet mode alike; ``--jax-profile`` wraps every jitted mixed step in a
 ``jax.profiler`` step annotation so device traces line up with ticks.
+The persistent compile cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, else to ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -156,6 +158,7 @@ def main() -> None:
         ap.error("--fleet needs --paged with --admission chunked")
 
     from repro.configs import get_config, get_reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model_zoo as zoo
     from repro.models import param as pm
     from repro.obs import JsonlSink, Tracker
@@ -170,6 +173,7 @@ def main() -> None:
         ServeEngine,
     )
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
 
     # ONE manager for the whole process: restart_factory restores

@@ -3,10 +3,12 @@
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --reduced --steps 200 --ckpt-dir artifacts/run1 [--upcycle-from DIR]
 
-On a real cluster this process runs once per host (jax.distributed
-initialization via the standard env vars); the data iterator shards by
-host and the mesh shards by device automatically. Auto-resumes from the
-newest valid checkpoint in --ckpt-dir.
+The step runs on one device: this launcher builds no mesh (the Trainer
+gets ``ctx=None``). A sharded step over several chips takes a
+``ShardCtx`` mesh, as ``chip_smoke.py --chips 4`` builds one.
+Auto-resumes from the newest valid checkpoint in --ckpt-dir. The
+persistent compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says,
+else to ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -93,11 +95,13 @@ def main() -> None:
 
     from repro.configs import get_config, get_reduced
     from repro.data import make_iterator
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model_zoo as zoo
     from repro.optim import adafactor, inverse_sqrt
     from repro.training import TrainConfig, Trainer
     from repro.training.train_loop import PreemptionSignal
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if cfg.moe is not None and args.ep != "none":
         import dataclasses

@@ -12,6 +12,7 @@ Shapes: q (B, Sq, H, dh); k, v (B, Skv, Kh, dh) with H % Kh == 0 (GQA).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -62,9 +63,12 @@ def attention_init(rng, cfg: ArchConfig, *, dtype=jnp.float32):
     d, h, kh, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ks = jax.random.split(rng, 4)
     p = {
-        "wq": pm.dense(ks[0], (d, h, dh), "embed heads head_dim", dtype=dtype),
-        "wk": pm.dense(ks[1], (d, kh, dh), "embed kv_heads head_dim", dtype=dtype),
-        "wv": pm.dense(ks[2], (d, kh, dh), "embed kv_heads head_dim", dtype=dtype),
+        "wq": pm.dense(ks[0], (d, h, dh), "embed heads head_dim", dtype=dtype,
+                       fan_in=d),
+        "wk": pm.dense(ks[1], (d, kh, dh), "embed kv_heads head_dim",
+                       dtype=dtype, fan_in=d),
+        "wv": pm.dense(ks[2], (d, kh, dh), "embed kv_heads head_dim",
+                       dtype=dtype, fan_in=d),
         "wo": pm.dense(
             ks[3], (h, dh, d), "heads head_dim embed", dtype=dtype,
             fan_in=h * dh,
@@ -225,7 +229,7 @@ def attention_apply(
     written. kv_x: encoder states for cross-attention (no cache/causality).
 
     block_tables: None, or (B, nb) int32 — switches the cache to the
-    PAGED layout {k: (P, bs, Kh, dh), v: ...} (a global block pool,
+    PAGED layout {k: (P, Kh, bs, dh), v: ...} (a global block pool,
     repro/serve): ``cache_index`` becomes the per-slot (B,) int32 length
     vector. Prefill (Sq > 1, one request at a time) writes the prompt's
     k/v into the slot's blocks and attends over the local fresh k/v;
@@ -441,17 +445,52 @@ def attention_apply(
     else:
         from repro.kernels import ops
 
-        y = ops.flash_attention(
-            q, k, v,
+        attend = functools.partial(
+            ops.flash_attention,
             causal=causal and kv_x is None,
-            q_offset=q_offset,
-            kv_len=kv_len,
             q_chunk=q_chunk,
             kv_chunk=kv_chunk,
             implementation=implementation,
         )
+        if (ops._resolve(implementation) == "pallas" and ctx is not None
+                and ctx.mesh.devices.size > 1):
+            y = _attend_per_shard(attend, q, k, v, q_offset, kv_len, ctx)
+        else:
+            y = attend(q, k, v, q_offset=q_offset, kv_len=kv_len)
     out = jnp.einsum("bshk,hkd->bsd", y, wo)
     return out, cache
+
+
+def _attend_per_shard(attend, q, k, v, q_offset, kv_len, ctx):
+    """Run a Pallas attention kernel once per shard of ``ctx.mesh``:
+    GSPMD cannot partition a Mosaic kernel. The batch shards as the
+    activation rules say; heads shard only where the query and the KV
+    heads take the same mesh axis, so every shard holds whole GQA
+    groups (local head h still reads local kv head h // G)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding import spec_for
+
+    mesh, rules = ctx.mesh, ctx.act_rules
+
+    def spec(logical, shape):
+        s = tuple(spec_for(logical, shape, mesh, rules))
+        return s + (None,) * (len(shape) - len(s))
+
+    qs = spec("batch seq heads head_dim", q.shape)
+    ks = spec("batch seq kv_heads head_dim", k.shape)
+    if qs[2] != ks[2]:
+        qs, ks = qs[:2] + (None, None), ks[:2] + (None, None)
+    kv_len = jnp.asarray(k.shape[1] if kv_len is None else kv_len,
+                         jnp.int32)
+    fn = jax.shard_map(
+        lambda q, k, v, o, n: attend(q, k, v, q_offset=o, kv_len=n),
+        mesh=mesh,
+        in_specs=(P(*qs), P(*ks), P(*ks), P(), P()),
+        out_specs=P(*qs),
+        check_vma=False,
+    )
+    return fn(q, k, v, jnp.asarray(q_offset, jnp.int32), kv_len)
 
 
 def _decode_attention(q, k, v, kv_len):
@@ -506,8 +545,12 @@ def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int, *,
     """Global KV block pool replacing the dense (B, max_len, ...) cache:
     fixed-size blocks owned by sequence slots via per-slot block tables
     (allocated/freed by repro.serve.BlockPool). Block 0 is the trash
-    block free slots write into."""
-    shape = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    block free slots write into.
+
+    Head-major ``(num_blocks, Kh, block_size, dh)``: the Pallas paged
+    kernels read one ``(block_size, dh)`` slab per (block, kv head), and
+    Mosaic tiles only a block's two trailing dims."""
+    shape = (num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
@@ -517,12 +560,12 @@ def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int, *,
 def paged_prefill_write(pool, kv, block_table):
     """Write a full prompt's k or v into its slot's blocks.
 
-    pool: (P, bs, Kh, dh); kv: (1, S, Kh, dh) with S % bs == 0 (the
+    pool: (P, Kh, bs, dh); kv: (1, S, Kh, dh) with S % bs == 0 (the
     serve engine buckets prompt lengths to block multiples — padded tail
     positions carry garbage that stays masked by the slot length until
     decode overwrites it); block_table: (1, nb), nb >= S // bs.
     """
-    bs = pool.shape[1]
+    bs = pool.shape[2]
     S = kv.shape[1]
     if S % bs:
         raise ValueError(
@@ -530,25 +573,22 @@ def paged_prefill_write(pool, kv, block_table):
             f"block size ({bs}); bucket the prompt before prefill"
         )
     nbu = S // bs
-    blocks = kv[0].reshape(nbu, bs, *kv.shape[2:]).astype(pool.dtype)
-    return pool.at[block_table[0, :nbu]].set(blocks)
+    blocks = kv[0].reshape(nbu, bs, *kv.shape[2:]).transpose(0, 2, 1, 3)
+    return pool.at[block_table[0, :nbu]].set(blocks.astype(pool.dtype))
 
 
 def paged_decode_write(pool, kv, block_tables, lengths):
     """Scatter one decode token's k or v per slot into the pool.
 
-    pool: (P, bs, Kh, dh); kv: (B, 1, Kh, dh); block_tables: (B, nb);
+    pool: (P, Kh, bs, dh); kv: (B, 1, Kh, dh); block_tables: (B, nb);
     lengths: (B,) write position per slot (the token count already
     cached). Free slots (length 0, all-zero table rows) land in trash
     block 0 — never read.
     """
-    P, bs = pool.shape[:2]
+    bs = pool.shape[2]
     blk = lengths // bs
-    off = lengths % bs
     bids = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    flat = pool.reshape(P * bs, *pool.shape[2:])
-    flat = flat.at[bids * bs + off].set(kv[:, 0].astype(pool.dtype))
-    return flat.reshape(pool.shape)
+    return _scatter_rows(pool, kv[:, 0], bids, lengths % bs)
 
 
 def paged_row_write(pool, kv, row_tables, positions, live):
@@ -556,7 +596,7 @@ def paged_row_write(pool, kv, row_tables, positions, live):
     — the single cache-write path of the mixed serve step (decode rows
     AND chunk rows go through this one scatter).
 
-    pool: (P, bs, Kh, dh); kv: (R, 1, Kh, dh); row_tables: (R, nb) each
+    pool: (P, Kh, bs, dh); kv: (R, 1, Kh, dh); row_tables: (R, nb) each
     row's slot block table; positions: (R,) absolute token position to
     write; live: (R,) bool — dead rows (free slots, padded chunk rows,
     idle chunk lanes) land in trash block 0, which is never read.
@@ -564,15 +604,23 @@ def paged_row_write(pool, kv, row_tables, positions, live):
     position runs past the slot's allocation stay in bounds (they are
     dead and routed to trash anyway).
     """
-    P, bs = pool.shape[:2]
+    bs = pool.shape[2]
     nb = row_tables.shape[1]
     blk = jnp.clip(positions // bs, 0, nb - 1)
     bids = jnp.take_along_axis(row_tables, blk[:, None], axis=1)[:, 0]
     bids = jnp.where(live, bids, 0)
     off = jnp.where(live, positions % bs, 0)
-    flat = pool.reshape(P * bs, *pool.shape[2:])
-    flat = flat.at[bids * bs + off].set(kv[:, 0].astype(pool.dtype))
-    return flat.reshape(pool.shape)
+    return _scatter_rows(pool, kv[:, 0], bids, off)
+
+
+def _scatter_rows(pool, rows, bids, offs):
+    """Write token rows (R, Kh, dh) into the head-major pool (P, Kh, bs,
+    dh) at (block ``bids[r]``, offset ``offs[r]``) — one scatter over
+    every kv head, in place under buffer donation (no pool transpose)."""
+    kh = jnp.arange(pool.shape[1])
+    return pool.at[bids[:, None], kh[None, :], offs[:, None]].set(
+        rows.astype(pool.dtype)
+    )
 
 
 CACHE_AXES = {"k": "batch cache_seq kv_heads head_dim",

@@ -70,7 +70,10 @@ def validate(values, axes) -> None:
 
 
 def dense(rng, shape, axes: str, *, dtype=jnp.float32, fan_in: int | None = None):
-    """Truncated-normal fan-in init (lecun_normal-style)."""
+    """Truncated-normal fan-in init (lecun_normal-style). ``fan_in``
+    defaults to ``shape[-2]``, the input dim of ``(d_in, d_out)`` and
+    ``(E, d_in, d_out)``; pass it where the input dim is elsewhere, as
+    in a ``(d, heads, head_dim)`` projection."""
     if fan_in is None:
         fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     std = 1.0 / math.sqrt(max(fan_in, 1))

@@ -42,10 +42,11 @@ def time_mix_init(rng, cfg: ArchConfig, *, dtype=jnp.float32):
         "w_lora_a": pm.normal(ks[0], (d, LORA_DIM), "embed _", std=0.02,
                               dtype=dtype),
         "w_lora_b": pm.zeros((LORA_DIM, d), "_ embed", dtype=dtype),
-        "wr": pm.dense(ks[1], (d, H, K), "embed heads head_dim", dtype=dtype),
-        "wk": pm.dense(ks[2], (d, H, K), "embed heads head_dim", dtype=dtype),
-        "wv": pm.dense(ks[3], (d, H, K), "embed heads head_dim", dtype=dtype),
-        "wg": pm.dense(ks[4], (d, H, K), "embed heads head_dim", dtype=dtype),
+        **{
+            name: pm.dense(k, (d, H, K), "embed heads head_dim", fan_in=d,
+                           dtype=dtype)
+            for name, k in zip(("wr", "wk", "wv", "wg"), ks[1:5])
+        },
         "u": pm.normal(ks[5], (H, K), "heads head_dim", std=0.02,
                        dtype=dtype),
         "wo": pm.dense(ks[6], (H, K, d), "heads head_dim embed",

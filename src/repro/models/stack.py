@@ -279,21 +279,30 @@ def layer_apply(
 
 
 def stack_init(rng, cfg: ArchConfig, descs, *, dtype=jnp.float32):
+    """Layer ``l`` draws from ``fold_in(rng, l)``. Each segment position
+    inits all its repeats under one ``vmap`` (the same values as one
+    call per layer): a per-layer loop puts every layer's RNG ops into
+    the program, and the TPU compile of a 24-layer init grows to a
+    minute."""
     segs = find_segments(descs)
     out = []
     layer = 0
     for reps, pdescs in segs:
-        per_pos = {f"pos{i}": [] for i in range(len(pdescs))}
-        for _ in range(reps):
-            for i, d in enumerate(pdescs):
-                per_pos[f"pos{i}"].append(
-                    layer_init(jax.random.fold_in(rng, layer), cfg, d,
-                               dtype=dtype)
-                )
-                layer += 1
-        out.append(
-            {k: pm.stack_layers(v) for k, v in per_pos.items()}
-        )
+        n = len(pdescs)
+        seg = {}
+        for i, d in enumerate(pdescs):
+            keys = jax.vmap(lambda l: jax.random.fold_in(rng, l))(
+                layer + i + n * jnp.arange(reps)
+            )
+            stacked = jax.vmap(
+                lambda k, d=d: layer_init(k, cfg, d, dtype=dtype)
+            )(keys)
+            seg[f"pos{i}"] = jax.tree.map(
+                lambda p: pm.Param(p.value, ("layer " + p.axes).strip()),
+                stacked, is_leaf=lambda x: isinstance(x, pm.Param),
+            )
+        out.append(seg)
+        layer += reps * n
     return {"segments": out}
 
 

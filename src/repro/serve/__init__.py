@@ -13,8 +13,8 @@ component      role
 slots          fixed decode-batch positions (``max_batch`` of them);
                a slot is FREE or ACTIVE (one request), evicted the
                step its request finishes (scheduler.py)
-block pool     global per-layer KV tensors ``(num_blocks, block_size,
-               Kh, dh)`` + a host-side refcounted free list; block 0
+block pool     global per-layer KV tensors ``(num_blocks, Kh,
+               block_size, dh)`` + a host-side refcounted free list; block 0
                is the reserved trash block dead rows write into
                (paged_cache.py, models/attention.init_paged_cache)
 block tables   per-slot ``(nb,)`` int32 maps slot positions ->

@@ -283,7 +283,7 @@ class ServeEngine:
                     # Copy one pool block across every layer (the
                     # prefix cache's copy-on-write for partial tail
                     # blocks). Pool leaves carry a leading layer-stack
-                    # dim: (reps, P, bs, Kh, dh).
+                    # dim: (reps, P, Kh, bs, dh).
                     return jax.tree.map(
                         lambda p: p.at[:, dst].set(p[:, src]), cache
                     )

@@ -1,8 +1,8 @@
 """Host-side KV block pool: refcounted allocator + content-hash prefix
 index behind the paged serve cache.
 
-The device side is a per-layer global pool ``(num_blocks, block_size,
-Kh, dh)`` (``models/attention.init_paged_cache``); this module owns the
+The device side is a per-layer global pool ``(num_blocks, Kh,
+block_size, dh)`` (``models/attention.init_paged_cache``); this module owns the
 *bookkeeping*: which blocks are free, which sequence owns which blocks,
 and which blocks hold known prompt-prefix content. Blocks are allocated
 atomically on request admission and freed on completion — the

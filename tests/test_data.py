@@ -3,7 +3,7 @@ import numpy as np
 
 from repro.configs import get_reduced
 from repro.data import ClusteredBigramTask, lm_batch, make_iterator
-from repro.data.synthetic import frame_batch, patch_batch, \
+from repro.data.synthetic import SUCCESSORS, frame_batch, patch_batch, \
     span_corruption_batch
 
 
@@ -16,13 +16,55 @@ def test_determinism_and_no_step_overlap():
     assert not np.array_equal(b1["tokens"], b3["tokens"])
 
 
+def _dense(task):
+    """(K, V, V) transition matrices of the sparse tables."""
+    succ, p = task.tables()
+    K, V = task.n_clusters, task.vocab_size
+    t = np.zeros((K, V, V))
+    np.put_along_axis(t, succ, p, axis=-1)
+    return t
+
+
 def test_bigram_tables_are_stochastic_and_clustered():
     task = ClusteredBigramTask(vocab_size=64, n_clusters=4)
-    t = task.tables()
-    assert t.shape == (4, 64, 64)
+    succ, _ = task.tables()
+    assert succ.shape == (4, 64, SUCCESSORS)
+    # successors of a row are distinct in-vocab ids
+    rows = succ.reshape(-1, SUCCESSORS)
+    assert all(len(set(r)) == SUCCESSORS for r in rows)
+    assert succ.min() >= 0 and succ.max() < 64
+    t = _dense(task)
     np.testing.assert_allclose(t.sum(-1), 1.0, atol=1e-6)
     # clusters differ (the MoE-specializable structure)
     assert np.abs(t[0] - t[1]).max() > 0.1
+
+
+def test_bigram_rows_match_dense_gumbel_top_k():
+    """The direct draw has the distribution of the dense construction:
+    top-SUCCESSORS of V iid Gumbel / concentration, renormalized. Mean
+    sorted row probabilities over 16384 rows agree within 0.01 (their
+    standard errors are below 0.003)."""
+    task = ClusteredBigramTask(vocab_size=64, n_clusters=256)
+    _, p = task.tables()
+    rng = np.random.default_rng(0)
+    g = rng.gumbel(size=(256 * 64, 64)) / task.concentration
+    top = np.sort(g, axis=-1)[:, -SUCCESSORS:][:, ::-1]
+    want = np.exp(top - top[:, :1])
+    want /= want.sum(-1, keepdims=True)
+    np.testing.assert_allclose(
+        p.reshape(-1, SUCCESSORS).mean(0), want.mean(0), atol=0.01
+    )
+    # every token is a successor about equally often (uniform ids)
+    counts = np.bincount(task.tables()[0].ravel(), minlength=64)
+    assert counts.min() > 0.8 * counts.mean()
+
+
+def test_published_vocab_samples_quickly():
+    """granite's 49155-token vocab: tables cost O(K * V), not O(K * V^2)."""
+    task = ClusteredBigramTask(vocab_size=49155)
+    toks = task.sample(2, 512, step=0)
+    assert toks.shape == (2, 513)
+    assert toks.min() >= 0 and toks.max() < 49155
 
 
 def test_targets_are_next_tokens():

@@ -19,17 +19,24 @@ BS = 8  # KV block size under test
 
 
 def _case(B, H, Kh, dh, nb, *, seed=0, dtype=jnp.float32):
-    """Random pool + per-slot block tables over distinct shuffled blocks
-    (block 0 left as trash)."""
+    """Random head-major pool (P, Kh, BS, dh) + per-slot block tables
+    over distinct shuffled blocks (block 0 left as trash)."""
     rng = np.random.default_rng(seed)
     P = 1 + B * nb
     q = jnp.asarray(rng.normal(size=(B, 1, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, BS, Kh, dh)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, BS, Kh, dh)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, Kh, BS, dh)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, Kh, BS, dh)), dtype)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, P)).reshape(B, nb), jnp.int32
     )
     return q, kp, vp, bt
+
+
+def _dense(pool, bt):
+    """(B, nb*BS, Kh, dh) sequence view of each slot's pool blocks."""
+    B, nb = bt.shape
+    blocks = pool[bt].transpose(0, 1, 3, 2, 4)  # (B, nb, BS, Kh, dh)
+    return blocks.reshape(B, nb * BS, *blocks.shape[3:])
 
 
 @pytest.mark.parametrize("H,Kh", [(4, 4), (4, 2), (8, 2), (8, 1)])
@@ -70,8 +77,8 @@ def test_oracle_matches_dense_decode_attention():
         1 + np.arange(B * nb).reshape(B, nb), jnp.int32
     )
     ln = jnp.asarray([5, 2 * BS], jnp.int32)
-    k_dense = kp[bt].reshape(B, nb * BS, Kh, dh)
-    v_dense = vp[bt].reshape(B, nb * BS, Kh, dh)
+    k_dense = _dense(kp, bt)
+    v_dense = _dense(vp, bt)
     y_dense = _decode_attention(q, k_dense, v_dense, ln)
     y_paged = ops.decode_attention(q, kp, vp, bt, ln,
                                    implementation="xla")
@@ -94,8 +101,10 @@ def test_scattered_table_equals_contiguous():
 
     def build(order):
         bt = jnp.asarray(order, jnp.int32)
-        kp = jnp.zeros((P, BS, Kh, dh), jnp.float32)
-        kp = kp.at[bt].set(seq.reshape(B, nb, BS, Kh, dh))
+        kp = jnp.zeros((P, Kh, BS, dh), jnp.float32)
+        kp = kp.at[bt].set(
+            seq.reshape(B, nb, BS, Kh, dh).transpose(0, 1, 3, 2, 4)
+        )
         return bt, kp
 
     bt_a, kp_a = build(1 + np.arange(B * nb).reshape(B, nb))
@@ -142,15 +151,17 @@ def test_bf16_pool_parity():
 
 
 def test_compiled_alignment_guard():
-    """Explicitly misaligned compiled shapes raise a clear error instead
-    of an opaque Mosaic failure (interpret mode accepts anything)."""
+    """The head-major pool makes every block a whole (bs, dh) slab, so
+    Mosaic takes any head_dim and block size (tests/test_tpu_compile.py
+    compiles dh=64); the one up-front shape check left is the GQA
+    grouping, which raises before any lowering, compiled or not."""
     from repro.kernels.decode_attention import (
         paged_decode_attention_pallas,
     )
 
-    q, kp, vp, bt = _case(1, 4, 2, 16, 2)
+    q, kp, vp, bt = _case(1, 4, 3, 16, 2)
     ln = jnp.asarray([4], jnp.int32)
-    with pytest.raises(ValueError, match="head_dim"):
+    with pytest.raises(ValueError, match="multiple of Kh"):
         paged_decode_attention_pallas(
             q[:, 0], kp, vp, bt, ln, interpret=False
         )
@@ -166,7 +177,7 @@ def test_prefill_write_then_decode_write_roundtrip():
     the dense sequence layout block-for-block."""
     Kh, dh, nb = 2, 4, 3
     rng = np.random.default_rng(5)
-    pool = jnp.zeros((1 + nb, BS, Kh, dh), jnp.float32)
+    pool = jnp.zeros((1 + nb, Kh, BS, dh), jnp.float32)
     bt = jnp.asarray([[2, 3, 1]], jnp.int32)
     plen = BS + 3
     sp = 2 * BS  # bucketed
@@ -178,7 +189,7 @@ def test_prefill_write_then_decode_write_roundtrip():
         pool = paged_decode_write(
             pool, toks[t:t + 1], bt, jnp.asarray([plen + t], jnp.int32)
         )
-    dense = pool[bt[0]].reshape(1, nb * BS, Kh, dh)
+    dense = _dense(pool, bt)
     np.testing.assert_allclose(
         np.asarray(dense[0, :plen]), np.asarray(prompt_kv[0, :plen])
     )
@@ -213,22 +224,22 @@ def test_attention_apply_free_slot_attends_nothing():
 
 
 def test_prefill_write_rejects_unbucketed_length():
-    pool = jnp.zeros((3, BS, 2, 4), jnp.float32)
+    pool = jnp.zeros((3, 2, BS, 4), jnp.float32)
     kv = jnp.zeros((1, BS + 1, 2, 4), jnp.float32)
     with pytest.raises(ValueError, match="multiple of the block size"):
         paged_prefill_write(pool, kv, jnp.asarray([[1, 2]], jnp.int32))
 
 
 def test_decode_write_dead_slot_hits_trash_block():
-    pool = jnp.zeros((3, BS, 2, 4), jnp.float32)
+    pool = jnp.zeros((3, 2, BS, 4), jnp.float32)
     kv = jnp.ones((2, 1, 2, 4), jnp.float32)
     bt = jnp.asarray([[0, 0], [1, 2]], jnp.int32)  # slot 0 dead
     out = paged_decode_write(
         pool, kv, bt, jnp.asarray([0, 3], jnp.int32)
     )
-    assert float(jnp.abs(out[0, 0]).max()) == 1.0  # trash block written
-    assert float(jnp.abs(out[1, 3]).max()) == 1.0  # live slot position
-    assert float(jnp.abs(out[1, :3]).max()) == 0.0
+    assert float(jnp.abs(out[0, :, 0]).max()) == 1.0  # trash written
+    assert float(jnp.abs(out[1, :, 3]).min()) == 1.0  # every kv head
+    assert float(jnp.abs(out[1, :, :3]).max()) == 0.0
 
 
 # ---------------------------------------------------------------------------

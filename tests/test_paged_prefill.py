@@ -23,17 +23,22 @@ BS = 8  # KV block size under test
 
 
 def _case(NC, C, H, Kh, dh, nb, *, seed=0, dtype=jnp.float32):
-    """Random pool + per-chunk block tables over distinct shuffled
-    blocks (block 0 left as trash)."""
+    """Random head-major pool (P, Kh, BS, dh) + per-chunk block tables
+    over distinct shuffled blocks (block 0 left as trash)."""
     rng = np.random.default_rng(seed)
     P = 1 + NC * nb
     q = jnp.asarray(rng.normal(size=(NC, C, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, BS, Kh, dh)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, BS, Kh, dh)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, Kh, BS, dh)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, Kh, BS, dh)), dtype)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, P)).reshape(NC, nb), jnp.int32
     )
     return q, kp, vp, bt
+
+
+def _blocks(seq, nb):
+    """(nb*BS, Kh, dh) sequence -> (nb, Kh, BS, dh) head-major blocks."""
+    return seq.reshape(nb, BS, *seq.shape[1:]).transpose(0, 2, 1, 3)
 
 
 def _both(q, kp, vp, bt, starts, lens):
@@ -81,8 +86,8 @@ def test_scattered_table_equals_contiguous():
 
     def build(order):
         bt = jnp.asarray([order], jnp.int32)
-        kp = jnp.zeros((P, BS, Kh, dh), jnp.float32)
-        kp = kp.at[bt[0]].set(seq.reshape(nb, BS, Kh, dh))
+        kp = jnp.zeros((P, Kh, BS, dh), jnp.float32)
+        kp = kp.at[bt[0]].set(_blocks(seq, nb))
         return bt, kp
 
     bt_a, kp_a = build([1, 2, 3])
@@ -107,10 +112,8 @@ def test_oracle_matches_dense_reference():
     seq_k = jnp.asarray(rng.normal(size=(1, nb * BS, Kh, dh)), jnp.float32)
     seq_v = jnp.asarray(rng.normal(size=(1, nb * BS, Kh, dh)), jnp.float32)
     bt = jnp.asarray([[1, 2, 3]], jnp.int32)
-    kp = jnp.zeros((4, BS, Kh, dh)).at[bt[0]].set(
-        seq_k[0].reshape(nb, BS, Kh, dh))
-    vp = jnp.zeros((4, BS, Kh, dh)).at[bt[0]].set(
-        seq_v[0].reshape(nb, BS, Kh, dh))
+    kp = jnp.zeros((4, Kh, BS, dh)).at[bt[0]].set(_blocks(seq_k[0], nb))
+    vp = jnp.zeros((4, Kh, BS, dh)).at[bt[0]].set(_blocks(seq_v[0], nb))
     q = jnp.asarray(rng.normal(size=(1, C, 4, dh)), jnp.float32)
     y = ops.prefill_attention(q, kp, vp, bt, jnp.asarray([start]),
                               jnp.asarray([C]), implementation="xla")
@@ -163,15 +166,19 @@ def test_pick_q_tile_and_alignment_guard():
 
     assert pick_q_tile(128) == 128
     assert pick_q_tile(96) == 32  # largest pow2 divisor
-    assert pick_q_tile(7) == 1
     assert pick_q_tile(256) == 128  # capped
+    # Fewer than 8 rows per tile (q_tile * GQA group): the whole chunk.
+    assert pick_q_tile(7) == 7
+    assert pick_q_tile(12) == 12
+    assert pick_q_tile(12, group=2) == 4
+    assert pick_q_tile(5, group=8) == 1
     with pytest.raises(ValueError, match="chunk_tokens"):
         pick_q_tile(0)
     q, kp, vp, bt = _case(1, 8, 4, 2, 16, 2)
-    with pytest.raises(ValueError, match="head_dim"):
-        paged_prefill_attention_pallas(
+    with pytest.raises(ValueError, match="q_tile"):
+        paged_prefill_attention_pallas(  # 2 rows * G=2 < 8 sublanes
             q, kp, vp, bt, jnp.asarray([0]), jnp.asarray([8]),
-            interpret=False,
+            q_tile=2, interpret=False,
         )
     with pytest.raises(ValueError, match="must divide"):
         paged_prefill_attention_pallas(
@@ -193,10 +200,10 @@ def test_row_write_matches_prefill_and_decode_writes():
     bt = jnp.asarray([[2, 3, 1]], jnp.int32)
     kv = jnp.asarray(rng.normal(size=(2 * BS, Kh, dh)), jnp.float32)
 
-    pool_a = jnp.zeros((1 + nb, BS, Kh, dh), jnp.float32)
+    pool_a = jnp.zeros((1 + nb, Kh, BS, dh), jnp.float32)
     pool_a = paged_prefill_write(pool_a, kv[None, :2 * BS], bt)
 
-    pool_b = jnp.zeros((1 + nb, BS, Kh, dh), jnp.float32)
+    pool_b = jnp.zeros((1 + nb, Kh, BS, dh), jnp.float32)
     R = 2 * BS
     rows = kv[:R][:, None]  # (R, 1, Kh, dh)
     tables = jnp.broadcast_to(bt, (R, nb))
@@ -216,7 +223,7 @@ def test_row_write_matches_prefill_and_decode_writes():
 
 def test_row_write_dead_rows_hit_trash_and_clamp():
     Kh, dh, nb = 2, 4, 2
-    pool = jnp.zeros((4, BS, Kh, dh), jnp.float32)
+    pool = jnp.zeros((4, Kh, BS, dh), jnp.float32)
     kv = jnp.ones((3, 1, Kh, dh), jnp.float32)
     tables = jnp.asarray([[1, 2], [1, 2], [0, 0]], jnp.int32)
     # Row 1 is dead with an out-of-table nominal position (a padded
@@ -224,12 +231,12 @@ def test_row_write_dead_rows_hit_trash_and_clamp():
     pos = jnp.asarray([3, 5 * BS, 0], jnp.int32)
     live = jnp.asarray([True, False, False])
     out = paged_row_write(pool, kv, tables, pos, live)
-    assert float(jnp.abs(out[1, 3]).max()) == 1.0  # live write landed
+    assert float(jnp.abs(out[1, :, 3]).min()) == 1.0  # live write landed
     assert float(jnp.abs(out[1]).sum()) == float(
-        jnp.abs(out[1, 3]).sum()
+        jnp.abs(out[1, :, 3]).sum()
     )
     assert float(jnp.abs(out[2]).max()) == 0.0  # nothing leaked
-    assert float(jnp.abs(out[0, 0]).max()) == 1.0  # trash took the dead
+    assert float(jnp.abs(out[0, :, 0]).max()) == 1.0  # trash took the dead
 
 
 # ---------------------------------------------------------------------------

@@ -213,14 +213,18 @@ def test_streaming_and_eos(paged_engine):
     base, _ = paged_engine.serve(
         [Request(rid=0, prompt=[4, 5, 6], max_new=6)]
     )
-    eos = base[0][3 + 1]  # second generated token
+    gen = base[0][3:]
+    eos = gen[1]  # second generated token
+    # Greedy output repeats tokens, so EOS may fire earlier: the engine
+    # stops at the FIRST emitted occurrence (included).
+    cut = gen.index(eos) + 1
     got = []
     outs, stats = paged_engine.serve(
         [Request(rid=0, prompt=[4, 5, 6], max_new=6, eos_id=eos)],
         on_token=lambda rid, t: got.append((rid, t)),
     )
     assert stats[0]["reason"] == "eos"
-    assert outs[0] == base[0][:3 + 2]  # truncated at (and incl.) EOS
+    assert outs[0] == base[0][:3 + cut]  # truncated at (and incl.) EOS
     assert [t for _, t in got] == outs[0][3:]  # streamed == emitted
 
 

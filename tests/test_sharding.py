@@ -6,19 +6,12 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.sharding import make_rules, spec_for
 
 
-def _abstract_mesh(shape, axes):
-    try:  # jax >= 0.5 signature: (axis_sizes, axis_names)
-        return AbstractMesh(shape, axes)
-    except TypeError:  # jax 0.4.x: ((name, size), ...)
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
 def mesh2():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh3():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def pr(mesh, **kw):
@@ -123,7 +116,7 @@ def test_expert_parallel_layout():
     # grok: E=8 does not divide the 16-wide axis -> fallback (None)
     assert expert_parallel_layout(m2, 8) is None
     assert expert_parallel_layout(None, 32) is None
-    data_only = _abstract_mesh((16,), ("data",))
+    data_only = AbstractMesh((16,), ("data",))
     assert expert_parallel_layout(data_only, 32) is None
-    ep1 = _abstract_mesh((16, 1), ("data", "model"))
+    ep1 = AbstractMesh((16, 1), ("data", "model"))
     assert expert_parallel_layout(ep1, 32) is None
