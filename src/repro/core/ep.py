@@ -144,31 +144,33 @@ def sorted_dispatch_ep(
             jnp.zeros((M + 1, d), xg_l.dtype)
             .at[dest].set(jnp.take(recv_x, perm, axis=0))[:M]
         )
-        ys = ops.grouped_mlp(
-            xs[None], wi_l, wg_l, wo_l, counts,
-            act=cfg.act, block=block, implementation=implementation,
-        )[0]
+        with jax.named_scope("moe.experts"):
+            ys = ops.grouped_mlp(
+                xs[None], wi_l, wg_l, wo_l, counts,
+                act=cfg.act, block=block, implementation=implementation,
+            )[0]
 
         # ---- return all-to-all + combine on the source --------------
-        ys_pad = jnp.concatenate(
-            [ys, jnp.zeros((1, d), ys.dtype)], axis=0
-        )
-        y_recv = (
-            jnp.zeros((Rr, d), ys.dtype)
-            .at[perm].set(jnp.take(ys_pad, dest, axis=0))
-        )
-        y_ret = jax.lax.all_to_all(y_recv, ep_axis, 0, 0, tiled=True)
-        y_pad = jnp.concatenate(
-            [y_ret, jnp.zeros((1, d), y_ret.dtype)], axis=0
-        )
-        w_eff = jnp.where(keep, wf, 0.0).astype(xg_l.dtype)
-        contrib = jnp.take(y_pad, slot, axis=0).astype(xg_l.dtype)
-        contrib = contrib * w_eff[:, None]
-        tok_dst = jnp.where(keep, tokf, Gl * g)
-        y_l = (
-            jnp.zeros((Gl * g + 1, d), xg_l.dtype)
-            .at[tok_dst].add(contrib)[: Gl * g]
-        ).reshape(Gl, g, d)
+        with jax.named_scope("moe.combine"):
+            ys_pad = jnp.concatenate(
+                [ys, jnp.zeros((1, d), ys.dtype)], axis=0
+            )
+            y_recv = (
+                jnp.zeros((Rr, d), ys.dtype)
+                .at[perm].set(jnp.take(ys_pad, dest, axis=0))
+            )
+            y_ret = jax.lax.all_to_all(y_recv, ep_axis, 0, 0, tiled=True)
+            y_pad = jnp.concatenate(
+                [y_ret, jnp.zeros((1, d), y_ret.dtype)], axis=0
+            )
+            w_eff = jnp.where(keep, wf, 0.0).astype(xg_l.dtype)
+            contrib = jnp.take(y_pad, slot, axis=0).astype(xg_l.dtype)
+            contrib = contrib * w_eff[:, None]
+            tok_dst = jnp.where(keep, tokf, Gl * g)
+            y_l = (
+                jnp.zeros((Gl * g + 1, d), xg_l.dtype)
+                .at[tok_dst].add(contrib)[: Gl * g]
+            ).reshape(Gl, g, d)
 
         # ---- overflow metric (EP drops on top of capacity drops) ----
         n_over = jax.lax.psum(

@@ -123,12 +123,13 @@ def expert_ffn(experts, xe, cfg: ArchConfig, *, implementation="xla",
     """xe: (G, E, cap, d) -> (G, E, cap, d). Dispatches to kernels.ops."""
     from repro.kernels import ops
 
-    wi, wg, wo = _compute_layout_weights(experts, ctx)
-    return ops.expert_ffn(
-        xe, wi, wg, wo,
-        act=cfg.act,
-        implementation=implementation,
-    )
+    with jax.named_scope("moe.experts"):
+        wi, wg, wo = _compute_layout_weights(experts, ctx)
+        return ops.expert_ffn(
+            xe, wi, wg, wo,
+            act=cfg.act,
+            implementation=implementation,
+        )
 
 
 def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
@@ -150,55 +151,61 @@ def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
     G, g, d = xg.shape
     E = moe.num_experts
 
-    # Flat per-group assignment stream (token id, expert id, weight) —
-    # shared with the expert-parallel path (core/ep.py).
-    tok, eid, w = R.assignment_stream(r, E, g)
-    N = tok.shape[1]
-    valid = (eid < E) & (tok < g)
-    key = jnp.where(valid, eid, E).astype(jnp.int32)
+    with jax.named_scope("moe.dispatch"):
+        # Flat per-group assignment stream (token id, expert id, weight)
+        # — shared with the expert-parallel path (core/ep.py).
+        tok, eid, w = R.assignment_stream(r, E, g)
+        N = tok.shape[1]
+        valid = (eid < E) & (tok < g)
+        key = jnp.where(valid, eid, E).astype(jnp.int32)
 
-    # Stable sort by expert (dropped assignments -> key E, past the last
-    # segment) and block-aligned ragged destinations — the layout math
-    # shared with core/ep.py via kernels/grouped_mlp.py. Only the
-    # integer permutation goes through lax.sort; the differentiable
-    # weights follow via take_along_axis, so no gradient flows through
-    # the sort itself.
-    perm, key_s, counts, dest, M = ragged_destinations(key, E, block)
-    tok_s = jnp.take_along_axis(tok, perm, axis=1)
-    w_s = jnp.take_along_axis(w, perm, axis=1)
-    valid_s = key_s < E
+        # Stable sort by expert (dropped assignments -> key E, past the
+        # last segment) and block-aligned ragged destinations — the
+        # layout math shared with core/ep.py via kernels/grouped_mlp.py.
+        # Only the integer permutation goes through lax.sort; the
+        # differentiable weights follow via take_along_axis, so no
+        # gradient flows through the sort itself.
+        perm, key_s, counts, dest, M = ragged_destinations(key, E, block)
+        tok_s = jnp.take_along_axis(tok, perm, axis=1)
+        w_s = jnp.take_along_axis(w, perm, axis=1)
+        valid_s = key_s < E
 
-    # Ragged buffers: src maps ragged row -> group-local token (g = pad
-    # row), wr carries the combine weight (0 on pad rows). Row M is the
-    # trash row for dropped assignments.
-    gi = jnp.broadcast_to(jnp.arange(G)[:, None], (G, N))
-    src = jnp.full((G, M + 1), g, jnp.int32).at[gi, dest].set(tok_s)[:, :M]
-    wr = (
-        jnp.zeros((G, M + 1), w_s.dtype)
-        .at[gi, dest].set(jnp.where(valid_s, w_s, 0.0))[:, :M]
-    )
+        # Ragged buffers: src maps ragged row -> group-local token (g =
+        # pad row), wr carries the combine weight (0 on pad rows). Row M
+        # is the trash row for dropped assignments.
+        gi = jnp.broadcast_to(jnp.arange(G)[:, None], (G, N))
+        src = (jnp.full((G, M + 1), g, jnp.int32)
+               .at[gi, dest].set(tok_s)[:, :M])
+        wr = (
+            jnp.zeros((G, M + 1), w_s.dtype)
+            .at[gi, dest].set(jnp.where(valid_s, w_s, 0.0))[:, :M]
+        )
 
-    gm = jnp.broadcast_to(jnp.arange(G)[:, None], (G, M))
-    pad_row = src >= g
-    xs = xg[gm, jnp.minimum(src, g - 1)]
-    xs = xs * (1.0 - pad_row[..., None].astype(xg.dtype))
-    # Ragged rows stay batch-sharded: expert boundaries are dynamic, so
-    # the expert dim cannot be a sharding axis here (see module docstring).
-    xs = act(ctx, xs, "batch seq embed")
-    wi, wg, wo = _compute_layout_weights(params["experts"], ctx)
-    ys = ops.grouped_mlp(
-        xs, wi, wg, wo, counts,
-        act=cfg.act, block=block, implementation=implementation,
-    )
-    # Combine: weight, unsort, scatter-add (duplicate token rows — one per
-    # surviving assignment — accumulate, exactly like the gather path).
-    ys = act(ctx, ys, "batch seq mlp")
-    yw = (ys * wr[..., None]).astype(xg.dtype)
-    y = jnp.zeros((G, g + 1, d), xg.dtype)
-    y = act(ctx, y, "batch seq mlp")
-    y = y.at[gm, src].add(yw)
-    y = act(ctx, y, "batch seq mlp")
-    return y[:, :g]
+        gm = jnp.broadcast_to(jnp.arange(G)[:, None], (G, M))
+        pad_row = src >= g
+        xs = xg[gm, jnp.minimum(src, g - 1)]
+        xs = xs * (1.0 - pad_row[..., None].astype(xg.dtype))
+        # Ragged rows stay batch-sharded: expert boundaries are dynamic,
+        # so the expert dim cannot be a sharding axis here (see module
+        # docstring).
+        xs = act(ctx, xs, "batch seq embed")
+    with jax.named_scope("moe.experts"):
+        wi, wg, wo = _compute_layout_weights(params["experts"], ctx)
+        ys = ops.grouped_mlp(
+            xs, wi, wg, wo, counts,
+            act=cfg.act, block=block, implementation=implementation,
+        )
+    with jax.named_scope("moe.combine"):
+        # Weight, unsort, scatter-add (duplicate token rows — one per
+        # surviving assignment — accumulate, exactly like the gather
+        # path).
+        ys = act(ctx, ys, "batch seq mlp")
+        yw = (ys * wr[..., None]).astype(xg.dtype)
+        y = jnp.zeros((G, g + 1, d), xg.dtype)
+        y = act(ctx, y, "batch seq mlp")
+        y = y.at[gm, src].add(yw)
+        y = act(ctx, y, "batch seq mlp")
+        return y[:, :g]
 
 
 def _group(x2d: jax.Array, group_size: int):
@@ -245,57 +252,62 @@ def moe_apply(
     xg, n, pad = _group(x2d, moe.group_size)
     G, g, d = xg.shape
 
-    mg = None
-    if token_mask is not None:
-        m1 = jnp.broadcast_to(
-            token_mask, orig_shape[:-1]
-        ).reshape(-1).astype(bool)
-        if pad:
-            m1 = jnp.pad(m1, (0, pad))
-        mg = m1.reshape(G, g)
+    with jax.named_scope("moe.route"):
+        mg = None
+        if token_mask is not None:
+            m1 = jnp.broadcast_to(
+                token_mask, orig_shape[:-1]
+            ).reshape(-1).astype(bool)
+            if pad:
+                m1 = jnp.pad(m1, (0, pad))
+            mg = m1.reshape(G, g)
 
-    logits = jnp.einsum(
-        "Ggd,de->Gge", xg, params["router"]["w"],
-        preferred_element_type=jnp.float32,
-    )
-    r = R.route(logits, moe, router_kind, token_mask=mg)
-    cap = r.token_idx.shape[-1]
+        logits = jnp.einsum(
+            "Ggd,de->Gge", xg, params["router"]["w"],
+            preferred_element_type=jnp.float32,
+        )
+        r = R.route(logits, moe, router_kind, token_mask=mg)
 
     if dispatch == "einsum":
         # One-hot dispatch/combine (GShard-era faithful path).
-        oh = jax.nn.one_hot(r.token_idx, g + 1, dtype=xg.dtype)[..., :g]
-        # (G, E, cap, g) x (G, g, d) -> (G, E, cap, d)
-        xe = jnp.einsum("Gect,Gtd->Gecd", oh, xg)
-        xe = act(ctx, xe, "batch expert cap embed")
+        with jax.named_scope("moe.dispatch"):
+            oh = jax.nn.one_hot(r.token_idx, g + 1, dtype=xg.dtype)[..., :g]
+            # (G, E, cap, g) x (G, g, d) -> (G, E, cap, d)
+            xe = jnp.einsum("Gect,Gtd->Gecd", oh, xg)
+            xe = act(ctx, xe, "batch expert cap embed")
         ye = expert_ffn(params["experts"], xe, cfg,
                         implementation=implementation, ctx=ctx)
-        ye = act(ctx, ye, "batch expert cap embed")
-        comb = oh * r.combine[..., None].astype(xg.dtype)
-        y = jnp.einsum("Gect,Gecd->Gtd", comb, ye)
+        with jax.named_scope("moe.combine"):
+            ye = act(ctx, ye, "batch expert cap embed")
+            comb = oh * r.combine[..., None].astype(xg.dtype)
+            y = jnp.einsum("Gect,Gecd->Gtd", comb, ye)
     elif dispatch == "gather":
-        safe_idx = jnp.minimum(r.token_idx, g - 1)
-        gi = jnp.broadcast_to(
-            jnp.arange(G)[:, None, None], r.token_idx.shape
-        )
-        xe = xg[gi, safe_idx]  # (G, E, cap, d)
-        valid = (r.token_idx < g)[..., None].astype(xg.dtype)
-        xe = xe * valid
-        xe = act(ctx, xe, "batch expert cap embed")
+        with jax.named_scope("moe.dispatch"):
+            safe_idx = jnp.minimum(r.token_idx, g - 1)
+            gi = jnp.broadcast_to(
+                jnp.arange(G)[:, None, None], r.token_idx.shape
+            )
+            xe = xg[gi, safe_idx]  # (G, E, cap, d)
+            valid = (r.token_idx < g)[..., None].astype(xg.dtype)
+            xe = xe * valid
+            xe = act(ctx, xe, "batch expert cap embed")
         ye = expert_ffn(params["experts"], xe, cfg,
                         implementation=implementation, ctx=ctx)
-        # Combine. Resharding ye from expert-sharded to hidden-sharded
-        # BEFORE the scatter makes GSPMD emit a (tokens*k*d/E)-sized
-        # all-to-all and a shard-local scatter, instead of partial-summing
-        # the full (G, g, d) token buffer with an all-reduce per layer
-        # (~E/k * 2 more bytes; see EXPERIMENTS.md SPerf jamba iteration).
-        ye = act(ctx, ye, "batch _ cap mlp")
-        w = (r.combine[..., None] * valid).astype(ye.dtype)
-        yw = (ye * w).astype(xg.dtype)
-        y = jnp.zeros((G, g + 1, d), xg.dtype)
-        y = act(ctx, y, "batch seq mlp")
-        y = y.at[gi, r.token_idx].add(yw)
-        y = act(ctx, y, "batch seq mlp")
-        y = y[:, :g]
+        with jax.named_scope("moe.combine"):
+            # Resharding ye from expert-sharded to hidden-sharded BEFORE
+            # the scatter makes GSPMD emit a (tokens*k*d/E)-sized
+            # all-to-all and a shard-local scatter, instead of
+            # partial-summing the full (G, g, d) token buffer with an
+            # all-reduce per layer (~E/k * 2 more bytes; see
+            # EXPERIMENTS.md SPerf jamba iteration).
+            ye = act(ctx, ye, "batch _ cap mlp")
+            w = (r.combine[..., None] * valid).astype(ye.dtype)
+            yw = (ye * w).astype(xg.dtype)
+            y = jnp.zeros((G, g + 1, d), xg.dtype)
+            y = act(ctx, y, "batch seq mlp")
+            y = y.at[gi, r.token_idx].add(yw)
+            y = act(ctx, y, "batch seq mlp")
+            y = y[:, :g]
     elif dispatch == "sorted":
         from repro.sharding.logical import expert_parallel_layout
 
@@ -306,11 +318,13 @@ def moe_apply(
         if ep_layout is not None:
             from repro.core.ep import sorted_dispatch_ep
 
-            y, ep_overflow = sorted_dispatch_ep(
-                params, xg, r, cfg, moe,
-                ctx=ctx, implementation=implementation,
-                block=sorted_block,
-            )
+            # The expert FFN and the combine name their own scopes inside.
+            with jax.named_scope("moe.dispatch"):
+                y, ep_overflow = sorted_dispatch_ep(
+                    params, xg, r, cfg, moe,
+                    ctx=ctx, implementation=implementation,
+                    block=sorted_block,
+                )
         else:
             # ep="a2a" on an EP-incapable mesh (or no ctx) falls back to
             # the batch-sharded weight-gather layout — same results.
@@ -322,10 +336,11 @@ def moe_apply(
     else:
         raise ValueError(f"unknown dispatch {dispatch!r}")
 
-    y = y.reshape(-1, d)
-    if pad:
-        y = y[:n]
-    y = y.reshape(orig_shape).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        y = y.reshape(-1, d)
+        if pad:
+            y = y[:n]
+        y = y.reshape(orig_shape).astype(x.dtype)
     # Remat boundary tag: with stack_apply(remat="moe") only this combined
     # output is saved for the backward; the dispatched (G, E, cap, d)
     # buffers and router tensors above are recomputed.
@@ -333,13 +348,15 @@ def moe_apply(
 
     y = checkpoint_name(y, "moe_block")
 
-    metrics = {
-        "aux_loss": r.aux_loss * moe.aux_loss_weight,
-        "z_loss": r.z_loss * moe.z_loss_weight,
-        "dropped_frac": r.dropped_frac,
-        "router_prob_mean_max": r.probs.max(-1).mean(),
-        # Assignments dropped by the expert-parallel a2a send-buffer
-        # budget (0 outside the EP path and whenever the budget holds).
-        "ep_overflow_frac": ep_overflow,
-    }
+    with jax.named_scope("moe.route"):
+        metrics = {
+            "aux_loss": r.aux_loss * moe.aux_loss_weight,
+            "z_loss": r.z_loss * moe.z_loss_weight,
+            "dropped_frac": r.dropped_frac,
+            "router_prob_mean_max": r.probs.max(-1).mean(),
+            # Assignments dropped by the expert-parallel a2a send-buffer
+            # budget (0 outside the EP path and whenever the budget
+            # holds).
+            "ep_overflow_frac": ep_overflow,
+        }
     return y, metrics

@@ -204,6 +204,7 @@ def reference_attention(q, k, v, *, causal=True, q_offset=0, kv_len=None):
     return out.reshape(B, Sq, H, dh).astype(q.dtype)
 
 
+@jax.named_scope("attn")
 def attention_apply(
     p,
     x,
@@ -258,7 +259,8 @@ def attention_apply(
     (e.g. qwen2.5's 40 heads on a 16-wide axis) still shard — padded heads
     compute garbage attention that is annihilated by the zero wo rows, so
     the function is EXACTLY preserved (tests/test_attention_padding).
-    Returns (y, new_cache).
+    Returns (y, new_cache). Its ops carry the ``attn`` named scope, the
+    cache writes ``kv.write`` inside it.
     """
     from repro.sharding import act as _act
 
@@ -343,8 +345,11 @@ def attention_apply(
             live_parts.append(chunk_live.reshape(-1))
         live = jnp.concatenate(live_parts)
         # ONE cache-write path for all lanes: a single per-row scatter.
-        new_pk = paged_row_write(pool_k, k, block_tables, positions, live)
-        new_pv = paged_row_write(pool_v, v, block_tables, positions, live)
+        with jax.named_scope("kv.write"):
+            new_pk = paged_row_write(pool_k, k, block_tables, positions,
+                                     live)
+            new_pv = paged_row_write(pool_v, v, block_tables, positions,
+                                     live)
         cache = {"k": new_pk, "v": new_pv}
         ys = []
         if B_dec:
@@ -392,14 +397,18 @@ def attention_apply(
                 raise ValueError(
                     "paged prefill admits one request at a time (B == 1)"
                 )
-            cache = {
-                "k": paged_prefill_write(pool_k, k, block_tables),
-                "v": paged_prefill_write(pool_v, v, block_tables),
-            }
+            with jax.named_scope("kv.write"):
+                cache = {
+                    "k": paged_prefill_write(pool_k, k, block_tables),
+                    "v": paged_prefill_write(pool_v, v, block_tables),
+                }
         else:
             lengths = cache_index  # (B,) tokens already cached per slot
-            new_pk = paged_decode_write(pool_k, k, block_tables, lengths)
-            new_pv = paged_decode_write(pool_v, v, block_tables, lengths)
+            with jax.named_scope("kv.write"):
+                new_pk = paged_decode_write(pool_k, k, block_tables,
+                                            lengths)
+                new_pv = paged_decode_write(pool_v, v, block_tables,
+                                            lengths)
             cache = {"k": new_pk, "v": new_pv}
             from repro.kernels import ops
 
@@ -415,12 +424,13 @@ def attention_apply(
             out = jnp.einsum("bshk,hkd->bsd", y, wo)
             return out, cache
     elif cache is not None and kv_x is None:
-        new_k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1
-        )
-        new_v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1
-        )
+        with jax.named_scope("kv.write"):
+            new_k = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1
+            )
+            new_v = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1
+            )
         cache = {"k": new_k, "v": new_v}
         q_offset = cache_index
         if Sq > 1:

@@ -113,6 +113,7 @@ def embed_init(rng, cfg: ArchConfig, *, dtype=jnp.float32):
     return p
 
 
+@jax.named_scope("embed")
 def embed_apply(p, tokens, cfg: ArchConfig, *, positions=None):
     x = jnp.take(p["tokens"], tokens, axis=0)
     if cfg.pos_emb == "learned" and positions is not None:

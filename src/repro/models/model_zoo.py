@@ -137,6 +137,16 @@ def _cast_params(params, dtype):
     )
 
 
+def _lm_head(params, x, cfg: ArchConfig):
+    """Final norm and the (tied) vocabulary projection -> float32 logits,
+    under the ``lm_head`` scope."""
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg)
+        return head_apply(
+            params.get("head", {}), x, params.get("embed"), cfg
+        ).astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # forward (train / eval)
 # ---------------------------------------------------------------------------
@@ -211,11 +221,12 @@ def forward_train(
             attn_impl=ac.attn_impl,
             mixer_impl=ac.mixer_impl, ctx=ctx, remat=ac.remat,
         )
-        x = norm_apply(params["final_norm"], x, cfg)
-        pooled = x.mean(axis=1)  # global average pooling (paper §2.2)
-        logits = jnp.einsum(
-            "bd,dv->bv", pooled, params["head"]["w"]
-        ).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            x = norm_apply(params["final_norm"], x, cfg)
+            pooled = x.mean(axis=1)  # global average pooling (paper §2.2)
+            logits = jnp.einsum(
+                "bd,dv->bv", pooled, params["head"]["w"]
+            ).astype(jnp.float32)
         return logits, mets
 
     enc = None
@@ -236,14 +247,16 @@ def forward_train(
         pad_heads_multiple=ac.pad_heads_multiple,
         ctx=ctx, remat=ac.remat,
     )
-    x = norm_apply(params["final_norm"], x, cfg)
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg)
     mets = jax.tree.map(jnp.add, mets, enc_mets)
     if return_hidden:
         return x, mets
-    logits = head_apply(
-        params.get("head", {}), x, params["embed"], cfg
-    ).astype(jnp.float32)
-    logits = act(ctx, logits, "batch seq vocab")
+    with jax.named_scope("lm_head"):
+        logits = head_apply(
+            params.get("head", {}), x, params["embed"], cfg
+        ).astype(jnp.float32)
+        logits = act(ctx, logits, "batch seq vocab")
     return logits, mets
 
 
@@ -259,11 +272,12 @@ def loss_fn(
     if cfg.structure == "encoder_only":
         logits, mets = forward_train(params, batch, cfg, ac=ac, ctx=ctx)
         labels = batch["labels"]
-        ce = -jnp.mean(
-            jnp.take_along_axis(
-                jax.nn.log_softmax(logits), labels[:, None], axis=-1
+        with jax.named_scope("loss"):
+            ce = -jnp.mean(
+                jnp.take_along_axis(
+                    jax.nn.log_softmax(logits), labels[:, None], axis=-1
+                )
             )
-        )
     elif ac.ce_chunk:
         hidden, mets = forward_train(
             params, batch, cfg, ac=ac, ctx=ctx, return_hidden=True
@@ -277,12 +291,14 @@ def loss_fn(
     else:
         logits, mets = forward_train(params, batch, cfg, ac=ac, ctx=ctx)
         targets = batch["targets"]
-        valid = targets >= 0
-        tgt = jnp.maximum(targets, 0)
-        logp = jax.nn.log_softmax(logits)
-        ce_tok = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        denom = jnp.maximum(valid.sum(), 1)
-        ce = jnp.where(valid, ce_tok, 0.0).sum() / denom
+        with jax.named_scope("loss"):
+            valid = targets >= 0
+            tgt = jnp.maximum(targets, 0)
+            logp = jax.nn.log_softmax(logits)
+            ce_tok = -jnp.take_along_axis(
+                logp, tgt[..., None], axis=-1)[..., 0]
+            denom = jnp.maximum(valid.sum(), 1)
+            ce = jnp.where(valid, ce_tok, 0.0).sum() / denom
     loss = ce + mets["aux_loss"] + mets["z_loss"]
     out = dict(mets)
     out.update(loss=loss, ce=ce)
@@ -312,15 +328,18 @@ def _chunked_ce(hidden, w, targets, chunk: int):
     def body(carry, xs):
         ce_sum, n = carry
         xch, tch = xs
-        logits = jnp.einsum(
-            "bsd,dv->bsv", xch, w, preferred_element_type=jnp.float32
-        )
-        valid = tch >= 0
-        tgt = jnp.maximum(tch, 0)
-        logp = jax.nn.log_softmax(logits)
-        ce_tok = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        ce_sum = ce_sum + jnp.where(valid, ce_tok, 0.0).sum()
-        n = n + valid.sum()
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum(
+                "bsd,dv->bsv", xch, w, preferred_element_type=jnp.float32
+            )
+        with jax.named_scope("loss"):
+            valid = tch >= 0
+            tgt = jnp.maximum(tch, 0)
+            logp = jax.nn.log_softmax(logits)
+            ce_tok = -jnp.take_along_axis(
+                logp, tgt[..., None], axis=-1)[..., 0]
+            ce_sum = ce_sum + jnp.where(valid, ce_tok, 0.0).sum()
+            n = n + valid.sum()
         return (ce_sum, n), None
 
     (ce_sum, n), _ = jax.lax.scan(
@@ -417,14 +436,11 @@ def paged_prefill(
     )
     new_cache = dict(cache)
     new_cache["stack"] = stack_cache
-    x_last = jax.lax.dynamic_slice_in_dim(
-        x, jnp.asarray(length, jnp.int32) - 1, 1, axis=1
-    )
-    x_last = norm_apply(params["final_norm"], x_last, cfg)
-    logits = head_apply(
-        params.get("head", {}), x_last, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits
+    with jax.named_scope("sample"):
+        x_last = jax.lax.dynamic_slice_in_dim(
+            x, jnp.asarray(length, jnp.int32) - 1, 1, axis=1
+        )
+    return new_cache, _lm_head(params, x_last, cfg)
 
 
 def paged_decode_step(
@@ -470,11 +486,7 @@ def paged_decode_step(
     )
     new_cache = dict(cache)
     new_cache["stack"] = stack_cache
-    x = norm_apply(params["final_norm"], x, cfg)
-    logits = head_apply(
-        params.get("head", {}), x, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits
+    return new_cache, _lm_head(params, x, cfg)
 
 
 def paged_mixed_step(
@@ -571,15 +583,12 @@ def paged_mixed_step(
     # each chunk lane's last valid row (the TRUE last prompt position
     # when the chunk completes a prompt).
     d = x.shape[-1]
-    xd = x[:B, 0]
-    last = jnp.clip(chunk_lens - 1, 0, C - 1)
-    xc = x[B:, 0].reshape(NC, C, d)[jnp.arange(NC), last]
-    h = jnp.concatenate([xd, xc], axis=0)[:, None]  # (B + NC, 1, d)
-    h = norm_apply(params["final_norm"], h, cfg)
-    logits = head_apply(
-        params.get("head", {}), h, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits[:, 0]
+    with jax.named_scope("sample"):
+        xd = x[:B, 0]
+        last = jnp.clip(chunk_lens - 1, 0, C - 1)
+        xc = x[B:, 0].reshape(NC, C, d)[jnp.arange(NC), last]
+        h = jnp.concatenate([xd, xc], axis=0)[:, None]  # (B + NC, 1, d)
+    return new_cache, _lm_head(params, h, cfg)[:, 0]
 
 
 def paged_verify_step(
@@ -679,15 +688,12 @@ def paged_verify_step(
     # distribution at every drafted position for rejection sampling)
     # plus each chunk lane's last valid row.
     d = x.shape[-1]
-    xv = x[: B * K1, 0]
-    last = jnp.clip(chunk_lens - 1, 0, C - 1)
-    xc = x[B * K1:, 0].reshape(NC, C, d)[jnp.arange(NC), last]
-    h = jnp.concatenate([xv, xc], axis=0)[:, None]  # (B*K1 + NC, 1, d)
-    h = norm_apply(params["final_norm"], h, cfg)
-    logits = head_apply(
-        params.get("head", {}), h, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits[:, 0]
+    with jax.named_scope("sample"):
+        xv = x[: B * K1, 0]
+        last = jnp.clip(chunk_lens - 1, 0, C - 1)
+        xc = x[B * K1:, 0].reshape(NC, C, d)[jnp.arange(NC), last]
+        h = jnp.concatenate([xv, xc], axis=0)[:, None]  # (B*K1 + NC, 1, d)
+    return new_cache, _lm_head(params, h, cfg)[:, 0]
 
 
 def serve_cache_axes(cfg: ArchConfig):
@@ -731,11 +737,7 @@ def prefill(
     )
     new_cache = dict(cache)
     new_cache["stack"] = stack_cache
-    x = norm_apply(params["final_norm"], x[:, -1:], cfg)
-    logits = head_apply(
-        params.get("head", {}), x, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits
+    return new_cache, _lm_head(params, x[:, -1:], cfg)
 
 
 def decode_step(
@@ -771,8 +773,4 @@ def decode_step(
     )
     new_cache = dict(cache)
     new_cache["stack"] = stack_cache
-    x = norm_apply(params["final_norm"], x, cfg)
-    logits = head_apply(
-        params.get("head", {}), x, params.get("embed"), cfg
-    ).astype(jnp.float32)
-    return new_cache, logits
+    return new_cache, _lm_head(params, x, cfg)

@@ -8,13 +8,11 @@ from repro.obs.tracker import (
     DEFAULT_BOUNDS,
     NULL,
     WALL_FIELDS,
-    ConsoleSink,
     Histogram,
     JsonlSink,
     MemorySink,
     NullTracker,
     Sink,
-    TensorBoardSink,
     Tracker,
     deterministic_rows,
 )
@@ -23,13 +21,11 @@ __all__ = [
     "DEFAULT_BOUNDS",
     "NULL",
     "WALL_FIELDS",
-    "ConsoleSink",
     "Histogram",
     "JsonlSink",
     "MemorySink",
     "NullTracker",
     "Sink",
-    "TensorBoardSink",
     "Tracker",
     "deterministic_rows",
 ]

@@ -41,21 +41,27 @@ nondeterminism; two identical seeded runs must agree on the result
 Sinks
 -----
 
-:class:`MemorySink` (tests), :class:`JsonlSink` (one JSON object per
-line, flushed on every row, close-on-exception via the context-manager
-protocol), :class:`ConsoleSink`, and an optional
-:class:`TensorBoardSink` that is import-gated — constructing it
-without a TensorBoard provider installed raises ``ImportError``; no
-new dependency is required for any other sink.
+:class:`MemorySink` (tests) and :class:`JsonlSink` (one JSON object
+per line, flushed on every row, close-on-exception via the
+context-manager protocol).
+
+Profiler
+--------
+
+Every span, the :class:`NullTracker`'s included, also enters a
+``jax.profiler.TraceAnnotation`` named by its path (``tick``,
+``tick/mixed_step``): with a profiler trace active the spans land as
+host events on the device trace's clock, with or without a sink.
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
-import sys
 import time
 from typing import Callable, Iterable, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Wall-clock-derived row fields, stripped by deterministic_rows().
 WALL_FIELDS = ("dur_ms", "step_ms", "tokens_per_s")
@@ -148,59 +154,6 @@ class JsonlSink(Sink):
     @property
     def closed(self) -> bool:
         return self.path is not None and self._fh is None
-
-
-class ConsoleSink(Sink):
-    """Compact one-line-per-row console output (stderr by default so
-    token streams on stdout stay clean)."""
-
-    def __init__(self, stream=None, kinds: Optional[tuple] = None):
-        self.stream = stream if stream is not None else sys.stderr
-        self.kinds = kinds
-
-    def write(self, row: dict) -> None:
-        if self.kinds is not None and row.get("kind") not in self.kinds:
-            return
-        print(json.dumps(row, sort_keys=True), file=self.stream)
-
-
-class TensorBoardSink(Sink):
-    """Optional TensorBoard export of scalar rows (counter / gauge /
-    observe / summary). Import-gated: constructing it without a
-    TensorBoard provider raises ImportError — callers that want a soft
-    dependency should catch it. Not used by any default path."""
-
-    def __init__(self, logdir: str):
-        writer_cls = None
-        try:  # torch ships a SummaryWriter
-            from torch.utils.tensorboard import SummaryWriter as writer_cls  # noqa: F401
-        except Exception:
-            try:
-                from tensorboardX import SummaryWriter as writer_cls  # noqa: F401
-            except Exception:
-                writer_cls = None
-        if writer_cls is None:
-            raise ImportError(
-                "TensorBoardSink needs torch.utils.tensorboard or "
-                "tensorboardX; neither is installed"
-            )
-        self._w = writer_cls(logdir)
-
-    def write(self, row: dict) -> None:
-        kind = row.get("kind")
-        t = row.get("t") or 0
-        name = row.get("name", kind)
-        if kind in ("counter", "gauge", "observe"):
-            self._w.add_scalar(name, row["value"], t)
-        elif kind == "summary":
-            for k in ("p50", "p99"):
-                self._w.add_scalar(f"{name}/{k}", row[k], t)
-
-    def flush(self) -> None:
-        self._w.flush()
-
-    def close(self) -> None:
-        self._w.close()
 
 
 # -- histogram ------------------------------------------------------------
@@ -364,13 +317,15 @@ class Tracker:
     def span(self, name: str):
         """Nestable wall-clock span. Emits one span row on exit (path
         slash-joined through enclosing spans) and accumulates the
-        duration into the ``span.<path>`` histogram."""
+        duration into the ``span.<path>`` histogram; the profiler sees
+        it as the host event ``<path>``."""
         self._stack.append(name)
         path = "/".join(self._stack)
         depth = len(self._stack)
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(path):
+                yield
         finally:
             dur_ms = (time.perf_counter() - t0) * 1e3
             self._stack.pop()
@@ -407,9 +362,9 @@ class Tracker:
 
 
 class NullTracker(Tracker):
-    """Zero-overhead default: every instrument is a no-op and span
-    returns a shared null context. ``tracker or NULL`` keeps hot loops
-    branch-free."""
+    """Default tracker: every instrument is a no-op and a span is only
+    its profiler host event, a shared null context while no profiler
+    records. ``tracker or NULL`` keeps hot loops branch-free."""
 
     def __init__(self):
         super().__init__(owns_sinks=False)
@@ -437,7 +392,20 @@ class NullTracker(Tracker):
         pass
 
     def span(self, name):
-        return _NULL_CTX
+        # Only the profiler's host event: nothing to enter unless a
+        # profiler is recording.
+        if not TraceAnnotation.is_enabled():
+            return _NULL_CTX
+        return self._annotation(name)
+
+    @contextlib.contextmanager
+    def _annotation(self, name):
+        self._stack.append(name)
+        try:
+            with TraceAnnotation("/".join(self._stack)):
+                yield
+        finally:
+            self._stack.pop()
 
     def bind(self, *, extra_sinks=(), clock=None, **tags):
         if extra_sinks:

@@ -1009,18 +1009,18 @@ class ChunkedSession:
         the session still has work afterwards — the solo loop is
         ``while sess.tick(): pass``.
 
-        With a tracker attached, the tick is wrapped in a ``tick`` span
-        (phases nested under it) and one ``engine`` row — the per-tick
-        queue-depth / occupancy / stall time series — is emitted per
-        call. All tracked values are pure host-side reads: tracking
-        adds ZERO device syncs (the mixed step's single logits pull
-        stays the only one)."""
+        The tick is wrapped in a ``tick`` span, its phases nested under
+        it: with a profiler trace active they are host events (``tick``,
+        ``tick/admission``, ... ``tick/emit``) on the device trace's
+        clock, tracker or not. With a tracker attached they are also
+        span rows, and one ``engine`` row — the per-tick queue-depth /
+        occupancy / stall time series — is emitted per call. All tracked
+        values are pure host-side reads: tracking adds ZERO device syncs
+        (the mixed step's single logits pull stays the only one)."""
         trk = self.trk
-        if not trk.enabled:
+        with trk.span("tick"):
             alive = self._tick_inner()
-        else:
-            with trk.span("tick"):
-                alive = self._tick_inner()
+        if trk.enabled:
             sig = self.signals()
             trk.row(
                 "engine",

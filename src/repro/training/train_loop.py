@@ -162,34 +162,38 @@ def make_train_step(
         else:
             residual = state.get("residual")
 
-        updates, opt_state = optimizer.update(
-            grads, state["opt_state"], params
-        )
-        if lr_scale is not None:
-            updates = jax.tree.map(lambda u: u * lr_scale, updates)
-        new_params = apply_updates(params, updates)
-        mets = dict(mets)
-        grad_norm = global_norm(grads)
-        mets["grad_norm"] = grad_norm
-        if tc.max_consecutive_skips > 0:
-            # Non-finite guard: keep the OLD params/opt state/residual
-            # when the loss or grad norm blew up — all inside the jitted
-            # step (jnp.where), zero extra host syncs; the Trainer reads
-            # mets["skipped"] off the metrics it already pulls.
-            ok = jnp.isfinite(mets["loss"]) & jnp.isfinite(grad_norm)
+        # Update, norm and skip guard: the "optimizer" part of a step's
+        # device profile.
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state["opt_state"], params
+            )
+            if lr_scale is not None:
+                updates = jax.tree.map(lambda u: u * lr_scale, updates)
+            new_params = apply_updates(params, updates)
+            mets = dict(mets)
+            grad_norm = global_norm(grads)
+            mets["grad_norm"] = grad_norm
+            if tc.max_consecutive_skips > 0:
+                # Non-finite guard: keep the OLD params/opt state/
+                # residual when the loss or grad norm blew up — all
+                # inside the jitted step (jnp.where), zero extra host
+                # syncs; the Trainer reads mets["skipped"] off the
+                # metrics it already pulls.
+                ok = jnp.isfinite(mets["loss"]) & jnp.isfinite(grad_norm)
 
-            def pick(new, old):
-                return jax.tree.map(
-                    lambda a, b: jnp.where(ok, a, b), new, old
-                )
+                def pick(new, old):
+                    return jax.tree.map(
+                        lambda a, b: jnp.where(ok, a, b), new, old
+                    )
 
-            new_params = pick(new_params, params)
-            opt_state = pick(opt_state, state["opt_state"])
-            if residual is not None and "residual" in state:
-                residual = pick(residual, state["residual"])
-            mets["skipped"] = (~ok).astype(jnp.float32)
-        else:
-            mets["skipped"] = jnp.zeros((), jnp.float32)
+                new_params = pick(new_params, params)
+                opt_state = pick(opt_state, state["opt_state"])
+                if residual is not None and "residual" in state:
+                    residual = pick(residual, state["residual"])
+                mets["skipped"] = (~ok).astype(jnp.float32)
+            else:
+                mets["skipped"] = jnp.zeros((), jnp.float32)
         new_state = dict(state)
         new_state.update(
             params=new_params,

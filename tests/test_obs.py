@@ -309,6 +309,33 @@ def test_solo_serve_engine_rows_spans_counters(granite):
     assert counters["serve.terminal.completed"] == 4
 
 
+def _host_events(log_dir):
+    from jax.profiler import ProfileData
+
+    path = sorted(log_dir.glob("plugins/profile/*/*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    return [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in pd.planes if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events]
+
+
+def test_tick_spans_are_profiler_host_events_without_a_sink(granite,
+                                                            tmp_path):
+    eng = _engine(granite)
+    reqs = [_req(r, arrival=r // 2) for r in range(3)]
+    with jax.profiler.trace(str(tmp_path)):
+        outs, fin = eng.serve(reqs)  # the default NULL tracker
+    assert all(rec["status"] == "completed" for rec in fin.values())
+    events = _host_events(tmp_path)
+    ticks = [(a, b) for a, b, n in events if n == "tick"]
+    phases = [(a, b, n) for a, b, n in events if n.startswith("tick/")]
+    assert len(ticks) >= eng.last_stats["mixed_steps"] > 0
+    assert {"tick/admission", "tick/prefix", "tick/mixed_step",
+            "tick/host_sync", "tick/emit"} <= {n for _, _, n in phases}
+    for a, b, n in phases:
+        assert any(ta <= a and b <= tb for ta, tb in ticks), n
+
+
 def test_fleet_autoscales_up_under_overload_and_down_when_idle(granite):
     sink = MemorySink()
     eng = _engine(granite)
