@@ -24,10 +24,21 @@ tail blocks clamp to E-1), ``block_live (G, nb)`` (does the block hold
 any valid row) and ``prev_live (G, nb)`` (the most recent live block at
 or before m; 0 when none), are prefetched into SMEM and drive the
 x/weight BlockSpec index maps — so row-block m fetches exactly its
-owner's weight tiles, and consecutive blocks of the same expert reuse
-the resident tiles. Dead blocks skip all matmuls via scalar ``pl.when``
+owner's weight tiles. Dead blocks skip all matmuls via scalar ``pl.when``
 (their output/grad rows are written as zeros), making compute
 proportional to the *filled* rows.
+
+**Whole-expert windows:** consecutive blocks of one expert reuse the
+resident weight tiles only when the block's last window equals the next
+block's first, i.e. with one f and one d tile (``nf = nd = 1``). With
+several, every block walks all its ``(fi, di)`` windows again and the
+expert's whole weight set streams once per ``bm`` rows. Tiles left to
+the caller (``bf = bd = None``) therefore come from
+``tiling.grouped_expert_tiles``: ``bf = fp, bd = dp`` wherever the
+double-buffered forward and dx windows fit the scoped VMEM limit, so an
+expert's weights are fetched once per segment; the dW kernel takes the
+widest f window its own set fits. Shapes too wide for that keep the
+tuned tiles of ``tune_expert_tiles``.
 
 **Compacted block walk (bytes ragged like FLOPs):** a dead block's grid
 steps pin every *input* index map to the previous live block's final
@@ -58,7 +69,7 @@ recomputed in-kernel:
   expert_mlp's dx kernel (phase 1 re-accumulates a/g/dh, activation VJP
   at the phase boundary, phase 2 expands da/dg into a persistent
   (bm, d) f32 dx accumulator).
-* dW kernel — grid (G, nf, nb), row-blocks innermost. f32 VMEM
+* dW kernel — grid (G, f/bf_dw, nb), row-blocks innermost. f32 VMEM
   accumulators are zeroed at each expert-segment START (detected from
   the prefetched ``block_expert`` table: block m starts a segment iff
   ``be[m] != be[m-1]``), accumulated across the segment's blocks, and
@@ -82,7 +93,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.tiling import (
     check_mxu_alignment,
     clamp_tile,
-    tune_expert_tiles,
+    grouped_expert_tiles,
     VMEM_LIMIT_BYTES,
 )
 
@@ -184,15 +195,12 @@ def prev_live_table(block_live: jax.Array) -> jax.Array:
     return jnp.maximum(jax.lax.cummax(marked, axis=1), 0).astype(jnp.int32)
 
 
-def _resolve_tiles(bf, bd, f, d):
-    if bf is None or bd is None:
-        _, tbf, tbd = tune_expert_tiles(0, f, d)
-        bf = tbf if bf is None else bf
-        bd = tbd if bd is None else bd
-    return bf, bd
-
-
-def _clamp_tiles(bm, bf, bd, M, f, d, interpret):
+def _tiles(bm, bf, bd, xs, wi, wg, interpret):
+    """(bf, bd, bf_dw): explicit tiles where given (the dW kernel then
+    takes ``bf`` too), else ``grouped_expert_tiles``' choice by shape;
+    clamped to the dims and checked for the MXU."""
+    _, M, d = xs.shape
+    f = wi.shape[-1]
     # bm is a LAYOUT parameter (the caller aligned segments to it): it is
     # never clamped, only validated.
     if M % bm:
@@ -200,10 +208,18 @@ def _clamp_tiles(bm, bf, bd, M, f, d, interpret):
             f"ragged buffer rows ({M}) must be a multiple of the row "
             f"block bm={bm} (use ragged_buffer_rows to size the buffer)"
         )
-    bf = clamp_tile(bf, f, interpret)
-    bd = clamp_tile(bd, d, interpret)
-    check_mxu_alignment("grouped MLP", interpret, bm=bm, bf=bf, bd=bd)
-    return bf, bd
+    item = max(xs.dtype.itemsize, wi.dtype.itemsize)
+    rbf, rbd, bf_dw = grouped_expert_tiles(f, d, item, bm=bm,
+                                           gated=wg is not None)
+    bf_dw = bf_dw if bf is None else bf
+    bf = clamp_tile(rbf if bf is None else bf, f, interpret)
+    bd = clamp_tile(rbd if bd is None else bd, d, interpret)
+    bf_dw = clamp_tile(bf_dw, f, interpret)
+    if (f + (-f) % bf) % bf_dw:  # interpret mode clamps tiles to f exactly
+        bf_dw = bf
+    check_mxu_alignment("grouped MLP", interpret, bm=bm, bf=bf, bd=bd,
+                        bf_dw=bf_dw)
+    return bf, bd, bf_dw
 
 
 def _pad_fd(xs, wi, wg, wo, bf, bd):
@@ -325,8 +341,7 @@ def _grouped_mlp_pallas_tables(
 ):
     G, M, d = xs.shape
     E, _, f = wi.shape
-    bf, bd = _resolve_tiles(bf, bd, f, d)
-    bf, bd = _clamp_tiles(bm, bf, bd, M, f, d, interpret)
+    bf, bd, _ = _tiles(bm, bf, bd, xs, wi, wg, interpret)
     xs, wi, wg, wo, pf, pd = _pad_fd(xs, wi, wg, wo, bf, bd)
     fp, dp = f + pf, d + pd
     nb, nf, nd = M // bm, fp // bf, dp // bd
@@ -536,13 +551,13 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
     """Returns (dx, dwi, dwg, dwo); dwg is None when wg is None."""
     G, M, d = xs.shape
     E, _, f = wi.shape
-    bf, bd = _resolve_tiles(bf, bd, f, d)
-    bf, bd = _clamp_tiles(bm, bf, bd, M, f, d, interpret)
+    bf, bd, bf_dw = _tiles(bm, bf, bd, xs, wi, wg, interpret)
     xs, wi, wg, wo, pf, pd = _pad_fd(xs, wi, wg, wo, bf, bd)
     if pd:
         dy = jnp.pad(dy, ((0, 0), (0, 0), (0, pd)))
     fp, dp = f + pf, d + pd
     nb, nf, nd = M // bm, fp // bf, dp // bd
+    nf_dw = fp // bf_dw
     gated = wg is not None
     pl_tbl = prev_live_table(bl)
     x_map, wi_map, _ = _compact_walk_maps(nf, nd)
@@ -632,7 +647,7 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
         compiler_params=_COMPILER_PARAMS,
     )(be, bl, pl_tbl, *args)
 
-    # ---- dW: grid (G, nf, nb), row-blocks innermost --------------------
+    # ---- dW: grid (G, nf_dw, nb), row-blocks innermost -----------------
     # Outputs are PER GROUP (G, E, ...) — summed over G below; this is the
     # same contract the padded path gets from vmap'ing the dW kernel over
     # groups. Every expert owns >= 1 block per group (layout contract), so
@@ -655,24 +670,24 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
 
     in_specs = [
         pl.BlockSpec((1, bm, dp), dw_x_map),
-        pl.BlockSpec((1, dp, bf), dw_wi_map),
+        pl.BlockSpec((1, dp, bf_dw), dw_wi_map),
     ]
     args = [xs, wi]
     if gated:
-        in_specs.append(pl.BlockSpec((1, dp, bf), dw_wi_map))
+        in_specs.append(pl.BlockSpec((1, dp, bf_dw), dw_wi_map))
         args.append(wg)
-    in_specs.append(pl.BlockSpec((1, bf, dp), dw_wo_map))
+    in_specs.append(pl.BlockSpec((1, bf_dw, dp), dw_wo_map))
     args.append(wo)
     in_specs.append(pl.BlockSpec((1, bm, dp), dw_x_map))
     args.append(dy)
 
     out_specs = [
         pl.BlockSpec(
-            (1, 1, dp, bf),
+            (1, 1, dp, bf_dw),
             lambda g, fi, m, be, bl, pt: (g, be[g, m], 0, fi),
         ),
         pl.BlockSpec(
-            (1, 1, bf, dp),
+            (1, 1, bf_dw, dp),
             lambda g, fi, m, be, bl, pt: (g, be[g, m], fi, 0),
         ),
     ]
@@ -681,19 +696,19 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
         jax.ShapeDtypeStruct((G, E, fp, dp), wo.dtype),
     ]
     scratch = [
-        pltpu.VMEM((dp, bf), jnp.float32),  # dwi
-        pltpu.VMEM((bf, dp), jnp.float32),  # dwo
+        pltpu.VMEM((dp, bf_dw), jnp.float32),  # dwi
+        pltpu.VMEM((bf_dw, dp), jnp.float32),  # dwo
     ]
     if gated:
         out_specs.insert(
             1,
             pl.BlockSpec(
-                (1, 1, dp, bf),
+                (1, 1, dp, bf_dw),
                 lambda g, fi, m, be, bl, pt: (g, be[g, m], 0, fi),
             ),
         )
         out_shape.insert(1, jax.ShapeDtypeStruct((G, E, dp, fp), wg.dtype))
-        scratch.insert(1, pltpu.VMEM((dp, bf), jnp.float32))
+        scratch.insert(1, pltpu.VMEM((dp, bf_dw), jnp.float32))
 
     def dw_kernel(be_ref, bl_ref, pt_ref, *refs):
         if gated:
@@ -710,7 +725,7 @@ def _grouped_mlp_pallas_bwd(xs, wi, wg, wo, dy, be, bl, *, act: str,
 
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(G, nf, nb),
+        grid=(G, nf_dw, nb),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,

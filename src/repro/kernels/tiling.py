@@ -19,6 +19,11 @@ is evaluated against the per-core VMEM budget and the tile sizes are
 halved, largest contributor first, until the model fits. The dW kernel's
 ``6 * d * bf`` accumulator+output term is what drives ``bf`` down to 128
 at d_model >= 4096 (see kernels/README.md).
+
+The grouped-GEMM kernels first try whole-expert windows
+(``grouped_expert_tiles``), judged by a model that counts the
+pipeline's double buffers against the scoped limit they request, and
+fall back to ``tune_expert_tiles``.
 """
 from __future__ import annotations
 
@@ -28,9 +33,9 @@ from __future__ import annotations
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 # Scoped VMEM the expert-FFN kernels request from Mosaic (its default
 # scoped limit is the 16 MiB above). The pipeline double-buffers every
-# streamed window on top of the modeled resident set: the grouped dW
-# kernel at f32 and d_model 1024 (granite) needs 17 MiB, past the
-# default. A v5e core has 128 MiB of VMEM.
+# streamed window on top of the modeled resident set: with whole-expert
+# windows the grouped dW kernel at f32 and granite-3b widths (d 1536,
+# f 512) models 51 MiB (``grouped_vmem_bytes``). A v5e core has 128 MiB.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 MXU = 128
 
@@ -102,6 +107,62 @@ def tune_expert_tiles(
     return bc, bf, bd
 
 
+def grouped_vmem_bytes(
+    bm: int, bf: int, bd: int, d: int, itemsize: int, *, gated: bool = True,
+) -> tuple[int, int, int]:
+    """Modeled VMEM bytes of the grouped-GEMM kernels (forward, dx, dW)
+    at tiles ``(bm, bf, bd)`` (kernels/grouped_mlp.py).
+
+    Unlike ``expert_tile_vmem_bytes``, every window the pipeline streams
+    is counted twice (it double-buffers inputs and outputs) at the
+    operands' ``itemsize``, on top of the f32 scratch accumulators and
+    the largest f32 value a step builds in VMEM (forward: the hidden tile
+    and the full-d output product; dx: one d tile of the expansion; dW:
+    one ``(dp, bf)`` weight-gradient product).
+    """
+    dp = _align128(d)
+    nw = 2 if gated else 1  # wi (+ wg)
+    fwd = (2 * itemsize * (bm * bd + nw * bd * bf + bf * dp + bm * dp)
+           + 4 * (nw * bm * bf + bm * bf + bm * dp))
+    dx = (2 * itemsize * (2 * bm * bd + nw * bd * bf + bf * bd + bm * dp)
+          + 4 * ((nw + 1) * bm * bf + bm * dp + bm * bd))
+    dw = (2 * itemsize * (2 * bm * dp + 2 * (nw + 1) * dp * bf)
+          + 4 * (nw + 2) * dp * bf)
+    return fwd, dx, dw
+
+
+def grouped_expert_tiles(
+    f: int, d: int, itemsize: int, *, bm: int = 128, gated: bool = True,
+    limit_bytes: int = VMEM_LIMIT_BYTES,
+) -> tuple[int, int, int]:
+    """Pick ``(bf, bd, bf_dw)`` for the grouped-GEMM kernels by shape.
+
+    Whole-expert windows (``bf = fp``, ``bd = dp``: one f and one d tile)
+    when the forward and dx kernels' modeled sets (``grouped_vmem_bytes``)
+    fit ``limit_bytes`` less an eighth kept for Mosaic's own scratch. An
+    expert's weight windows are then the same for every row-block of its
+    segment, so the pipeline fetches them once per segment instead of
+    once per ``bm`` rows. The dW kernel (``bf_dw``) takes the widest
+    128-multiple divisor of ``fp`` its own model fits. A shape whose
+    whole windows do not fit keeps ``tune_expert_tiles``' tiles in all
+    three kernels.
+    """
+    fp, dp = _align128(f), _align128(d)
+    budget = limit_bytes - limit_bytes // 8
+    fwd, dx, _ = grouped_vmem_bytes(bm, fp, dp, d, itemsize, gated=gated)
+    if max(fwd, dx) > budget:
+        _, bf, bd = tune_expert_tiles(0, f, d)
+        return bf, bd, bf
+    bf_dw = fp
+    while bf_dw > MXU and (
+        fp % bf_dw
+        or grouped_vmem_bytes(bm, bf_dw, dp, d, itemsize, gated=gated)[2]
+        > budget
+    ):
+        bf_dw -= MXU
+    return fp, dp, bf_dw
+
+
 def grouped_walk_fwd_bytes(
     live_blocks: int, total_blocks: int, bm: int, d: int, f: int,
     n_weights: int = 3, *, compacted: bool = True, itemsize: int = 2,
@@ -110,9 +171,11 @@ def grouped_walk_fwd_bytes(
     (kernels/grouped_mlp.py), shared by benchmarks/roofline.py and
     benchmarks/kernels_micro.py.
 
-    Per visited row-block the walk streams its owner's full weight set
-    (``n_weights * d * f``: wi + wo, + wg when gated) and the block's
-    ``bm * d`` input rows; every block's output rows are written
+    This is the tiled walk (several f or d tiles): per visited row-block
+    it streams its owner's full weight set (``n_weights * d * f``: wi +
+    wo, + wg when gated; with the whole-expert windows of
+    ``grouped_expert_tiles`` they stream once per segment instead) and
+    the block's ``bm * d`` input rows; every block's output rows are written
     (dead blocks write zeros — part of the layout contract). The
     *static* walk streams x/weight tiles for dead blocks too; the
     *compacted* walk pins dead steps to the previous live block's

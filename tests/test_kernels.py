@@ -424,12 +424,23 @@ def _ragged_inputs(G, E, d, f, bm, gated, counts, key=KEY):
     return jnp.asarray(xs), wi, wg, wo, counts
 
 
+# Tile choices the grouped kernels are checked under: forced 8-wide f and
+# d tiles (several windows per expert), and the tiles left to
+# ``grouped_expert_tiles`` (whole-expert windows at these widths: one f
+# and one d tile, so an expert's weights stay resident across its blocks).
+GROUPED_TILINGS = pytest.mark.parametrize(
+    "tiles", [(8, 8), (None, None)], ids=["tiled", "whole"]
+)
+
+
+@GROUPED_TILINGS
 @pytest.mark.parametrize("case", GROUPED_CASES)
-def test_grouped_mlp_pallas_vs_ref(case):
+def test_grouped_mlp_pallas_vs_ref(case, tiles):
     G, E, d, f, bm, gated, act, counts = case
+    bf, bd = tiles
     xs, wi, wg, wo, counts = _ragged_inputs(G, E, d, f, bm, gated, counts)
     got = grouped_mlp_pallas(
-        xs, wi, wg, wo, counts, act=act, bm=bm, bf=8, bd=8, interpret=True
+        xs, wi, wg, wo, counts, act=act, bm=bm, bf=bf, bd=bd, interpret=True
     )
     want = ref.grouped_mlp_ref(xs, wi, wg, wo, counts, block=bm, act=act)
     np.testing.assert_allclose(
@@ -437,12 +448,14 @@ def test_grouped_mlp_pallas_vs_ref(case):
     )
 
 
+@GROUPED_TILINGS
 @pytest.mark.parametrize("case", GROUPED_CASES)
-def test_grouped_mlp_pallas_grad_vs_ref(case):
+def test_grouped_mlp_pallas_grad_vs_ref(case, tiles):
     """jax.grad through the grouped-GEMM custom VJP (scalar-prefetch dx +
     segment-walk dW kernels, interpret mode) matches the oracle's
     autodiff for every differentiable input."""
     G, E, d, f, bm, gated, act, counts = case
+    bf, bd = tiles
     xs, wi, wg, wo, counts = _ragged_inputs(G, E, d, f, bm, gated, counts)
     # Cotangent is zero on dead-block rows: the kernel skips them (dx = 0
     # by contract), while the oracle's autodiff would produce
@@ -457,7 +470,7 @@ def test_grouped_mlp_pallas_grad_vs_ref(case):
 
     def loss_pallas(xs, wi, wg, wo):
         y = grouped_mlp_pallas_vjp(
-            xs, wi, wg, wo, counts, act=act, bm=bm, bf=8, bd=8,
+            xs, wi, wg, wo, counts, act=act, bm=bm, bf=bf, bd=bd,
             interpret=True,
         )
         return jnp.sum(y * cot)
@@ -575,6 +588,38 @@ def test_tune_expert_tiles_vmem_budget():
     assert expert_tile_vmem_bytes(bc, bf, bd, 4096) <= VMEM_BUDGET_BYTES
     # tuned tiles stay MXU-aligned
     assert bc % 128 == bf % 128 == bd % 128 == 0
+
+
+@pytest.mark.parametrize(
+    "f, d, itemsize, whole",
+    [
+        (512, 1536, 4, True),  # granite-3b experts, float32
+        (512, 1024, 4, True),  # granite-1b experts, float32
+        (14336, 4096, 4, False),  # too wide to hold one expert
+    ],
+    ids=["granite3b-f32", "granite1b-f32", "d4096-f14336-f32"],
+)
+def test_grouped_expert_tiles_by_shape(f, d, itemsize, whole):
+    """Whole-expert windows (one f and one d tile) where the double-
+    buffered forward and dx sets fit the scoped VMEM limit; otherwise
+    exactly the tuned tiles of the padded kernels, in all three."""
+    from repro.kernels.tiling import (
+        VMEM_LIMIT_BYTES,
+        grouped_expert_tiles,
+        grouped_vmem_bytes,
+        tune_expert_tiles,
+    )
+
+    bf, bd, bf_dw = grouped_expert_tiles(f, d, itemsize)
+    if whole:
+        assert (bf, bd) == (-(-f // 128) * 128, -(-d // 128) * 128)
+        fwd, dx, _ = grouped_vmem_bytes(128, bf, bd, d, itemsize)
+        dw = grouped_vmem_bytes(128, bf_dw, bd, d, itemsize)[2]
+        assert max(fwd, dx, dw) < VMEM_LIMIT_BYTES
+        assert bf % bf_dw == 0 and bf_dw % 128 == 0
+    else:
+        _, tbf, tbd = tune_expert_tiles(0, f, d)
+        assert (bf, bd, bf_dw) == (tbf, tbd, tbf)
 
 
 def test_tune_attention_tiles_vmem_budget():

@@ -91,18 +91,32 @@ def _grouped(xs, wi, wg, wo, sizes):
     return grouped_mlp_pallas_vjp(xs, wi, wg, wo, sizes, interpret=False)
 
 
-def _grouped_args(shape):
-    m = ragged_buffer_rows(B * S * TOP_K, E, 128)
-    return (shape((1, m, D)), shape((E, D, F)), shape((E, D, F)),
-            shape((E, F, D)), shape((1, E), I32))
+# (groups, assignment rows per group, experts, d_model, d_ff): the smoke
+# train batch at granite-1b widths, and the granite-3b fine-tune cell's
+# step (2 x 4096 tokens, top-8 of 40 experts), whose whole-expert weight
+# windows are the largest the tile rule picks on this path.
+GROUPED_SHAPES = pytest.mark.parametrize(
+    "dims",
+    [(1, B * S * TOP_K, E, D, F), (2, 4096 * TOP_K, 40, 1536, 512)],
+    ids=["granite1b", "granite3b-finetune"],
+)
 
 
-def test_grouped_mlp_forward(shape):
-    _compiled_kernel(_grouped, *_grouped_args(shape))
+def _grouped_args(shape, dims):
+    g, n, e, d, f = dims
+    m = ragged_buffer_rows(n, e, 128)
+    return (shape((g, m, d)), shape((e, d, f)), shape((e, d, f)),
+            shape((e, f, d)), shape((g, e), I32))
 
 
-def test_grouped_mlp_backward(shape):
-    _compiled_kernel(_grad(_grouped, 4), *_grouped_args(shape))
+@GROUPED_SHAPES
+def test_grouped_mlp_forward(shape, dims):
+    _compiled_kernel(_grouped, *_grouped_args(shape, dims))
+
+
+@GROUPED_SHAPES
+def test_grouped_mlp_backward(shape, dims):
+    _compiled_kernel(_grad(_grouped, 4), *_grouped_args(shape, dims))
 
 
 def _expert(xe, wi, wg, wo):
