@@ -3,13 +3,17 @@
 Never from tiles or padding: a kernel that stops re-streaming weights or
 computing padded rows shows a higher roofline share, and no reading can
 pass 100%. ``dims`` is ``model.dims_of(config)``; ``item`` is the bytes
-of one element of the stored tensors (4 for float32).
+of one element of the stored tensors (4 for float32). The kernel counts
+read only the shared keys of ``dims`` (``bench/families/granite.py``
+lists them); a family's new kernel brings its counts in its own reader.
 
 Conventions: a matmul of (m, k) by (k, n) is 2mkn operations. Causal
 attention over S positions costs half the score matrix: one QK^T or PV
 product of a head is ``S * S * dh`` operations (2 * S * S/2 * dh).
 """
 from __future__ import annotations
+
+import model
 
 
 def least_time(flops: float, nbytes: float, peak: dict) -> float:
@@ -18,13 +22,12 @@ def least_time(flops: float, nbytes: float, peak: dict) -> float:
 
 
 # -- whole model ----------------------------------------------------------------
+# The architecture's counts are its family's (``bench/families/``): the
+# same numbers for every reader that holds a ``dims``.
 
 def matmul_params_per_token(dims: dict) -> int:
-    """Weights one token multiplies through in the decoder stack: the
-    attention projections, its top-k experts and the router."""
-    d, H, Kh, dh, f = (dims[k] for k in ("d", "H", "Kh", "dh", "f"))
-    attn = d * (H + 2 * Kh) * dh + H * dh * d
-    return dims["L"] * (attn + dims["k"] * 3 * d * f + d * dims["E"])
+    """Weights one token multiplies through in the decoder stack."""
+    return model.family_of(dims).matmul_params_per_token(dims)
 
 
 def head_flops(dims: dict, rows: int) -> float:
@@ -32,9 +35,9 @@ def head_flops(dims: dict, rows: int) -> float:
 
 
 def attn_flops(dims: dict, ctx_sum: float) -> float:
-    """QK^T and PV of every layer for query rows whose key counts sum to
-    ``ctx_sum``."""
-    return 4.0 * dims["L"] * dims["H"] * dims["dh"] * ctx_sum
+    """Attention's score and value products of every layer for query
+    rows whose key counts sum to ``ctx_sum``."""
+    return model.family_of(dims).attn_flops(dims, ctx_sum)
 
 
 def train_step_flops(dims: dict, batch: int, seq: int) -> float:

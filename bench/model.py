@@ -1,5 +1,6 @@
-"""A configuration file -> the program's ``ArchConfig``, the reference's
-sizes, and weights made from a seed.
+"""A configuration file -> its family (``bench/families/``), the
+program's ``ArchConfig``, the reference's sizes, and weights made from a
+seed.
 
 The benchmark makes the dense parent itself (``reference.dense_parent``)
 and hands it to the program's ``upcycle_params``: the users' first step,
@@ -8,61 +9,56 @@ own code, so it takes no weight the program made.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 import jax
 
-from reference import granite as ref
+FAMILIES = Path(__file__).resolve().parent / "families"
 
 
 def load_config(path: Path) -> dict:
     return json.loads(Path(path).read_text())
 
 
+def load_by_path(key: str, path: Path):
+    """The module in the file ``path``, loaded once as
+    ``sys.modules[key]``, so that a name such as ``trace`` cannot resolve
+    to another module of that name."""
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[key]
+
+
+def family_of(conf: dict):
+    """The family module that ``conf`` (a configuration file or its
+    ``dims``) names under ``"family"``: ``bench/families/<family>.py``.
+    A missing or unknown family is an error that names the families on
+    disk; there is no default."""
+    name = conf.get("family")
+    path = FAMILIES / f"{name}.py"
+    if not (isinstance(name, str) and name.isidentifier()
+            and path.is_file()):
+        have = sorted(p.stem for p in FAMILIES.glob("*.py"))
+        raise ValueError(f"family {name!r} of {conf.get('name', 'dims')!r}"
+                         f" is not one of {FAMILIES}: {have}")
+    return load_by_path(f"bench_family_{name}", path)
+
+
 def dims_of(conf: dict) -> dict:
-    """The sizes and settings the reference and the FLOP counts read."""
-    m = conf["moe"]
-    return {
-        "d": conf["hidden_size"], "L": conf["num_hidden_layers"],
-        "H": conf["num_attention_heads"], "Kh": conf["num_key_value_heads"],
-        "dh": conf["head_dim"], "f": conf["intermediate_size"],
-        "E": conf["num_local_experts"], "k": conf["num_experts_per_tok"],
-        "V": conf["vocab_size"], "theta": float(conf["rope_theta"]),
-        "eps": float(conf["rms_norm_eps"]),
-        "group": m["group_size"], "aux_weight": m["aux_loss_weight"],
-        "router_std": m["router_init_std"], "noise_std": m["init_noise_std"],
-        "expert_init": m["expert_init"],
-    }
+    """The family's sizes and settings of ``conf``, with the family's
+    name, so that whatever is handed ``dims`` finds the family again."""
+    return {**family_of(conf).dims_of(conf), "family": conf["family"]}
 
 
 def arch_of(conf: dict):
-    """The program's ArchConfig for this file: every size from the file,
-    dropless routing (capacity factor = expert count)."""
-    from repro.configs import ArchConfig, MoECfg
-
-    m = conf["moe"]
-    if conf["hidden_act"] != "silu" or not conf["tie_word_embeddings"]:
-        raise ValueError("bench configurations are tied SwiGLU decoders")
-    E = conf["num_local_experts"]
-    moe = MoECfg(
-        num_experts=E, router="top_k", top_k=conf["num_experts_per_tok"],
-        capacity_factor=float(E), layer_pattern="all",
-        group_size=m["group_size"], aux_loss_weight=m["aux_loss_weight"],
-        normalize_combine_weights=False, expert_init=m["expert_init"],
-        init_noise_std=m["init_noise_std"],
-        router_init_std=m["router_init_std"],
-    )
-    return ArchConfig(
-        name=conf["name"], family="moe", structure="decoder_only",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], d_head=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        gated_mlp=True, norm="rmsnorm", pos_emb="rope",
-        rope_theta=float(conf["rope_theta"]), tie_embeddings=True, moe=moe,
-        act="silu", source=conf["source"],
-    )
+    """The program's ``ArchConfig`` for ``conf``, by its family."""
+    return family_of(conf).arch_of(conf)
 
 
 def key_of(seed: int):
@@ -79,6 +75,7 @@ def program_weights(cfg, dims):
     from repro.models import model_zoo as zoo
     from repro.models import param as pm
 
+    ref = family_of(dims).reference
     dense_cfg = cfg.dense_parent()
     axes = pm.split(jax.eval_shape(
         lambda: zoo.init_params(jax.random.PRNGKey(0), dense_cfg)))[1]
@@ -94,6 +91,8 @@ def program_weights(cfg, dims):
 
 def reference_weights(dims):
     """Jitted ``seed key -> MoE value tree`` by the reference alone."""
+    ref = family_of(dims).reference
+
     def build(key):
         k_dense, k_moe = jax.random.split(key)
         return ref.upcycle(ref.dense_parent(k_dense, dims), k_moe, dims)

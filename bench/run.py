@@ -4,16 +4,18 @@
 
 Run from the root of a checkout on a machine with the TPU chips the cell
 asks for; it refuses to run anywhere else. A cell names a configuration
-(``bench/configs/<name>.json``) and a traffic mix
-(``bench/traffic/<name>.json``); per-layer metrics are readers in
+(``bench/configs/<name>.json``, which names its family,
+``bench/families/<family>.py``) and a traffic mix
+(``bench/traffic/<name>.json``, whose ``kind`` names its driver,
+``bench/<kind>.py``); per-layer metrics are readers in
 ``bench/metrics/<name>.py``. Set-up makes the weights from ``--seed``
 and warms up the cell's shapes; the window then measures ``--seconds``.
 With ``--trace 1`` a few seconds of the window are profiled and the
 per-layer metrics reported instead of the end-to-end ones. Afterwards
-what the timed path produced is compared with the plain reference in
-``bench/reference/``; each compared number is printed beside its limit,
-last on standard error and under ``checks`` in the result line, the last
-line of standard output.
+what the timed path produced is compared with the family's plain
+reference in ``bench/reference/``; each compared number is printed
+beside its limit, last on standard error and under ``checks`` in the
+result line, the last line of standard output.
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
-import importlib.util  # noqa: E402
+import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import sys  # noqa: E402
@@ -111,16 +113,21 @@ def peak_of(kind: str) -> dict:
 
 
 def bench_module(name: str, path: Path | None = None):
-    """Load a module of the benchmark by its file, so a name such as
-    ``trace`` cannot resolve to another module of that name."""
-    path = path or BENCH / f"{name}.py"
-    key = f"bench_{name}"
-    if key not in sys.modules:
-        spec = importlib.util.spec_from_file_location(key, path)
-        mod = importlib.util.module_from_spec(spec)
-        sys.modules[key] = mod
-        spec.loader.exec_module(mod)
-    return sys.modules[key]
+    """Load a module of the benchmark by its file."""
+    return model.load_by_path(f"bench_{name}", path or BENCH / f"{name}.py")
+
+
+def driver_of(mix: dict):
+    """The driver module the mix's ``kind`` names: ``bench/<kind>.py``
+    (``train``, ``serve``), whose ``run(conf, mix, args, clock, t_start,
+    log)`` sets up, measures and checks one run. A family whose cells
+    need a driver of their own brings it as a new file and a mix that
+    names it."""
+    kind = mix["kind"]
+    if not (kind.isidentifier() and (BENCH / f"{kind}.py").is_file()):
+        raise SystemExit(f"run.py: mix kind {kind!r} names no driver "
+                         f"bench/{kind}.py")
+    return importlib.import_module(kind)
 
 
 def read_layer_metric(name: str, ctx):
@@ -175,11 +182,7 @@ def measure(bench, cell, conf, mix, args, devices, peak, clock,
         args.trace_dir = (Path(stack.enter_context(
             tempfile.TemporaryDirectory(prefix="bench_trace_")))
             if args.trace else None)
-        if mix["kind"] == "serve":
-            import serve as driver
-        else:
-            import train as driver
-        res = driver.run(conf, mix, args, clock, t_start, log)
+        res = driver_of(mix).run(conf, mix, args, clock, t_start, log)
         log(f"[setup] setup_s={res['setup_s']!r} compiles={clock.compiles} "
             f"compile_s={clock.compile_s!r} cache_hits={clock.cache_hits} "
             f"cache_load_s={clock.cache_load_s!r}")

@@ -22,7 +22,6 @@ import numpy as np
 
 import model
 import traffic
-from reference import granite as ref
 
 WARM_RID = 1 << 40  # warm-up requests' ids, above every traffic rid
 TERMINAL = ("completed", "shed", "timeout", "failed", "cancelled")
@@ -232,7 +231,7 @@ def warm_up(sess, conf, rng):
 
     s = conf["serve"]
     n = s["chunk_size"] * s["chunks_per_step"] + 1
-    prompt = rng.integers(1, conf["vocab_size"], n).tolist()
+    prompt = rng.integers(1, model.dims_of(conf)["V"], n).tolist()
     sess.submit(Request(rid=WARM_RID, prompt=prompt, max_new=2,
                         arrival=sess.step))
     while sess.has_work:
@@ -265,6 +264,7 @@ def ref_gaps(dims: dict, seed: int, seqs: list, *, control: bool = False):
     With ``control``, the gap of the token a bfloat16 reference would
     put first instead. Sequences run one at a time, padded to a multiple
     of 1024."""
+    ref = model.family_of(dims).reference
     w = model.reference_weights(dims)(model.key_of(seed))
 
     def gaps(w, toks, served):

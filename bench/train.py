@@ -22,7 +22,6 @@ import numpy as np
 
 import model
 import traffic
-from reference import granite as ref
 
 CHECK_STEPS = 3
 
@@ -135,9 +134,10 @@ def build_step(cfg, mix):
 
 def reference_steps(dims, mix, seed, data, steps=CHECK_STEPS, *,
                     dtype=jnp.float32, batch_fn=None):
-    """The reference's first ``steps`` steps from the seed's weights:
-    losses, the first gradient's leaf norms, and the leaf norms of the
-    parameters' change after the last step."""
+    """The family's reference through its first ``steps`` steps from the
+    seed's weights: losses, the first gradient's leaf norms, and the leaf
+    norms of the parameters' change after the last step."""
+    ref = model.family_of(dims).reference
     make_w = model.reference_weights(dims)
     key = model.key_of(seed)
     batch_fn = batch_fn or (lambda i: data.batch(i))
