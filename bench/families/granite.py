@@ -5,8 +5,8 @@ A configuration file names its family (``"family": "granite"``), and
 family is what the shared harness may not know about an architecture;
 another one joins as new files beside this one (its family file, its
 reference under ``bench/reference/``, its configuration and traffic
-files and readers for its own kernels), with no edit to a file the
-harness has. A family file provides:
+files, and readers for its own kernels, scopes and counters), with no
+edit to a file the harness has. A family file provides:
 
 * ``dims_of(conf)`` -- the sizes and settings its reference and counts
   read, from the configuration file. The shared readers (the kernel
@@ -27,6 +27,21 @@ harness has. A family file provides:
 * ``matmul_params_per_token(dims)`` and ``attn_flops(dims, ctx_sum)`` --
   the whole-model counts that ``flops.train_step_flops`` and
   ``flops.serve_flops`` (and so the step MFU readers) take.
+* ``SCOPES`` (optional) -- a tuple of the further ``jax.named_scope``
+  names that the family's program path sets beyond the shared ones of
+  ``bench/scopes.py`` (a latent projection, a shared expert, a mixer
+  that is not attention). The traced split counts them after the shared
+  scopes, and a reader takes one's share as
+  ``scopes.of(ctx).share(("<scope>",))``. A name that repeats a shared
+  scope is refused.
+
+What the family's own readers (``bench/metrics/<name>.py``, ``read(ctx)``)
+can read besides the trace: in a traced training run,
+``ctx.res["step_metrics"]`` holds the scalar metrics the program's train
+step returns (``loss``, ``ce``, ``aux_loss``, ``dropped_frac_sum``,
+``moe_layer_count``, ``grad_norm`` and any counter the program adds),
+``{name: [one float per traced step]}``. A reader whose counter is not
+there returns None, and the run then stops naming it.
 
 Granite: a tied SwiGLU decoder, every layer MoE, grouped-query attention
 with one head width, full causal attention in every layer.

@@ -1,22 +1,34 @@
 """The train step's device time by the parts the program names.
 
-The program names its parts with ``jax.named_scope`` (``SCOPES``). A scope
-changes only the ``op_name`` metadata of the HLO it encloses, never the
-compiled code. The compiled module's text prints that metadata on every
+The program names its parts with ``jax.named_scope``. A scope changes
+only the ``op_name`` metadata of the HLO it encloses, never the compiled
+code. The compiled module's text prints that metadata on every
 instruction (``%fusion.3 = ... metadata={op_name="jit(train_step)/jvp()/
 while/body/closed_call/moe.dispatch/sort"}``), and the device trace names
 each op by its instruction, so the text maps a traced op to its path. A
 fusion carries the op_name of its root instruction: a fused op belongs
 to the scope of its fusion root.
 
-Each op of the traced window counts its self time (a loop's event less
-the events of its body, as ``trace.self_times``) under the innermost
-scope of its path, or ``unscoped``, and in one phase: ``recompute`` where
-the path passes ``rematted_computation`` (the forward that ``remat``
-runs again inside the backward pass), ``bwd`` where it passes a
-``transpose(`` (the backward), ``fwd`` otherwise. An op the text does
-not name is unresolved; unresolved ops over ``MAX_UNRESOLVED`` of the
-busy time stop the run, naming them.
+The scopes a split counts are the shared ones, ``SCOPES`` (the program's
+parts every family's step has), followed by the family's own: a family
+file (``bench/families/<family>.py``) may list in its ``SCOPES`` the
+further scope names its program path sets (a latent projection, a
+shared expert, a mixer that is not attention). A family scope that
+repeats a shared name is refused. Readers take a scope's share from
+``of(ctx)`` whichever list it came from.
+
+Each op of the traced window counts its self time under the innermost
+listed scope of its path, or ``unscoped``, and in one phase:
+``recompute`` where the path passes ``rematted_computation`` (the
+forward that ``remat`` runs again inside the backward pass), ``bwd``
+where it passes a ``transpose(`` (the backward), ``fwd`` otherwise.
+Self time (``trace.self_times``) charges each instant of a device plane
+to the innermost op open then, the latest started: a loop's event counts
+the time between the events of its body, an op that another outlives
+loses only the overlap, no op's self time is negative, and the self
+times of a plane sum to its busy time. An op the text does not name is
+unresolved; unresolved ops over ``MAX_UNRESOLVED`` of the busy time stop
+the run, naming them.
 
 The text comes from compiling the cell's step again after the window,
 in traced runs only: the same lowering as the timed step's, so the
@@ -30,8 +42,9 @@ import sys
 import time
 from dataclasses import dataclass, field
 
-# The program's scopes (src/repro: models/layers.py, models/attention.py,
-# core/moe.py, models/model_zoo.py, training/train_loop.py).
+# The shared scopes: the program's parts every family's step has
+# (src/repro: models/layers.py, models/attention.py, core/moe.py,
+# models/model_zoo.py, training/train_loop.py).
 SCOPES = ("embed", "attn", "kv.write", "moe.route", "moe.dispatch",
           "moe.experts", "moe.combine", "lm_head", "loss", "sample",
           "optimizer")
@@ -99,17 +112,37 @@ def _path(comps, comp: str, name: str) -> str:
     return ""
 
 
-def scope_of(path: str) -> str:
-    """The innermost of ``SCOPES`` on the path. A scope at the top of a
+def scope_of(path: str, scopes: tuple = SCOPES) -> str:
+    """The innermost of ``scopes`` on the path. A scope at the top of a
     differentiated function shows wrapped in its transforms
     (``jvp(loss)``, ``transpose(jvp(lm_head))``)."""
     found = UNSCOPED
     for seg in path.split("/"):
         while (m := _TRANSFORM.match(seg)):
             seg = m.group(1)
-        if seg in SCOPES:
+        if seg in scopes:
             found = seg
     return found
+
+
+def scopes_of(dims: dict) -> tuple:
+    """The shared scopes, then those of the family that ``dims`` names
+    (its ``SCOPES``, none where it lists none). A family scope that
+    repeats a shared name, ``unscoped`` or one of its own is refused."""
+    import model
+
+    family = model.family_of(dims)
+    own = tuple(getattr(family, "SCOPES", ()))
+    for i, s in enumerate(own):
+        clash = ("the shared scope" if s in SCOPES
+                 else "the name of unscoped time" if s == UNSCOPED
+                 else "its own scope" if s in own[:i] else None)
+        if clash:
+            raise ValueError(
+                f"scopes: family {dims['family']!r} ({family.__file__}) "
+                f"lists scope {s!r}, which repeats {clash} {s!r} "
+                f"(shared scopes: {SCOPES})")
+    return SCOPES + own
 
 
 def phase_of(path: str) -> str:
@@ -123,6 +156,7 @@ class Split:
     """Device seconds of the traced window by (scope, phase), averaged
     over the device planes, and of the ops the text does not name."""
     busy_s: float
+    scopes: tuple = SCOPES  # in the order the table lists them
     seconds: dict[tuple[str, str], float] = field(default_factory=dict)
     unresolved: dict[str, float] = field(default_factory=dict)
 
@@ -141,7 +175,7 @@ class Split:
         rows = {}
         for (sc, ph), v in self.seconds.items():
             rows.setdefault(sc, dict.fromkeys(PHASES, 0.0))[ph] += v
-        order = [s for s in SCOPES + (UNSCOPED,) if s in rows]
+        order = [s for s in self.scopes + (UNSCOPED,) if s in rows]
         return {s: rows[s] for s in order}
 
     def line(self, **extra) -> str:
@@ -150,13 +184,14 @@ class Split:
              **extra, "seconds": self.table()})
 
 
-def split(red, names: dict[str, str]) -> Split:
+def split(red, names: dict[str, str], scopes: tuple = SCOPES) -> Split:
     """``red``: a ``trace.Reduced``; ``names``: ``op_names`` of the
-    module that ran in its window."""
+    module that ran in its window; ``scopes``: the scopes to count, as
+    ``scopes_of`` gives them."""
     # the module that reduced the trace (bench/trace.py)
     trace = sys.modules[type(red).__module__]
     lo, hi = red.window
-    out = Split(red.busy_s)
+    out = Split(red.busy_s, scopes)
     k = len(red.devices)
     for ops in red.devices:
         clipped = [trace.Op(o.name, max(o.start, lo),
@@ -168,7 +203,7 @@ def split(red, names: dict[str, str]) -> Split:
                     + own / k
                 continue
             path = names[o.name]
-            key = (scope_of(path), phase_of(path))
+            key = (scope_of(path, scopes), phase_of(path))
             out.seconds[key] = out.seconds.get(key, 0.0) + own / k
     return out
 
@@ -209,7 +244,8 @@ def of(ctx):
         t0 = time.perf_counter()
         text = getattr(ctx, "hlo", None) or train_step_hlo(ctx.conf,
                                                              ctx.mix)
-        got = ctx.scopes = split(ctx.trace, op_names(text))
+        got = ctx.scopes = split(ctx.trace, op_names(text),
+                                 scopes_of(ctx.dims))
         print(got.line(hlo_s=time.perf_counter() - t0), file=sys.stderr,
               flush=True)
         if got.unresolved_s > MAX_UNRESOLVED * got.busy_s:

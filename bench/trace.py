@@ -14,6 +14,8 @@ clipped to it.
 from __future__ import annotations
 
 import bisect
+import heapq
+import math
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -123,18 +125,40 @@ _INSTRUCTION = re.compile(r"%?([^\s=]+) = ")
 
 
 def self_times(ops: list[Op]) -> list[tuple[Op, float]]:
-    """Each op with its duration less that of the ops it encloses."""
-    out: list[list] = []
-    stack: list[list] = []
-    for o in sorted(ops, key=lambda o: (o.start, -o.dur)):
-        while stack and stack[-1][0].start + stack[-1][0].dur <= o.start:
-            stack.pop()
-        entry = [o, o.dur]
-        if stack:
-            stack[-1][1] -= o.dur
-        stack.append(entry)
-        out.append(entry)
-    return [(o, own) for o, own in out]
+    """Each op of one plane with its self time: every instant is charged
+    to the innermost op open then, the latest started (of two starting
+    together, the shorter) among those still running. An op's self time
+    is so its duration less the parts of it that ops starting inside it
+    cover, each part clipped to the op's own end: never negative, and
+    over the plane the self times sum to the union of the op intervals.
+    Where ops nest properly, that is each op's duration less those of
+    the ops it encloses. Where an op outlives the op it started in (one
+    that starts where the op before it ends, which rounding the trace's
+    nanoseconds to float seconds can put inside that op, or one that
+    truly overlaps it), the earlier op loses only the overlap, not the
+    later op's whole duration."""
+    order = sorted(ops, key=lambda o: (o.start, -o.dur))
+    own = [0.0] * len(order)
+    open_: list[tuple[int, float]] = []  # (-rank, end): innermost first
+    t = -math.inf
+
+    def charge(until):
+        nonlocal t
+        while open_ and t < until:
+            rank, end = open_[0]
+            if end <= t:
+                heapq.heappop(open_)
+                continue
+            nxt = min(end, until)
+            own[-rank] += nxt - t
+            t = nxt
+        t = max(t, until)
+
+    for rank, o in enumerate(order):
+        charge(o.start)
+        heapq.heappush(open_, (-rank, o.start + o.dur))
+    charge(math.inf)
+    return list(zip(order, own))
 
 
 def _op_name(ev) -> str:

@@ -9,6 +9,12 @@ host batch per step, one ``device_get`` of the metrics per step. The
 benchmark drives it itself because ``Trainer.run`` keeps its state
 local, and the check needs the optimizer state after step 1 and the
 parameters after step 3.
+
+While the profiler records, each traced step's scalar metrics (those the
+step returns, already on the host) are kept as floats: ``run`` returns
+them as ``step_metrics``, ``{name: [one value per traced step]}``, for
+readers of counters the program adds to its step; an untraced run keeps
+none.
 """
 from __future__ import annotations
 
@@ -210,6 +216,7 @@ def run(conf, mix, args, clock, t_start, log) -> dict:
     i, n, skipped = CHECK_STEPS, 0, 0
     compiles0 = clock.compiles
     traced, span = [], None
+    step_metrics: dict[str, list[float]] = {}
     t0 = time.perf_counter()
     while True:
         el = time.perf_counter() - t0
@@ -230,6 +237,9 @@ def run(conf, mix, args, clock, t_start, log) -> dict:
         i, n = i + 1, n + 1
         if span is not None:
             traced.append(i)
+            for k, v in mets.items():
+                if np.ndim(v) == 0:
+                    step_metrics.setdefault(k, []).append(float(v))
             if len(traced) >= mix["trace_steps"]:
                 span.__exit__(None, None, None)
                 jax.profiler.stop_trace()
@@ -256,6 +266,7 @@ def run(conf, mix, args, clock, t_start, log) -> dict:
         "setup_s": setup_s, "peak": peak,
         "metrics": {"train_tok_s": n * tokens / elapsed},
         "attempted": n, "failed": skipped, "checks": checks,
-        "traced_steps": len(traced), "data": data, "prog": prog,
+        "traced_steps": len(traced), "step_metrics": step_metrics,
+        "data": data, "prog": prog,
         "want": want,
     }

@@ -7,7 +7,10 @@ And a family that exists only as new files -- a copy of ``bench/`` with
 a family, its configurations, traffic and ``BENCHMARK.json`` entries
 added and no file edited -- runs a train and a chat cell ``correct``,
 while a wrong reference in such a family fails them: the check uses the
-family's own reference.
+family's own reference. Such a family also brings its own scopes and
+readers: the traced split counts its scope, a reader reads that scope's
+share and another a counter of the train step's, and a family scope
+that repeats a shared one is refused.
 """
 import hashlib
 import json
@@ -164,6 +167,76 @@ print(json.dumps(out))
 # family -> its reference module: granite's own, or one that renormalises
 # the top-k weights (granite's recipe leaves them as the softmax gives)
 NEW = {"granite_alias": "granite", "granite_renorm": "granite_renorm"}
+# a family with a scope of its own, and one whose scope repeats a shared
+# one; the first has a training cell and two readers of its own
+SCOPED = {"granite_scoped": ("attn.latent",),
+          "granite_clash": ("attn.latent", "moe.dispatch")}
+SCOPED_CELL = "granite_scoped.finetune"
+READERS = {
+    "granite_scoped.latent_share": '''"""Share of busy time under the
+family's own scope."""
+import scopes
+
+
+def read(ctx):
+    split = scopes.of(ctx)
+    return None if split is None else split.share(("attn.latent",))
+''',
+    "granite_scoped.aux_loss": '''"""The traced steps' mean aux loss, a
+counter of the train step's."""
+import statistics
+
+
+def read(ctx):
+    got = ctx.res.get("step_metrics", {}).get("aux_loss")
+    return statistics.fmean(got) if got else None
+''',
+}
+# ops and their paths for the scoped cell's split: the family's scope
+# inside a shared one, at the top of a differentiated function, and an
+# unscoped loop (seconds of own time: attn 0.2, attn.latent 0.2 fwd +
+# 0.3 bwd, unscoped 0.1, of 0.8 busy)
+SCOPED_OPS = [("fusion.1", 0.0, 0.4, ".../attn/bshk,hkd->bsd"),
+              ("dot.2", 0.1, 0.2, ".../attn/attn.latent/dot"),
+              ("dot.3", 0.5, 0.3, "jit(train_step)/transpose("
+                                  "jvp(attn.latent))/dot"),
+              ("while.4", 0.8, 0.1, "jit(train_step)/while")]
+SCOPED_DRIVE = '''import json, sys, tempfile, time, types
+from pathlib import Path
+sys.path.insert(0, "bench")
+import model, run, scopes, train
+trace = run.bench_module("trace")
+bench = run.load_bench()
+cell, conf, mix = run.cell_of(bench, sys.argv[1])
+ops = json.loads(sys.argv[2])
+out = {}
+for t, kind in enumerate(("untraced", "traced")):
+    with tempfile.TemporaryDirectory() as d:
+        args = types.SimpleNamespace(seed=2 ** 31 + 78, seconds=1.5,
+                                     trace=t, trace_dir=Path(d))
+        res = train.run(conf, mix, args, run.CompileClock(),
+                        time.perf_counter(), run.log)
+    out[kind] = {"traced_steps": res["traced_steps"],
+              "step_metrics": res["step_metrics"]}
+red = trace.Reduced((0.0, 1.0), [[trace.Op(n, a, d) for n, a, d, _ in ops]])
+hlo = "ENTRY %main (p: f32[]) -> f32[] {\\n" + "\\n".join(
+    f\'  %{n} = f32[] op(), metadata={{op_name="{p}"}}\' for n, _, _, p in ops
+) + "\\n}"
+ctx = types.SimpleNamespace(dims=model.dims_of(conf), peak={}, item=4,
+                            trace=red, mix=mix, conf=conf, res=res, hlo=hlo)
+out["metrics"] = run.layer_metrics(bench, cell, ctx)
+out["table"] = ctx.scopes.table()
+ctx.res = {"traced_steps": 2}
+try:
+    run.layer_metrics(bench, cell, ctx)
+except SystemExit as e:
+    out["missing"] = str(e)
+try:
+    scopes.scopes_of(model.dims_of(conf | {"family": "granite_clash"}))
+except ValueError as e:
+    out["clash"] = str(e)
+print(json.dumps(out))
+'''
 
 
 def _tiny(**moe):
@@ -177,7 +250,8 @@ def _tiny(**moe):
 
 def _add_family_as_new_files(root):
     """A checkout at ``root``: ``bench/`` copied, then only files added
-    and entries appended to ``BENCHMARK.json``."""
+    and entries appended to ``BENCHMARK.json``. Returns the cells of
+    ``NEW``'s families."""
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (root / "src").symlink_to(ROOT / "src")
@@ -209,20 +283,54 @@ def _add_family_as_new_files(root):
             for m in metrics:
                 e2e[m]["workloads"].append(cell)
             cells.append(cell)
+    for fam, own in SCOPED.items():
+        (b / "families" / f"{fam}.py").write_text(
+            FAMILY.format(ref="granite") + f"SCOPES = {own!r}\n")
+    conf = _tiny(expert_init="copy") | {"name": "granite-scoped-tiny",
+                                        "family": "granite_scoped"}
+    path = f"bench/configs/{conf['name']}.json"
+    (root / path).write_text(json.dumps(conf))
+    bench["configs"].append({"name": conf["name"], "source": conf["source"],
+                             "file": path, "reduced": [], "why": "test size"})
+    bench["workloads"].append({"name": SCOPED_CELL, "config": conf["name"],
+                               "traffic": "tiny_train", "chips": 1,
+                               "why": "test size"})
+    e2e["train_tok_s"]["workloads"].append(SCOPED_CELL)
+    for name, src in READERS.items():
+        (b / "metrics" / f"{name}.py").write_text(src)
+        bench["per_layer"].append({
+            "name": name, "unit": "%", "better": "lower",
+            "source": "device_trace", "layer": "family",
+            "moves": "train_tok_s", "workloads": [SCOPED_CELL]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return cells
 
 
 @pytest.fixture(scope="module")
-def new_family_runs(tmp_path_factory):
+def checkout(tmp_path_factory):
     root = tmp_path_factory.mktemp("checkout")
-    cells = _add_family_as_new_files(root)
+    return root, _add_family_as_new_files(root)
+
+
+def _drive(root, script, *argv):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
-    out = subprocess.run([sys.executable, "-c", DRIVE, *cells], cwd=root,
+    out = subprocess.run([sys.executable, "-c", script, *argv], cwd=root,
                          env=env, capture_output=True, text=True,
                          timeout=900)
     assert out.returncode == 0, out.stderr[-4000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def new_family_runs(checkout):
+    root, cells = checkout
+    return _drive(root, DRIVE, *cells)
+
+
+@pytest.fixture(scope="module")
+def scoped_family_run(checkout):
+    return _drive(checkout[0], SCOPED_DRIVE, SCOPED_CELL,
+                  json.dumps(SCOPED_OPS))
 
 
 @pytest.mark.parametrize("kind", ["finetune", "chat"])
@@ -239,3 +347,38 @@ def test_a_family_added_as_new_files_runs_correct(new_family_runs, kind):
 def test_the_check_uses_the_family_s_own_reference(new_family_runs, kind):
     line = new_family_runs[f"granite_renorm.{kind}"]
     assert line["correct"] is False, line["checks"]
+
+
+def test_a_family_s_own_scope_counts_in_the_split(scoped_family_run):
+    got = scoped_family_run
+    assert got["metrics"]["granite_scoped.latent_share"]["value"] == \
+        pytest.approx(100 * 0.5 / 0.8)
+    # the family's scope follows the shared ones, unscoped time last
+    assert list(got["table"]) == ["attn", "attn.latent", "unscoped"]
+    assert got["table"]["attn"]["fwd"] == pytest.approx(0.2)
+    assert got["table"]["attn.latent"] == pytest.approx(
+        {"fwd": 0.2, "recompute": 0.0, "bwd": 0.3})
+
+
+def test_a_family_scope_that_repeats_a_shared_one_is_refused(
+        scoped_family_run):
+    msg = scoped_family_run["clash"]
+    assert "'granite_clash'" in msg
+    assert "repeats the shared scope 'moe.dispatch'" in msg
+
+
+def test_the_train_driver_hands_readers_the_traced_steps_counters(
+        scoped_family_run):
+    got = scoped_family_run
+    untraced, traced = got["untraced"], got["traced"]
+    assert untraced == {"traced_steps": 0, "step_metrics": {}}
+    n = traced["traced_steps"]
+    assert n == load(DATA / "tiny_train.json")["trace_steps"]
+    counters = traced["step_metrics"]
+    assert {"loss", "aux_loss", "dropped_frac_sum"} <= set(counters)
+    assert all(len(v) == n for v in counters.values()), counters
+    aux = counters["aux_loss"]
+    assert got["metrics"]["granite_scoped.aux_loss"]["value"] == \
+        pytest.approx(sum(aux) / n)
+    # a reader whose counter is missing reads nothing: the run stops
+    assert "'granite_scoped.aux_loss' read nothing" in got["missing"]

@@ -69,6 +69,22 @@ def test_scope_and_phase_of_a_path(path, scope, phase):
     assert scopes.phase_of(path) == phase
 
 
+LATENT = "jit(train_step)/jvp()/while/body/closed_call/attn/attn.latent/dot"
+
+
+@pytest.mark.parametrize("path,listed,scope", [
+    # a scope no list names folds into the enclosing listed one
+    (LATENT, scopes.SCOPES, "attn"),
+    (LATENT, scopes.SCOPES + ("attn.latent",), "attn.latent"),
+    ("jit(train_step)/transpose(jvp(attn.latent))/dot",
+     scopes.SCOPES + ("attn.latent",), "attn.latent"),
+    ("jit(f)/attn.latent/moe.route/top_k", scopes.SCOPES + ("attn.latent",),
+     "moe.route"),
+])
+def test_the_innermost_scope_of_the_list_given_wins(path, listed, scope):
+    assert scopes.scope_of(path, listed) == scope
+
+
 HLO = """HloModule m, is_scheduled=true
 
 %fused_computation.1 (param_0.1: f32[4]) -> (f32[4], f32[4]) {
