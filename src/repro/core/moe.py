@@ -15,8 +15,10 @@ token — top-k's k; rows below are per group, f = d_ff):
                                 unfilled slots           (G,E,cap,d) buf
   sorted    g*A + O(E*bm)       none; FFN FLOPs track    perf path: C > 1
             (independent of     *filled* rows only       or imbalanced
-            capacity factor C)  (ragged grouped GEMM)    Top-K; finetune/
-                                                         inference economy
+            capacity factor C)  (ragged grouped GEMM);   Top-K; finetune/
+                                rows move by gathers     inference economy
+                                alone for token-choice
+                                routers (no scatter)
   ========  ==================  =======================  =================
 
 ``einsum``/``gather`` build the padded ``(G, E, cap, d)`` capacity buffer
@@ -27,6 +29,19 @@ assignment stream by expert into a block-aligned ragged buffer
 kernel on TPU, per-group ``lax.ragged_dot`` on XLA. All three consume the
 same ``Routing`` decisions, so outputs/gradients agree to float tolerance
 (tests/test_moe.py parity sweeps).
+
+How ``sorted`` moves rows: the sort is a bijection between the surviving
+assignments and the live ragged rows, so for the token-choice routers
+(k slots per token) each side looks the other up by gather —
+``kernels.grouped_mlp.ragged_sources`` gives each row its assignment and
+combine weight, and each assignment its row. Dispatch gathers token rows
+into the buffer, and its transpose gathers each token's k row cotangents
+and sums them; the combine gathers each token's k weighted expert rows
+and sums them, and its transposes gather the output cotangent back onto
+the rows and read the weight gradient per row. No scatter is left, in
+either direction (on TPU XLA runs a scatter as a serial loop over its
+updates). Expert Choice, whose tokens appear a variable number of times,
+keeps scatter-built tables and a scatter-add combine.
 
 Sharding: the padded paths constrain dispatched buffers (G, E, cap, d) to
 ``_ expert cap embed`` — with experts on the ``model`` mesh axis this makes
@@ -132,24 +147,111 @@ def expert_ffn(experts, xe, cfg: ArchConfig, *, implementation="xla",
         )
 
 
+def _rows(x, idx):
+    """Per-group row gather: x (G, R, d), idx (G, ...) -> (G, ..., d)."""
+    gi = jnp.arange(x.shape[0]).reshape((-1,) + (1,) * (idx.ndim - 1))
+    return x[gi, idx]
+
+
+def _zero_row(x):
+    """x (G, R, ...) with a zero row R appended: (G, R + 1, ...). Index
+    R then gathers zeros, with no select pass over the gathered rows."""
+    return jnp.concatenate([x, jnp.zeros_like(x[:, :1])], axis=1)
+
+
+def _slot_rows(x, row_of):
+    """Each token's k ragged rows: x (G, M, d), row_of (G, g, k) ->
+    ((G, g, k, d) with dropped slots' rows clamped, (G, g, k) bool live)."""
+    M = x.shape[1]
+    return _rows(x, jnp.minimum(row_of, M - 1)), row_of < M
+
+
+@jax.custom_vjp
+def _dispatch_rows(xg, slot, row_of):
+    """xs[m] = xg[slot[m] // k] — ragged row m's token — and 0 on pad
+    rows (slot == N = g*k, i.e. token g: the appended zero row). The
+    transpose walks the sort's bijection the other way: dxg[t] =
+    sum_j dxs[row_of[t, j]], a gather and a sum over the token's k
+    slots instead of a scatter-add."""
+    return _rows(_zero_row(xg), slot // row_of.shape[-1])
+
+
+def _dispatch_rows_fwd(xg, slot, row_of):
+    return _dispatch_rows(xg, slot, row_of), (slot, row_of)
+
+
+def _dispatch_rows_bwd(res, dxs):
+    slot, row_of = res
+    rows, live = _slot_rows(dxs, row_of)
+    dxg = jnp.where(live[..., None], rows, jnp.zeros((), dxs.dtype)).sum(2)
+    return dxg, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, w, slot, row_of, wr):
+    """y[t] = sum_j w[t, j] * ys[row_of[t, j]] — each token gathers its
+    k weighted expert rows (dropped slots add 0). Both cotangents are
+    gathers too, of one row gather dyr[m] = dy[slot[m] // k] (0 on pad
+    rows): dys[m] = wr[m] * dyr[m], with ``wr`` the rows' weights
+    (w[slot[m]], 0 on pad rows), and the weight gradient to the router,
+    dw[t, j] = <ys, dyr>[row_of[t, j]] (0 where dropped)."""
+    rows, live = _slot_rows(ys, row_of)
+    wv = jnp.where(live, w, 0.0)
+    return (rows * wv[..., None]).sum(2)
+
+
+def _combine_rows_fwd(ys, w, slot, row_of, wr):
+    return _combine_rows(ys, w, slot, row_of, wr), (ys, slot, row_of, wr)
+
+
+def _combine_rows_bwd(res, dy):
+    ys, slot, row_of, wr = res
+    G, g, k = row_of.shape
+    dyr = _rows(_zero_row(dy), slot // k)  # dy[token of m], 0 on pad rows
+    dys = (dyr * wr[..., None]).astype(ys.dtype)
+    dwr = (ys * dyr).sum(-1)  # (G, M): <ys[m], dy[token of m]>
+    # dropped slots read the appended zero at row M
+    dw = jnp.take_along_axis(_zero_row(dwr), row_of.reshape(G, g * k),
+                             axis=1)
+    return dys, dw.reshape(G, g, k).astype(wr.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
                      ctx: Optional[ShardCtx], implementation: str,
                      block: int):
-    """Sorted ragged dispatch: argsort the flat assignment stream by
-    expert, run the contiguous ragged buffer through the grouped-GEMM
-    kernel, unsort via scatter-add combine. Returns y (G, g, d).
+    """Sorted ragged dispatch: sort the flat assignment stream by expert,
+    run the contiguous ragged buffer through the grouped-GEMM kernel, and
+    bring each token its rows back. Returns y (G, g, d).
 
     The ragged buffer has ``M = (ceil(N/block) + E) * block`` rows — N is
     the assignment count (g*k for token-choice), so FFN work is
     independent of capacity factor; capacity only decides WHICH
     assignments survive (the routers' keep masks, identical across
     dispatch paths).
+
+    Token-choice routers (``r.token_expert`` set: k slots per token) move
+    rows by gathers alone, in both directions and both transposes: the
+    sort is a bijection between surviving assignments and live ragged
+    rows, so ``ragged_sources`` gives every row its assignment (``slot``)
+    and combine weight (``wr``) and every assignment its row
+    (``row_of``), and each scatter the autodiff of a gather would emit
+    has a gather through the other table instead (``_dispatch_rows``,
+    ``_combine_rows``). Expert Choice has no fixed slots per token (its
+    stream is expert-major, a token appears a variable number of times),
+    so it keeps the scatter-built tables and the scatter-add combine.
     """
     from repro.kernels import ops
-    from repro.kernels.grouped_mlp import ragged_destinations
+    from repro.kernels.grouped_mlp import ragged_destinations, ragged_sources
 
     G, g, d = xg.shape
     E = moe.num_experts
+    token_choice = r.token_expert is not None
 
     with jax.named_scope("moe.dispatch"):
         # Flat per-group assignment stream (token id, expert id, weight)
@@ -160,31 +262,37 @@ def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
         key = jnp.where(valid, eid, E).astype(jnp.int32)
 
         # Stable sort by expert (dropped assignments -> key E, past the
-        # last segment) and block-aligned ragged destinations — the
-        # layout math shared with core/ep.py via kernels/grouped_mlp.py.
-        # Only the integer permutation goes through lax.sort; the
-        # differentiable weights follow via take_along_axis, so no
-        # gradient flows through the sort itself.
-        perm, key_s, counts, dest, M = ragged_destinations(key, E, block)
-        tok_s = jnp.take_along_axis(tok, perm, axis=1)
-        w_s = jnp.take_along_axis(w, perm, axis=1)
-        valid_s = key_s < E
+        # last segment) into block-aligned ragged rows — the layout math
+        # shared with core/ep.py via kernels/grouped_mlp.py. No gradient
+        # flows through the sort itself: the weights it carries are
+        # constants, and the combine's VJP routes their gradient.
+        if token_choice:
+            counts, slot, wr, dest_of, M = ragged_sources(
+                key, jax.lax.stop_gradient(w), E, block)
+            row_of = dest_of.reshape(r.token_expert.shape)
+            xs = _dispatch_rows(xg, slot, row_of)
+        else:
+            perm, key_s, counts, dest, M = ragged_destinations(key, E,
+                                                               block)
+            tok_s = jnp.take_along_axis(tok, perm, axis=1)
+            w_s = jnp.take_along_axis(w, perm, axis=1)
+            valid_s = key_s < E
 
-        # Ragged buffers: src maps ragged row -> group-local token (g =
-        # pad row), wr carries the combine weight (0 on pad rows). Row M
-        # is the trash row for dropped assignments.
-        gi = jnp.broadcast_to(jnp.arange(G)[:, None], (G, N))
-        src = (jnp.full((G, M + 1), g, jnp.int32)
-               .at[gi, dest].set(tok_s)[:, :M])
-        wr = (
-            jnp.zeros((G, M + 1), w_s.dtype)
-            .at[gi, dest].set(jnp.where(valid_s, w_s, 0.0))[:, :M]
-        )
+            # Ragged buffers: src maps ragged row -> group-local token (g
+            # = pad row), wr carries the combine weight (0 on pad rows).
+            # Row M is the trash row for dropped assignments.
+            gi = jnp.broadcast_to(jnp.arange(G)[:, None], (G, N))
+            src = (jnp.full((G, M + 1), g, jnp.int32)
+                   .at[gi, dest].set(tok_s)[:, :M])
+            wr = (
+                jnp.zeros((G, M + 1), w_s.dtype)
+                .at[gi, dest].set(jnp.where(valid_s, w_s, 0.0))[:, :M]
+            )
 
-        gm = jnp.broadcast_to(jnp.arange(G)[:, None], (G, M))
-        pad_row = src >= g
-        xs = xg[gm, jnp.minimum(src, g - 1)]
-        xs = xs * (1.0 - pad_row[..., None].astype(xg.dtype))
+            gm = jnp.broadcast_to(jnp.arange(G)[:, None], (G, M))
+            pad_row = src >= g
+            xs = xg[gm, jnp.minimum(src, g - 1)]
+            xs = xs * (1.0 - pad_row[..., None].astype(xg.dtype))
         # Ragged rows stay batch-sharded: expert boundaries are dynamic,
         # so the expert dim cannot be a sharding axis here (see module
         # docstring).
@@ -196,10 +304,14 @@ def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
             act=cfg.act, block=block, implementation=implementation,
         )
     with jax.named_scope("moe.combine"):
+        ys = act(ctx, ys, "batch seq mlp")
+        if token_choice:
+            # Each token gathers and sums its k weighted rows.
+            y = _combine_rows(ys, r.token_weight, slot, row_of, wr)
+            return act(ctx, y.astype(xg.dtype), "batch seq mlp")
         # Weight, unsort, scatter-add (duplicate token rows — one per
         # surviving assignment — accumulate, exactly like the gather
         # path).
-        ys = act(ctx, ys, "batch seq mlp")
         yw = (ys * wr[..., None]).astype(xg.dtype)
         y = jnp.zeros((G, g + 1, d), xg.dtype)
         y = act(ctx, y, "batch seq mlp")

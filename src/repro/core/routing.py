@@ -98,6 +98,17 @@ def route_expert_choice(logits: jax.Array, moe: MoECfg) -> Routing:
     )
 
 
+def _top_k_by_mask(probs: jax.Array, k: int):
+    """``lax.top_k`` over the last axis whose weights read ``probs``
+    through a one-hot mask instead of a gather, so the gradient is a
+    masked sum rather than ``top_k``'s scatter-add transpose (on TPU a
+    serial loop over its g*k updates). Same values: each weight is one
+    probability plus exact zeros, and top-k picks distinct experts."""
+    top_e = jax.lax.top_k(jax.lax.stop_gradient(probs), k)[1]
+    hot = jax.nn.one_hot(top_e, probs.shape[-1], dtype=probs.dtype)
+    return (probs[..., None, :] * hot).sum(-1), top_e
+
+
 def route_top_k(
     logits: jax.Array,
     moe: MoECfg,
@@ -119,7 +130,7 @@ def route_top_k(
     bpr = moe.bpr if bpr is None else bpr
     cap = capacity(g, moe)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, k)  # (G, g, K)
+    top_w, top_e = _top_k_by_mask(probs, k)  # (G, g, K)
     if token_mask is not None:
         top_e = jnp.where(token_mask[..., None], top_e, E)
 

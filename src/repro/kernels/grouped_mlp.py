@@ -162,6 +162,58 @@ def ragged_destinations(key: jax.Array, num_experts: int, block: int):
     return perm, key_s, counts, dest, M
 
 
+def ragged_sources(key: jax.Array, weight: jax.Array, num_experts: int,
+                   block: int):
+    """Both directions of ``ragged_destinations``' block-aligned packing
+    (the same stable layout by expert), with no scatter and no gather:
+    the tables of the token-choice sorted dispatch, which moves rows
+    through them by gathers alone (``core/moe.py``).
+
+    Rows to assignments: one stable sort of the stream together with
+    ``M - N`` filler entries, expert ``e`` getting the
+    ``row_off[e+1] - row_off[e] - counts[e]`` fillers that pad its
+    segment to whole blocks (the rest, and the invalid assignments, sort
+    past the last segment). Sorted, row ``m`` holds its assignment's
+    stream index and ``weight`` (``N`` and 0 on a filler's row).
+    Assignments to rows: ``row_off[e] +`` the assignment's rank among
+    the assignments of ``e`` before it in stream order, a prefix sum (the
+    rank the stable sort gives it).
+
+    Returns ``(counts, slot, weight_rows, dest_of, M)``: per-expert valid
+    counts ``(G, E)``; ``slot (G, M)`` — the stream index (position in
+    ``key``) of row ``m``'s assignment, ``N`` on pad and tail rows;
+    ``weight_rows (G, M)`` — its ``weight``, 0 there; ``dest_of
+    (G, N)`` — the row of every assignment in stream order, ``M`` for
+    invalid ones; the static buffer row count.
+    """
+    G, N = key.shape
+    E = num_experts
+    M = ragged_buffer_rows(N, E, block)
+    valid = key < E
+    oh = (key[..., None] == jnp.arange(E)).astype(jnp.int32)  # (G, N, E)
+    counts = oh.sum(1)
+    row_off, _ = ragged_row_offsets(counts, block)  # (G, E+1)
+    before = jnp.cumsum(oh, axis=1) - oh  # same-expert assignments before
+    dest_of = jnp.where(
+        valid, (oh * (row_off[:, None, :E] + before)).sum(-1), M
+    )
+
+    # Filler f pads expert e's segment iff ends[e-1] <= f < ends[e].
+    ends = jnp.cumsum(row_off[:, 1:] - row_off[:, :-1] - counts, axis=-1)
+    fill = jnp.arange(M - N, dtype=jnp.int32)
+    fill_key = (fill[None, :, None] >= ends[:, None, :]).sum(
+        -1, dtype=key.dtype)  # (G, M - N)
+    stream = jnp.where(valid, jnp.arange(N, dtype=jnp.int32), N)
+    _, slot, weight_rows = jax.lax.sort(
+        (jnp.concatenate([key, fill_key], axis=1),
+         jnp.concatenate([stream, jnp.full((G, M - N), N, jnp.int32)], 1),
+         jnp.concatenate([jnp.where(valid, weight, 0),
+                          jnp.zeros((G, M - N), weight.dtype)], 1)),
+        dimension=1, num_keys=1,
+    )
+    return counts, slot, weight_rows, dest_of, M
+
+
 def block_tables(group_sizes: jax.Array, bm: int, nb: int):
     """Scalar-prefetch tables for the kernels' expert-boundary walk.
 

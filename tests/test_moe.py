@@ -1,5 +1,6 @@
 """MoE layer tests: dispatch-path equivalence, gradients, grouping."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,21 +137,41 @@ def test_sorted_pad_tokens(setup, router):
     )
 
 
-@pytest.mark.parametrize("router", ROUTERS)
-@pytest.mark.parametrize("capacity_factor", [0.5, 2.0])
-def test_sorted_gradients_match_gather(setup, router, capacity_factor):
+TOKEN_CHOICE = ["top_k", "switch"]
+
+
+@pytest.mark.parametrize(
+    "capacity_factor, router, dead_tokens",
+    [pytest.param(c, r, False, id=f"{c}-{r}")
+     for r in ROUTERS for c in (0.5, 2.0)]
+    + [pytest.param(c, r, True, id=f"{c}-{r}-dead_tokens")
+       for r in TOKEN_CHOICE for c in (0.5, 2.0)],
+)
+def test_sorted_gradients_match_gather(setup, router, capacity_factor,
+                                       dead_tokens):
     """Full jax.grad parity (router + expert weights + input) between the
-    sorted and gather dispatches, with and without capacity drops."""
+    sorted and gather dispatches, with and without capacity drops, and
+    output parity. With a ``token_mask`` of dead tokens (token-choice
+    routers only), dead tokens claim no rows and give zero outputs."""
     cfg, vals, x = setup
     moe = dataclasses.replace(cfg.moe, capacity_factor=capacity_factor)
+    mask = (jax.random.bernoulli(jax.random.PRNGKey(4), 0.7, x.shape[:-1])
+            if dead_tokens else None)
 
     def loss(v, xv, dispatch):
         y, m = moe_apply(v, xv, cfg, moe, router_kind=router,
-                         dispatch=dispatch, sorted_block=8)
-        return jnp.sum(y ** 2) + m["aux_loss"]
+                         dispatch=dispatch, sorted_block=8,
+                         token_mask=mask)
+        return jnp.sum(y ** 2) + m["aux_loss"], y
 
-    g1 = jax.grad(loss, argnums=(0, 1))(vals, x, "gather")
-    g2 = jax.grad(loss, argnums=(0, 1))(vals, x, "sorted")
+    grad = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    (_, y1), g1 = grad(vals, x, "gather")
+    (_, y2), g2 = grad(vals, x, "sorted")
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                               rtol=1e-4, atol=1e-5)
+    if dead_tokens:
+        assert not bool(mask.all())
+        assert float(jnp.abs(y2[~mask]).max()) == 0.0
     flat1 = jax.tree_util.tree_leaves_with_path(g1)
     flat2 = jax.tree_util.tree_leaves(g2)
     for (path, a), b in zip(flat1, flat2):
@@ -218,3 +239,73 @@ def test_token_major_routing_matches_slot_table():
                 if e < E:
                     tokmaj[gi, t, e] += float(r.token_weight[gi, t, a])
     np.testing.assert_allclose(slot, tokmaj, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# scatter-free token-choice sorted path (gathers through the sort's
+# bijection); Expert Choice keeps the scatter-built tables
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("drop", [False, True])
+def test_ragged_sources_invert_the_destinations(seed, drop):
+    """``ragged_sources`` gives every ragged row the assignment that
+    ``ragged_destinations`` packs into it and its weight (pad and tail
+    rows: N and 0), every assignment its row (invalid ones: the trash
+    row M), and the same counts and row count."""
+    from repro.kernels.grouped_mlp import (
+        ragged_destinations,
+        ragged_sources,
+    )
+
+    G, N, E, block = 2, 40, 5, 4
+    key = np.asarray(jax.random.randint(jax.random.PRNGKey(seed), (G, N),
+                                        0, E + int(drop)), np.int32)
+    perm, _, counts, dest, M = ragged_destinations(jnp.asarray(key), E,
+                                                   block)
+    weight = jax.random.uniform(jax.random.PRNGKey(seed + 7), (G, N))
+    got_counts, slot, wr, dest_of, got_M = ragged_sources(
+        jnp.asarray(key), weight, E, block)
+    assert got_M == M
+    np.testing.assert_array_equal(np.asarray(got_counts), np.asarray(counts))
+    want_slot = np.full((G, M + 1), N)
+    want_dest = np.zeros((G, N), np.int64)
+    for gi in range(G):
+        for s in range(N):
+            want_slot[gi, dest[gi, s]] = perm[gi, s]
+            want_dest[gi, perm[gi, s]] = dest[gi, s]
+    np.testing.assert_array_equal(np.asarray(slot), want_slot[:, :M])
+    want_wr = np.where(want_slot[:, :M] < N, np.take_along_axis(
+        np.asarray(weight), np.minimum(want_slot[:, :M], N - 1), 1), 0)
+    np.testing.assert_array_equal(np.asarray(wr), want_wr)
+    np.testing.assert_array_equal(np.asarray(dest_of), want_dest)
+    assert int((np.asarray(dest_of) == M).sum()) == int((key == E).sum())
+
+
+@pytest.mark.parametrize("router", ROUTERS)
+def test_sorted_token_choice_path_has_no_scatter(setup, router):
+    """Token-choice sorted ``moe_apply`` lowers, forward and ``jax.grad``,
+    without a single ``scatter``: every row move and every transpose is
+    a gather. Expert Choice has no fixed slots per token and keeps the
+    scatter-built tables under ``moe.dispatch``."""
+    cfg, vals, x = setup
+
+    def fwd(v, xv):
+        return moe_apply(v, xv, cfg, cfg.moe, router_kind=router,
+                         dispatch="sorted", sorted_block=8)[0]
+
+    def loss(v, xv):
+        y, m = moe_apply(v, xv, cfg, cfg.moe, router_kind=router,
+                         dispatch="sorted", sorted_block=8)
+        return jnp.sum(y ** 2) + m["aux_loss"]
+
+    lowered = [jax.jit(f).lower(vals, x)
+               for f in (fwd, jax.grad(loss, argnums=(0, 1)))]
+    compiled = [lo.compile().as_text() for lo in lowered]
+    if router == "expert_choice":
+        assert re.search(r'scatter\(.*op_name="[^"]*moe\.dispatch',
+                         compiled[0])
+    else:
+        assert [lo.as_text().count("stablehlo.scatter")
+                for lo in lowered] == [0, 0]
+        assert ["scatter(" in text for text in compiled] == [False, False]

@@ -70,7 +70,13 @@ def test_train_step_hlo_names_every_scope(cfg, dispatch):
     rec = set().union(*(_scopes(p) for p in paths
                         if "rematted_computation" in p))
     assert {"moe.route", "moe.dispatch", "moe.combine"} <= bwd
-    assert MOE <= rec, MOE - rec
+    if dispatch == "sorted":
+        # The token-choice sorted combine's VJP keeps only its inputs,
+        # so the recomputed forward has no combine op left to run.
+        assert MOE - {"moe.combine"} <= rec, MOE - rec
+        assert "moe.combine" not in rec
+    else:
+        assert MOE <= rec, MOE - rec
 
 
 def test_mixed_serve_step_hlo_names_every_scope(cfg):
