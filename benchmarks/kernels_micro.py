@@ -43,68 +43,14 @@ def run() -> list[tuple[str, float, str]]:
     rows = []
     key = jax.random.PRNGKey(0)
 
-    # expert FFN: XLA grouped einsum vs per-expert loop oracle
-    E, cap, d, f = (4, 64, 64, 128) if SMOKE else (8, 256, 256, 512)
-    reps = 3 if SMOKE else 10
-    ks = jax.random.split(key, 4)
-    xe = jax.random.normal(ks[0], (1, E, cap, d), jnp.float32)
-    wi = jax.random.normal(ks[1], (E, d, f)) * 0.05
-    wg = jax.random.normal(ks[2], (E, d, f)) * 0.05
-    wo = jax.random.normal(ks[3], (E, f, d)) * 0.05
-    fx = jax.jit(lambda x: ops.expert_ffn(x, wi, wg, wo, act="silu"))
-    us = timed(fx, xe, n=reps)
-    flops = 1 * E * cap * (2 * d * f * 2 + 2 * f * d)
-    rows.append((
-        "kernels/expert_ffn_xla", us,
-        f"gflops_per_s={flops / us / 1e3:.2f}",
-    ))
-
-    # fwd+bwd: value_and_grad through the XLA path (dx + dwi + dwg + dwo).
-    def ffn_loss(x, wi, wg, wo):
-        return jnp.sum(
-            ops.expert_ffn(x, wi, wg, wo, act="silu") ** 2
-        )
-
-    fg = jax.jit(jax.value_and_grad(ffn_loss, argnums=(0, 1, 2, 3)))
-    us_g = timed(fg, xe, wi, wg, wo, n=reps)
-    # bwd ≈ 2x fwd matmuls + 1x activation recompute (see roofline.py)
-    rows.append((
-        "kernels/expert_ffn_xla_fwd_bwd", us_g,
-        f"vs_fwd={us_g / us:.2f}x gflops_per_s={3 * flops / us_g / 1e3:.2f}",
-    ))
-
-    # Pallas custom-VJP backward kernels, interpret mode (correctness-path
-    # timing only — compiled perf is TPU-side; keep shapes tiny).
-    Ep, capp, dp, fp = 2, 32, 32, 64
-    xs = jax.random.normal(ks[0], (1, Ep, capp, dp), jnp.float32)
-    wis = jax.random.normal(ks[1], (Ep, dp, fp)) * 0.05
-    wgs = jax.random.normal(ks[2], (Ep, dp, fp)) * 0.05
-    wos = jax.random.normal(ks[3], (Ep, fp, dp)) * 0.05
-
-    def ffn_loss_p(x, wi, wg, wo):
-        return jnp.sum(
-            ops.expert_ffn(x, wi, wg, wo, act="silu",
-                           implementation="pallas") ** 2
-        )
-
-    fgp = jax.jit(jax.value_and_grad(ffn_loss_p, argnums=(0, 1, 2, 3)))
-    us_gp = timed(fgp, xs, wis, wgs, wos, n=2)
-    rows.append((
-        "kernels/expert_ffn_pallas_interpret_fwd_bwd", us_gp,
-        "custom_vjp_kernels=dx+dw",
-    ))
-
-    # Sorted ragged dispatch (grouped GEMM) vs padded capacity buffer at
-    # capacity factors 1.0 / 1.25 / 2.0: the padded path's rows — and so
-    # its modeled AND measured (XLA cost-analysis) FLOPs — scale linearly
-    # with the capacity factor; the sorted buffer's static row count
-    # M = (ceil(g*k/bm) + E) * bm does not depend on it at all.
-    from repro.configs import MoECfg
-    from repro.core.routing import capacity as capacity_fn
+    # Sorted ragged dispatch (grouped GEMM): the buffer's static row
+    # count M = (ceil(g*k/bm) + E) * bm does not depend on the capacity
+    # factor, so neither do its FLOPs.
     from repro.kernels.grouped_mlp import ragged_buffer_rows
 
+    d2, f2 = (64, 128) if SMOKE else (256, 512)
+    reps = 3 if SMOKE else 10
     g_tok, E2, k2 = (128, 4, 1) if SMOKE else (512, 8, 1)  # switch-style
-    d2, f2 = (d, f)
     bm = 8 if SMOKE else 32  # CPU-bench block; the TPU kernel uses 128
     ks = jax.random.split(key, 4)
     wi2 = jax.random.normal(ks[0], (E2, d2, f2)) * 0.05
@@ -115,40 +61,18 @@ def run() -> list[tuple[str, float, str]]:
     counts = jnp.full((1, E2), n_assign // E2, jnp.int32)
     xs_r = _ragged_rows(ks[3], M, d2, counts, bm)
 
-    def measured_flops(fn, *a):
-        ca = fn.lower(*a).compile().cost_analysis()
-        ca = ca[0] if isinstance(ca, list) else ca
-        return float(ca.get("flops", 0.0)) if ca else 0.0
-
     f_sort = jax.jit(lambda x, c: ops.grouped_mlp(
         x, wi2, wg2, wo2, c, act="silu", block=bm))
     us_sort = timed(f_sort, xs_r, counts, n=reps)
-    mf_sort = measured_flops(f_sort, xs_r, counts)
-    for cf in (1.0, 1.25, 2.0):
-        moe = MoECfg(num_experts=E2, top_k=k2, capacity_factor=cf)
-        cap2 = capacity_fn(g_tok, moe)
-        xe_p = jax.random.normal(ks[3], (1, E2, cap2, d2), jnp.float32)
-        f_pad = jax.jit(lambda x: ops.expert_ffn(
-            x, wi2, wg2, wo2, act="silu"))
-        us_pad = timed(f_pad, xe_p, n=reps)
-        mf_pad = measured_flops(f_pad, xe_p)
-        model_pad = E2 * cap2 * 6 * d2 * f2
-        model_sort = M * 6 * d2 * f2  # static rows: cf-independent
-        # Raw per-path numbers so the trend is visible in the CSV: padded
-        # model+measured FLOPs grow ~linearly in cf, the sorted column is
-        # CONSTANT. (The sorted measured term uses XLA's CPU lowering of
-        # ragged_dot, which expands to a dense per-expert loop — inflated
-        # by ~E vs the model, but still exactly cf-independent; the TPU
-        # kernel's live compute tracks the model.)
-        rows.append((
-            f"kernels/moe_dispatch_cf{cf}", us_pad,
-            f"padded_us={us_pad:.0f} sorted_us={us_sort:.0f} "
-            f"padded_rows={E2 * cap2} sorted_rows={M} "
-            f"padded_model_mflops={model_pad / 1e6:.1f} "
-            f"sorted_model_mflops={model_sort / 1e6:.1f} "
-            f"padded_measured_mflops={mf_pad / 1e6:.1f} "
-            f"sorted_measured_mflops={mf_sort / 1e6:.1f}",
-        ))
+    ca = f_sort.lower(xs_r, counts).compile().cost_analysis()
+    ca = ca[0] if isinstance(ca, list) else ca
+    # XLA's CPU lowering of ragged_dot expands to a dense per-expert
+    # loop, so the measured FLOPs run ~E above the model.
+    rows.append((
+        "kernels/grouped_mlp_xla", us_sort,
+        f"rows={M} model_mflops={M * 6 * d2 * f2 / 1e6:.1f} "
+        f"measured_mflops={float((ca or {}).get('flops', 0.0)) / 1e6:.1f}",
+    ))
 
     # Compacted block walk (dead blocks stream no x/weight tiles): model
     # the HBM byte savings at this bench's shapes under a skewed load —

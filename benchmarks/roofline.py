@@ -7,7 +7,7 @@ Terms are seconds per chip on TPU v5e (197 TFLOP/s bf16, 819 GB/s HBM,
 ~50 GB/s ICI link); dominant term = the bottleneck the perf loop attacks.
 
 Also emits a static per-kernel fwd/bwd roofline for the two Pallas
-training kernels (expert FFN, flash attention): now that the backward
+training kernels (grouped expert FFN, flash attention): now that the backward
 pass is kernel-fused (custom VJP), the training step pays the backward
 FLOP terms through the same VMEM-resident kernels, so both directions
 are modeled.
@@ -40,11 +40,11 @@ def _roofline_row(name, flops, bytes_):
 def kernel_rooflines() -> list[tuple[str, float, str]]:
     """Fwd/bwd FLOP + HBM-byte model at a reference training shape.
 
-    Expert FFN (gated), per expert: fwd = 3 matmuls (wi, wg, wo) =
-    6*cap*d*f FLOPs. Bwd = dx kernel (recompute a/g/dh: 3 matmuls, expand
+    Grouped expert FFN (gated), per row: fwd = 3 matmuls (wi, wg, wo) =
+    6*d*f FLOPs. Bwd = dx kernel (recompute a/g/dh: 3 matmuls, expand
     da/dg -> dx: 2) + dW kernel (recompute a/g/dh: 3, dwi/dwg/dwo: 3) =
-    16*cap*d*f — ~2.7x fwd (the flash-style recompute tax for keeping the
-    (cap, f) tensors in VMEM; residuals are the kernel inputs only).
+    16*d*f — ~2.7x fwd (the flash-style recompute tax for keeping the
+    (bm, f) tensors in VMEM; residuals are the kernel inputs only).
 
     Flash attention, per (b, h): fwd = qk^T + pv = 4*Sq*Skv*dh. Bwd =
     dq kernel (s, dp, dq: 6*Sq*Skv*dh) + dkv kernel (s, dp, dk, dv:
@@ -52,32 +52,11 @@ def kernel_rooflines() -> list[tuple[str, float, str]]:
     """
     rows = []
     # Reference shapes: an 8-expert 1B-class MoE layer and a 4k-context
-    # attention layer, bf16 tensors (2 bytes).
-    #
-    # HBM bytes model the kernels AS TILED, not an ideal single-read
-    # lower bound: the expert-FFN grids re-stream the full weights once
-    # per cap tile (nc = cap/bc times) and the full-d x/dy rows once per
-    # f tile (nf = f/bf; twice in the two-phase dx kernel) — the weights
-    # (~0.5 GB here) cannot be VMEM-resident. That re-streaming is why
-    # larger (bc, bf) tiles and the ROADMAP tile auto-tuner matter.
-    E, cap, d, f = 8, 4096, 2048, 5632
-    bc, bf = 128, 256  # expert_mlp.py defaults
-    nc, nf = cap // bc, f // bf
-    ffn_fwd = E * 6 * cap * d * f
-    ffn_bwd = E * 16 * cap * d * f
-    w_bytes = E * 3 * d * f * 2
-    x_bytes = E * cap * d * 2
-    rows.append(_roofline_row(
-        # fwd: weights streamed per cap tile, x read once, y written once.
-        "roofline/kernel.expert_ffn.fwd", ffn_fwd,
-        nc * w_bytes + 2 * x_bytes,
-    ))
-    rows.append(_roofline_row(
-        # dx kernel: 2 phases -> 2*nf re-reads of x+dy, 2*nc of weights;
-        # dW kernel: nf re-reads of x+dy, nc of weights; writes dx + dW.
-        "roofline/kernel.expert_ffn.bwd", ffn_bwd,
-        3 * nf * 2 * x_bytes + 3 * nc * w_bytes + x_bytes + w_bytes,
-    ))
+    # attention layer, bf16 tensors (2 bytes). A padded capacity buffer
+    # for comparison is tiled by bc = 128 rows; the grouped kernel's
+    # backward re-streams full-d x/dy rows once per bf = 256 f tile.
+    E, d, f = 8, 2048, 5632
+    bc, bf = 128, 256
     # Grouped-GEMM (sorted ragged dispatch) vs the padded capacity
     # buffer, same E/d/f, one routing group of g = 4096 tokens, top-2,
     # under a skewed expert load (top expert draws ~30% of assignments —
@@ -124,8 +103,8 @@ def kernel_rooflines() -> list[tuple[str, float, str]]:
             f"bytes_ratio_static_walk={rag_bytes_static / pad_bytes:.2f} "
             f"ragged_static_rows={M} (cf-independent)",
         ))
-    # fwd/bwd rooflines for the grouped kernel at the cf=2.0 point: same
-    # per-row FLOP family as expert_ffn (6x fwd, 16x bwd recompute tax),
+    # fwd/bwd rooflines for the grouped kernel at the cf=2.0 point
+    # (6x fwd, 16x bwd recompute tax per row),
     # bytes follow the compacted walk (live blocks only stream tiles).
     _, _, nb_live2 = live_blocks_of(2.0)
     live2 = nb_live2 * bm
@@ -138,8 +117,7 @@ def kernel_rooflines() -> list[tuple[str, float, str]]:
     ))
     nf = f // bf
     rows.append(_roofline_row(
-        # Same convention as kernel.expert_ffn.bwd: the dx kernel
-        # re-streams full-d x/dy rows once per f tile in each of its two
+        # The dx kernel re-streams full-d x/dy rows once per f tile in each of its two
         # phases, the dW kernel once more (3*nf*2 x-passes total); weight
         # tiles stream per LIVE row-block twice in dx, once in dW
         # (3*rag_w_bytes); writes = dx (buffer-sized) + dW (weight-sized).
@@ -248,8 +226,8 @@ def kernel_rooflines() -> list[tuple[str, float, str]]:
 
 
 def moe_comm_rows() -> list[tuple[str, float, str]]:
-    """Comm-volume model for the two sorted-dispatch layouts
-    (core/moe.py dispatch table), per device per MoE layer, bf16:
+    """Comm-volume model for the MoE layer's two layouts (core/moe.py
+    layout table), per device per MoE layer, bf16:
 
     * expert-parallel a2a (``moe.ep="a2a"``): tokens move — 2 exchanges
       (dispatch + return) of ``tokens_dev * k`` rows of d features, of

@@ -64,13 +64,14 @@ ARCH = "granite-moe-1b-a400m"
 LOSS_ATOL = 1e-2
 # Serve step logits vs the float32 reference, both at highest precision,
 # as the largest |difference| over the largest |reference logit|. They
-# differ only in summation order (paged blocks, online softmax, sorted
-# vs gather dispatch), so by float32 roundoff: a relative 2^-24 = 6e-8
-# per operation, grown to about 1e-6 over 24 layers. 1e-3 stays far
-# below what a cache layout, masking or routing error gives (a wrong
-# block or position moves logits by tens of percent). The same check at
-# the TPU's default precision (bf16 operands, 2^-9 relative) is printed
-# but not held to a limit: its size is what the next bf16 work measures.
+# differ only in summation order (paged blocks, online softmax, the
+# kernel's ragged walk vs the one-hot oracle), so by float32 roundoff:
+# a relative 2^-24 = 6e-8 per operation, grown to about 1e-6 over 24
+# layers. 1e-3 stays far below what a cache layout, masking or routing
+# error gives (a wrong block or position moves logits by tens of
+# percent). The same check at the TPU's default precision (bf16
+# operands, 2^-9 relative) is printed but not held to a limit: its size
+# is what the next bf16 work measures.
 LOGITS_RTOL = 1e-3
 # Sharded expert-parallel step vs one device: the same arithmetic with
 # sums reduced in another order across chips and the grouped GEMM fed
@@ -114,8 +115,7 @@ def train(cfg, params, *, impl: str, steps: int, batch: int, seq: int,
     """``steps`` Trainer steps from ``params``; returns (trained params,
     report). The first loss is checked against the XLA path's loss of
     the same parameters on the same batch."""
-    ac = zoo.ApplyCfg(dispatch="sorted", remat=remat, moe_impl=impl,
-                      attn_impl=impl).resolve()
+    ac = zoo.ApplyCfg(remat=remat, moe_impl=impl, attn_impl=impl).resolve()
     xla_ac = dataclasses.replace(ac, moe_impl="xla", attn_impl="xla")
     first_batch = next(make_iterator(cfg, global_batch=batch, seq_len=seq))
     loss_xla = float(jax.jit(
@@ -147,7 +147,6 @@ def train(cfg, params, *, impl: str, steps: int, batch: int, seq: int,
         "peak_bytes_in_use": _peak_bytes(jax.devices()[0]),
     }
     log(f"[train] kernels moe={ac.moe_impl} attn={ac.attn_impl} "
-        "dispatch=sorted "
         f"remat={remat} batch={batch}x{seq}")
     log(f"[train] losses={losses} first-step loss vs xla: "
         f"{losses[0]!r} vs {loss_xla!r}, |diff|={diff!r} "
@@ -239,8 +238,7 @@ def step_logits_vs_reference(cfg, params, ac, prompt, *, n_decode: int,
             rows.append(lg[0])
     got = np.asarray(jnp.stack(rows))
 
-    ref_ac = zoo.ApplyCfg(dispatch="gather", moe_impl="ref",
-                          attn_impl="ref")
+    ref_ac = zoo.ApplyCfg(moe_impl="ref", attn_impl="ref")
     toks = jnp.asarray([tokens], jnp.int32)
     with jax.default_matmul_precision("highest"):
         ref, _ = jax.jit(
@@ -295,8 +293,7 @@ def ep_step_vs_one_device(cfg, params, *, mesh, impl: str, batch: int,
     )
     cfg = dataclasses.replace(cfg, moe=moe)
     opt = adafactor(constant(1e-3))
-    ac = zoo.ApplyCfg(dispatch="sorted", remat=remat, moe_impl=impl,
-                      attn_impl=impl)
+    ac = zoo.ApplyCfg(remat=remat, moe_impl=impl, attn_impl=impl)
     data = next(make_iterator(cfg, global_batch=batch, seq_len=seq))
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt,
                              params=params)
@@ -322,7 +319,7 @@ def ep_step_vs_one_device(cfg, params, *, mesh, impl: str, batch: int,
     m4 = jax.device_get(m4)
     rel = abs(float(m4["loss"]) - float(m1["loss"])) / abs(float(m1["loss"]))
     peaks = [_peak_bytes(d) for d in mesh.devices.flat]
-    log(f"[ep] mesh {dict(mesh.shape)} dispatch=sorted ep=a2a "
+    log(f"[ep] mesh {dict(mesh.shape)} ep=a2a "
         f"group_size={moe.group_size} batch={batch}x{seq}")
     log(f"[ep] loss one device={float(m1['loss'])!r} "
         f"mesh={float(m4['loss'])!r} rel diff={rel!r} "

@@ -70,10 +70,10 @@ python -m repro.obs.lint
 
 echo "[verify] kernel micro-bench + serving bench + roofline (smoke mode)"
 # kernels_micro exercises every ops.* implementation (including the
-# Pallas custom-VJP kernels in interpret mode, the grouped-GEMM
-# sorted-dispatch path at capacity factors 1.0/1.25/2.0, the compacted
-# block walk's dead-block byte-savings row, and the chunked paged
-# prefill vs per-token decode-walk comparison); serve_bench runs the
+# Pallas custom-VJP kernels in interpret mode, the grouped-GEMM expert
+# FFN forward and backward, the compacted block walk's dead-block
+# byte-savings row, and the chunked paged prefill vs per-token
+# decode-walk comparison); serve_bench runs the
 # continuous-batching vs static-batch comparison under a Poisson
 # arrival trace PLUS the long-prompt bursty scenario comparing static /
 # prefill-on-join / chunked-mixed-step admission (wall-clock TTFT,

@@ -39,11 +39,11 @@ class MoECfg:
     expert_init: str = "copy"
     init_noise_std: float = 0.0
     router_init_std: float = 0.02
-    # Expert parallelism for dispatch="sorted": "none" keeps the ragged
+    # Expert parallelism of the MoE layer: "none" keeps the ragged
     # buffer batch-sharded with FSDP-style expert-weight gather (tokens
     # stay, weights move); "a2a" runs the shard_map expert-parallel path
     # (weights stay, tokens move over the `model` mesh axis via ragged
-    # all-to-all) — see core/ep.py. Ignored by the padded dispatches.
+    # all-to-all) — see core/ep.py.
     ep: str = "none"
     # Static per-(src device, dst device) row budget of the EP all-to-all
     # send/recv buffers, as a multiple of the balanced share

@@ -1,6 +1,6 @@
 """Expert-parallel sorted dispatch: shard_map ragged all-to-all.
 
-The ``dispatch="sorted"`` path of core/moe.py keeps the ragged token
+The ``moe.ep="none"`` layout of core/moe.py keeps the ragged token
 buffer batch-sharded and lets GSPMD gather every expert's weights to the
 data shards (FSDP / "Llama 3 Meets MoE" layout) — weight traffic scales
 with E. This module is the complementary regime (``moe.ep="a2a"``):
@@ -20,7 +20,7 @@ Under ``shard_map`` each device:
 4. locally sorts the received rows by local expert into the same
    block-aligned ragged layout as the single-device sorted path and
    runs ``ops.grouped_mlp`` (Pallas grouped-GEMM kernel / XLA
-   ragged_dot — the PR 2 custom-VJP kernels, unchanged);
+   ragged_dot — the same custom-VJP kernels);
 5. returns results through the mirror all-to-all and combines on the
    SOURCE device (weight multiply + unsort scatter-add), so combine
    weights never travel.
@@ -31,7 +31,7 @@ all-to-alls transpose to all-to-alls, scatters to gathers, and the
 replicated-in weight specs transpose to psums over the non-EP axes —
 the train loop needs no special casing.
 
-Who moves / where drops happen (vs the other layouts): see the dispatch
+Who moves / where drops happen (vs ``ep="none"``): see the layout
 table in core/moe.py and kernels/README.md.
 """
 from __future__ import annotations

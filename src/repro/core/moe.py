@@ -1,36 +1,14 @@
-"""The MoE layer: router + dispatch + expert FFN + combine.
+"""The MoE layer: router + sorted ragged dispatch + expert FFN + combine.
 
-Three dispatch implementations (N = tokens/group g, A = assignments per
-token — top-k's k; rows below are per group, f = d_ff):
+One dispatch: the flat assignment stream (``routing.assignment_stream``)
+is sorted by expert into a block-aligned ragged buffer ``(G, M, d)`` —
+``M = (ceil(N/block) + E) * block`` rows for N assignments a group, so
+FFN work tracks the *filled* rows and is independent of capacity factor,
+which only decides which assignments survive (the routers' keep masks)
+— and goes through ``kernels.ops.grouped_mlp``: the scalar-prefetch
+Pallas grouped-GEMM kernel on TPU, per-group ``lax.ragged_dot`` on XLA.
 
-  ========  ==================  =======================  =================
-  dispatch  FFN rows processed  extra FLOPs vs dense     when to use
-  ========  ==================  =======================  =================
-  einsum    E*cap = C*g         one-hot dispatch AND     paper-faithful
-            (scales with C)     combine matmuls:         baseline, tiny
-                                O(g*E*cap*d) each        shapes, audits
-  gather    E*cap = C*g         none (gather/scatter     padded default:
-            (scales with C)     indexing only), but      expert-parallel
-                                zero-pad FFN FLOPs on    a2a sharding via
-                                unfilled slots           (G,E,cap,d) buf
-  sorted    g*A + O(E*bm)       none; FFN FLOPs track    perf path: C > 1
-            (independent of     *filled* rows only       or imbalanced
-            capacity factor C)  (ragged grouped GEMM);   Top-K; finetune/
-                                rows move by gathers     inference economy
-                                alone for token-choice
-                                routers (no scatter)
-  ========  ==================  =======================  =================
-
-``einsum``/``gather`` build the padded ``(G, E, cap, d)`` capacity buffer
-and go through ``kernels.ops.expert_ffn``; ``sorted`` sorts the flat
-assignment stream by expert into a block-aligned ragged buffer
-``(G, M, d)`` (M independent of capacity factor) and goes through
-``kernels.ops.grouped_mlp`` — the scalar-prefetch Pallas grouped-GEMM
-kernel on TPU, per-group ``lax.ragged_dot`` on XLA. All three consume the
-same ``Routing`` decisions, so outputs/gradients agree to float tolerance
-(tests/test_moe.py parity sweeps).
-
-How ``sorted`` moves rows: the sort is a bijection between the surviving
+How rows move: the sort is a bijection between the surviving
 assignments and the live ragged rows, so for the token-choice routers
 (k slots per token) each side looks the other up by gather —
 ``kernels.grouped_mlp.ragged_sources`` gives each row its assignment and
@@ -43,20 +21,15 @@ either direction (on TPU XLA runs a scatter as a serial loop over its
 updates). Expert Choice, whose tokens appear a variable number of times,
 keeps scatter-built tables and a scatter-add combine.
 
-Sharding: the padded paths constrain dispatched buffers (G, E, cap, d) to
-``_ expert cap embed`` — with experts on the ``model`` mesh axis this makes
-GSPMD insert the all-to-alls of the paper's "expert partitioning"
-(§A.4). When E doesn't divide the axis (grok), the constraint degrades to
-replicated-expert + tensor-parallel d_ff via the rules engine. The sorted
-path has two layouts, selected by ``moe.ep``:
+Sharding: two layouts, selected by ``moe.ep``:
 
   ==========  ===========  ================  ==========================
   layout      who moves    drops happen      sharding constraints
   ==========  ===========  ================  ==========================
   ep="none"   weights      router capacity   ragged buffer batch-
   (FSDP       (E weight    only (keep        sharded (``batch seq
-  weight-     gathers to   masks, shared     embed``; dynamic expert
-  gather)     the data     by all paths)     boundaries forbid an
+  weight-     gathers to   masks)            embed``; dynamic expert
+  gather)     the data                       boundaries forbid an
               shards)                        expert axis); weights
                                              expert-resident when E
                                              divides ``model``, else
@@ -72,11 +45,10 @@ path has two layouts, selected by ``moe.ep``:
 
 ``ep="none"`` is the "Llama 3 Meets MoE" upcycling layout — weight
 traffic scales with E; ``ep="a2a"`` trades it for token traffic that
-scales with tokens/device (the GShard regime, where the capacity buffer
-used to live) — see benchmarks/roofline.py ``comm.moe`` for the
-crossover. Falls back to ``ep="none"`` when the mesh cannot host EP
-(no ``model`` axis, size 1, or E % ep != 0 — the rules-engine
-fallback discipline).
+scales with tokens/device (the GShard regime) — see
+benchmarks/roofline.py ``comm.moe`` for the crossover. Falls back to
+``ep="none"`` when the mesh cannot host EP (no ``model`` axis, size 1,
+or E % ep != 0 — the rules-engine fallback discipline).
 """
 from __future__ import annotations
 
@@ -88,7 +60,6 @@ import jax.numpy as jnp
 from repro.configs import ArchConfig, MoECfg
 from repro.core import routing as R
 from repro.models import param as pm
-from repro.models.layers import activation
 from repro.sharding import ShardCtx, act
 
 
@@ -117,7 +88,7 @@ def _compute_layout_weights(experts, ctx: Optional[ShardCtx]):
     `model` axis, else d_ff tensor-parallel. Without this GSPMD sometimes
     prefers replicating the token buffers over gathering the weights —
     ~4x more bytes at Jamba scale (EXPERIMENTS.md SPerf, jamba iteration 3).
-    Shared by the padded (expert_ffn) and sorted (grouped_mlp) paths."""
+    Called by the ``ep="none"`` layout before ``grouped_mlp``."""
     wi, wg, wo = experts["wi"], experts.get("wg"), experts["wo"]
     if ctx is not None:
         E = wi.shape[0]
@@ -131,20 +102,6 @@ def _compute_layout_weights(experts, ctx: Optional[ShardCtx]):
             wo = act(ctx, wo, "_ mlp _")
             wg = act(ctx, wg, "_ _ mlp") if wg is not None else None
     return wi, wg, wo
-
-
-def expert_ffn(experts, xe, cfg: ArchConfig, *, implementation="xla",
-               ctx: Optional[ShardCtx] = None):
-    """xe: (G, E, cap, d) -> (G, E, cap, d). Dispatches to kernels.ops."""
-    from repro.kernels import ops
-
-    with jax.named_scope("moe.experts"):
-        wi, wg, wo = _compute_layout_weights(experts, ctx)
-        return ops.expert_ffn(
-            xe, wi, wg, wo,
-            act=cfg.act,
-            implementation=implementation,
-        )
 
 
 def _rows(x, idx):
@@ -232,8 +189,7 @@ def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
     The ragged buffer has ``M = (ceil(N/block) + E) * block`` rows — N is
     the assignment count (g*k for token-choice), so FFN work is
     independent of capacity factor; capacity only decides WHICH
-    assignments survive (the routers' keep masks, identical across
-    dispatch paths).
+    assignments survive (the routers' keep masks).
 
     Token-choice routers (``r.token_expert`` set: k slots per token) move
     rows by gathers alone, in both directions and both transposes: the
@@ -310,8 +266,7 @@ def _sorted_dispatch(params, xg, r, cfg: ArchConfig, moe: MoECfg, *,
             y = _combine_rows(ys, r.token_weight, slot, row_of, wr)
             return act(ctx, y.astype(xg.dtype), "batch seq mlp")
         # Weight, unsort, scatter-add (duplicate token rows — one per
-        # surviving assignment — accumulate, exactly like the gather
-        # path).
+        # surviving assignment — accumulate).
         yw = (ys * wr[..., None]).astype(xg.dtype)
         y = jnp.zeros((G, g + 1, d), xg.dtype)
         y = act(ctx, y, "batch seq mlp")
@@ -336,7 +291,6 @@ def moe_apply(
     moe: MoECfg,
     *,
     router_kind: Optional[str] = None,
-    dispatch: str = "gather",
     ctx: Optional[ShardCtx] = None,
     implementation: str = "xla",
     sorted_block: int = 128,
@@ -344,10 +298,10 @@ def moe_apply(
 ):
     """x: (B, S, d) or (N, d). Returns (y, metrics dict).
 
-    ``dispatch``: "einsum" | "gather" (padded capacity buffer) | "sorted"
-    (ragged grouped GEMM; ``sorted_block`` is the row-block alignment of
-    the ragged buffer — 128 matches the TPU kernel's MXU tiles, tests use
-    smaller blocks to keep interpret-mode buffers tiny).
+    ``sorted_block`` is the row-block alignment of the ragged buffer —
+    128 matches the TPU kernel's MXU tiles; ``ApplyCfg.resolve`` picks 8
+    for the XLA and ref implementations, whose small decode batches
+    would otherwise pad to E*128 rows.
 
     ``token_mask``: None, or a bool array broadcastable to x's token dims
     (B, S) — False marks dead tokens (the continuous-batching engine's
@@ -380,73 +334,30 @@ def moe_apply(
         )
         r = R.route(logits, moe, router_kind, token_mask=mg)
 
-    if dispatch == "einsum":
-        # One-hot dispatch/combine (GShard-era faithful path).
-        with jax.named_scope("moe.dispatch"):
-            oh = jax.nn.one_hot(r.token_idx, g + 1, dtype=xg.dtype)[..., :g]
-            # (G, E, cap, g) x (G, g, d) -> (G, E, cap, d)
-            xe = jnp.einsum("Gect,Gtd->Gecd", oh, xg)
-            xe = act(ctx, xe, "batch expert cap embed")
-        ye = expert_ffn(params["experts"], xe, cfg,
-                        implementation=implementation, ctx=ctx)
-        with jax.named_scope("moe.combine"):
-            ye = act(ctx, ye, "batch expert cap embed")
-            comb = oh * r.combine[..., None].astype(xg.dtype)
-            y = jnp.einsum("Gect,Gecd->Gtd", comb, ye)
-    elif dispatch == "gather":
-        with jax.named_scope("moe.dispatch"):
-            safe_idx = jnp.minimum(r.token_idx, g - 1)
-            gi = jnp.broadcast_to(
-                jnp.arange(G)[:, None, None], r.token_idx.shape
-            )
-            xe = xg[gi, safe_idx]  # (G, E, cap, d)
-            valid = (r.token_idx < g)[..., None].astype(xg.dtype)
-            xe = xe * valid
-            xe = act(ctx, xe, "batch expert cap embed")
-        ye = expert_ffn(params["experts"], xe, cfg,
-                        implementation=implementation, ctx=ctx)
-        with jax.named_scope("moe.combine"):
-            # Resharding ye from expert-sharded to hidden-sharded BEFORE
-            # the scatter makes GSPMD emit a (tokens*k*d/E)-sized
-            # all-to-all and a shard-local scatter, instead of
-            # partial-summing the full (G, g, d) token buffer with an
-            # all-reduce per layer (~E/k * 2 more bytes; see
-            # EXPERIMENTS.md SPerf jamba iteration).
-            ye = act(ctx, ye, "batch _ cap mlp")
-            w = (r.combine[..., None] * valid).astype(ye.dtype)
-            yw = (ye * w).astype(xg.dtype)
-            y = jnp.zeros((G, g + 1, d), xg.dtype)
-            y = act(ctx, y, "batch seq mlp")
-            y = y.at[gi, r.token_idx].add(yw)
-            y = act(ctx, y, "batch seq mlp")
-            y = y[:, :g]
-    elif dispatch == "sorted":
-        from repro.sharding.logical import expert_parallel_layout
+    from repro.sharding.logical import expert_parallel_layout
 
-        ep_layout = (
-            expert_parallel_layout(ctx.mesh, moe.num_experts)
-            if (moe.ep == "a2a" and ctx is not None) else None
-        )
-        if ep_layout is not None:
-            from repro.core.ep import sorted_dispatch_ep
+    ep_layout = (
+        expert_parallel_layout(ctx.mesh, moe.num_experts)
+        if (moe.ep == "a2a" and ctx is not None) else None
+    )
+    if ep_layout is not None:
+        from repro.core.ep import sorted_dispatch_ep
 
-            # The expert FFN and the combine name their own scopes inside.
-            with jax.named_scope("moe.dispatch"):
-                y, ep_overflow = sorted_dispatch_ep(
-                    params, xg, r, cfg, moe,
-                    ctx=ctx, implementation=implementation,
-                    block=sorted_block,
-                )
-        else:
-            # ep="a2a" on an EP-incapable mesh (or no ctx) falls back to
-            # the batch-sharded weight-gather layout — same results.
-            y = _sorted_dispatch(
+        # The expert FFN and the combine name their own scopes inside.
+        with jax.named_scope("moe.dispatch"):
+            y, ep_overflow = sorted_dispatch_ep(
                 params, xg, r, cfg, moe,
                 ctx=ctx, implementation=implementation,
                 block=sorted_block,
             )
     else:
-        raise ValueError(f"unknown dispatch {dispatch!r}")
+        # ep="a2a" on an EP-incapable mesh (or no ctx) falls back to
+        # the batch-sharded weight-gather layout — same results.
+        y = _sorted_dispatch(
+            params, xg, r, cfg, moe,
+            ctx=ctx, implementation=implementation,
+            block=sorted_block,
+        )
 
     with jax.named_scope("moe.combine"):
         y = y.reshape(-1, d)
@@ -454,8 +365,8 @@ def moe_apply(
             y = y[:n]
         y = y.reshape(orig_shape).astype(x.dtype)
     # Remat boundary tag: with stack_apply(remat="moe") only this combined
-    # output is saved for the backward; the dispatched (G, E, cap, d)
-    # buffers and router tensors above are recomputed.
+    # output is saved for the backward; the ragged buffers and router
+    # tensors above are recomputed.
     from jax.ad_checkpoint import checkpoint_name
 
     y = checkpoint_name(y, "moe_block")

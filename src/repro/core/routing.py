@@ -5,14 +5,14 @@ top-k / capacity bookkeeping is local to each group, which bounds the
 routing working set and — on hardware — the all-to-all payloads.
 
 All routers return a ``Routing`` carrying integer dispatch indices, combine
-weights, and metrics. Three dispatch implementations live in core/moe.py:
-the paper-era one-hot einsum (faithful baseline), gather/scatter
-(optimized padded), and sorted ragged (grouped-GEMM, no capacity buffer).
-Token-choice routers additionally expose the token-major assignment view
-(``token_expert``/``token_weight``) the sorted path consumes.
+weights, and metrics. core/moe.py has one dispatch: it reads them through
+``assignment_stream`` and sorts the stream into a ragged buffer for the
+grouped-GEMM kernel. Token-choice routers additionally expose the
+token-major assignment view (``token_expert``/``token_weight``) that the
+stream and the gather-only combine read.
 
-Shapes: x grouped as (G, g, d); router logits (G, g, E); expert buffers
-(G, E, cap, d).
+Shapes: x grouped as (G, g, d); router logits (G, g, E); slot tables
+(G, E, cap).
 """
 from __future__ import annotations
 
@@ -31,8 +31,7 @@ class Routing(NamedTuple):
     token_idx: jax.Array  # int32 (G, E, cap)
     # Combine weight for each expert slot (0 where unfilled) (G, E, cap).
     combine: jax.Array
-    # Router probabilities (G, g, E) — kept for the einsum dispatch path
-    # and for metrics.
+    # Router probabilities (G, g, E) — kept for metrics.
     probs: jax.Array
     aux_loss: jax.Array  # scalar
     z_loss: jax.Array  # scalar

@@ -1,9 +1,8 @@
 """Grouped-GEMM expert FFN over a sorted ragged token buffer (Pallas TPU),
 forward + custom-VJP backward.
 
-This is the ``dispatch="sorted"`` hot path: instead of the padded
-``(G, E, cap, d)`` capacity buffer, tokens arrive as a flat expert-sorted
-stream ``xs: (G, M, d)`` in which expert ``e``'s rows occupy one
+This is the MoE layer's expert FFN (core/moe.py): tokens arrive as a
+flat expert-sorted stream ``xs: (G, M, d)`` in which expert ``e``'s rows occupy one
 contiguous *block-aligned* segment. Per-expert segment geometry is given
 by ``group_sizes: (G, E)`` — the number of VALID rows per expert — and
 the layout contract (shared with core/moe.py via ``ragged_row_offsets``):
@@ -12,8 +11,7 @@ the layout contract (shared with core/moe.py via ``ragged_row_offsets``):
   ``bm`` and holds at least one block (so every expert owns >= 1 block,
   which keeps the dW grid total and lets empty experts emit zero grads);
 * padded rows (and the tail past the last segment) are all-zero, so they
-  contribute zero forward and backward — exactly the discipline the
-  padded kernels already rely on;
+  contribute zero forward and backward;
 * static buffer size ``M = (ceil(N/bm) + E) * bm`` where ``N`` is the
   assignment count (g * k for token-choice routing) — *independent of
   capacity factor*, unlike ``E * cap``.
@@ -49,36 +47,33 @@ dead run (block 0 dead) falls back to block 0's own tiles, one fetch.
 The static grid shape is unchanged; only the data walk is compacted, so
 HBM read bytes now track the *live* blocks exactly like the FLOPs do
 (see ``kernels.tiling.grouped_walk_fwd_bytes`` for the byte model and
-``benchmarks/roofline.py kernel.grouped_mlp.cf*`` for the ratios vs the
-padded path).
+``benchmarks/roofline.py kernel.grouped_mlp.cf*`` for the ratios vs a
+padded capacity buffer).
 
 Contract note: dead-block rows get ``dx = 0`` — valid because the combine
 step never reads their outputs, so their cotangent is identically zero
 (the ref oracle's autodiff, fed a nonzero cotangent there, would instead
 produce ``act'(0)``-shaped gradients for ungated activations).
 
-Forward: grid (G, nb, nf, nd), d innermost — the same accumulate-then-
-activate-then-accumulate structure as the padded kernel in expert_mlp.py,
-with ``block_expert[g, m]`` replacing the expert grid axis.
+Forward: grid (G, nb, nf, nd), d innermost — accumulate the hidden over
+d tiles, activate, accumulate the output — with ``block_expert[g, m]``
+choosing each row-block's expert weights.
 
 Backward (``grouped_mlp_pallas_vjp``): residuals are the inputs only
 (xs, wi, wg, wo + the int32 block tables); the (bm, f) hidden tensors are
 recomputed in-kernel:
 
-* dx kernel — grid (G, nb, nf, 2*nd), the two-phase d-sweep of
-  expert_mlp's dx kernel (phase 1 re-accumulates a/g/dh, activation VJP
-  at the phase boundary, phase 2 expands da/dg into a persistent
-  (bm, d) f32 dx accumulator).
+* dx kernel — grid (G, nb, nf, 2*nd), a two-phase d-sweep (phase 1
+  re-accumulates a/g/dh, activation VJP at the phase boundary, phase 2
+  expands da/dg into a persistent (bm, d) f32 dx accumulator).
 * dW kernel — grid (G, f/bf_dw, nb), row-blocks innermost. f32 VMEM
   accumulators are zeroed at each expert-segment START (detected from
   the prefetched ``block_expert`` table: block m starts a segment iff
   ``be[m] != be[m-1]``), accumulated across the segment's blocks, and
   flushed at the segment END into *per-group* dW outputs (G, E, d, f),
-  summed over G outside the kernel — the same per-group-then-sum
-  contract the padded path gets from ``vmap`` over groups.
+  summed over G outside the kernel.
 
-See src/repro/kernels/README.md for VMEM budgets and the dispatch
-comparison table.
+See src/repro/kernels/README.md for VMEM budgets.
 """
 from __future__ import annotations
 
@@ -474,11 +469,34 @@ def _grouped_mlp_pallas_tables(
 # ---------------------------------------------------------------------------
 
 
+def _recompute_grads_f_tile(x, dy, wi_t, wg_t, wo_t, act):
+    """One (bm, bf) tile of the hidden-space gradients, from full-d rows.
+
+    Returns (h, da, dg): the post-activation hidden (for dwo) and the
+    pre-activation gradients (for dwi/dwg/dx). dg is None when ungated.
+    """
+    a = jnp.dot(x, wi_t, preferred_element_type=jnp.float32)
+    dh = jax.lax.dot_general(  # dy @ wo_t^T
+        dy, wo_t, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    act_out, act_vjp = jax.vjp(_act_fn(act), a)
+    if wg_t is not None:
+        g = jnp.dot(x, wg_t, preferred_element_type=jnp.float32)
+        h = act_out * g
+        da = act_vjp(dh * g)[0]
+        dg = dh * act_out
+    else:
+        h = act_out
+        da = act_vjp(dh)[0]
+        dg = None
+    return h, da, dg
+
+
 def _dx_kernel(be_ref, bl_ref, x_ref, wi_ref, wg_ref, wo_ref, dy_ref,
                dx_ref, a_acc, g_acc, dh_acc, dx_acc, *,
                act: str, nd: int, nf: int, bd: int):
-    """The two-phase d-sweep of expert_mlp's dx kernel over ragged
-    row-blocks. Phase 1 (t < nd): accumulate a, g, dh over d tiles.
+    """The two-phase d-sweep over ragged row-blocks. Phase 1 (t < nd): accumulate a, g, dh over d tiles.
     Phase boundary (t == nd): activation VJP in place. Phase 2: expand
     da/dg back to d tiles into the persistent (bm, dp) dx scratch."""
     g = pl.program_id(0)
@@ -550,8 +568,6 @@ def _dw_kernel(be_ref, bl_ref, x_ref, wi_ref, wg_ref, wo_ref, dy_ref,
     """Expert-segment walk: zero the f32 accumulators at each segment
     start, fold in one (bm, bf) recomputed hidden tile per live block,
     flush into the per-group dW outputs at the segment end."""
-    from repro.kernels.expert_mlp import _recompute_grads_f_tile
-
     g = pl.program_id(0)
     m = pl.program_id(2)
     e = be_ref[g, m]

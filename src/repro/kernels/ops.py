@@ -9,7 +9,7 @@ Every op takes ``implementation``:
                   mode for validation; any other backend raises. The
                   mode is decided per call (``_interpret``), never at
                   import, so importing this module touches no device.
-                  Grad-enabled: expert FFN and flash attention route
+                  Grad-enabled: grouped_mlp and flash attention route
                   through ``jax.custom_vjp`` wrappers whose backward passes
                   are themselves fused Pallas kernels, so ``jax.grad``
                   through "pallas" never falls back to XLA einsums.
@@ -55,40 +55,10 @@ def _resolve(implementation: str) -> str:
     return implementation
 
 
-def expert_ffn(xe, wi, wg, wo, *, act: str = "silu", implementation="xla"):
-    """Grouped expert FFN. xe: (G, E, cap, d) or (E, cap, d)."""
-    implementation = _resolve(implementation)
-    if implementation == "ref":
-        return _ref.expert_ffn_ref(xe, wi, wg, wo, act=act)
-    if implementation == "pallas":
-        from repro.kernels import expert_mlp
-
-        squeeze = xe.ndim == 3
-        if squeeze:
-            xe = xe[None]
-        G, E, cap, d = xe.shape
-        y = jax.vmap(
-            lambda x: expert_mlp.expert_ffn_pallas_vjp(
-                x, wi, wg, wo, act=act, interpret=_interpret()
-            )
-        )(xe)
-        return y[0] if squeeze else y
-    # XLA path: plain einsums; GSPMD shards them across expert/model axes.
-    from repro.models.layers import activation
-
-    h = jnp.einsum("...ecd,edf->...ecf", xe, wi)
-    if wg is not None:
-        g = jnp.einsum("...ecd,edf->...ecf", xe, wg)
-        h = activation(act)(h) * g
-    else:
-        h = activation(act)(h)
-    return jnp.einsum("...ecf,efd->...ecd", h, wo).astype(xe.dtype)
-
-
 def grouped_mlp(xs, wi, wg, wo, group_sizes, *, act: str = "silu",
                 block: int = 128, implementation="xla"):
-    """Grouped expert FFN over a sorted ragged token buffer — the
-    ``dispatch="sorted"`` hot path (no padded capacity buffer).
+    """Grouped expert FFN over a sorted ragged token buffer — the MoE
+    layer's expert FFN (no padded capacity buffer).
 
     xs: (G, M, d) expert-sorted rows, each expert's segment padded to a
     multiple of ``block`` rows (layout built by core/moe.py with
@@ -281,7 +251,7 @@ def rwkv6(r, k, v, w, u, *, initial_state=None, chunk=64,
     """RWKV-6 WKV. Returns (out, final_state)."""
     if implementation == "auto":
         # No custom-VJP rwkv6 Pallas kernel yet (ROADMAP open item):
-        # unlike expert_ffn / flash_attention, "auto" resolves to the
+        # unlike grouped_mlp / flash_attention, "auto" resolves to the
         # chunked XLA path EVERYWHERE — including TPU — so rwkv6
         # training steps do not get the kernel-fused backward the other
         # hot paths get. Warn once (at trace time) so the perf cliff is

@@ -16,22 +16,6 @@ def _act(name: str):
     return activation(name)
 
 
-def expert_ffn_ref(xe, wi, wg, wo, *, act: str = "silu"):
-    """Grouped expert FFN oracle.
-
-    xe: (..., E, cap, d); wi: (E, d, f); wg: (E, d, f) or None; wo: (E, f, d).
-    """
-    f32 = jnp.float32
-    h = jnp.einsum("...ecd,edf->...ecf", xe.astype(f32), wi.astype(f32))
-    if wg is not None:
-        g = jnp.einsum("...ecd,edf->...ecf", xe.astype(f32), wg.astype(f32))
-        h = _act(act)(h) * g
-    else:
-        h = _act(act)(h)
-    y = jnp.einsum("...ecf,efd->...ecd", h, wo.astype(f32))
-    return y.astype(xe.dtype)
-
-
 def grouped_mlp_ref(xs, wi, wg, wo, group_sizes, *, block: int = 128,
                     act: str = "silu"):
     """Grouped-GEMM (sorted ragged dispatch) oracle.

@@ -67,8 +67,8 @@ def _align128(dim: int) -> int:
 
 def expert_tile_vmem_bytes(bc: int, bf: int, bd: int, d: int) -> int:
     """Worst-case resident f32 bytes across the expert-FFN kernel family
-    (fwd / dx / dW; same model for the padded and the grouped ragged
-    kernels — ``bc`` is the row-block dim, cap-tile or bm).
+    (fwd / dx / dW of kernels/grouped_mlp.py — ``bc`` is the row-block
+    dim, ``bm`` there).
 
     Terms follow kernels/README.md: per-kernel scratch accumulators plus
     the full-d resident output window, plus the (non-full-d) input tiles

@@ -42,7 +42,6 @@ BATCH_AXES = {
 @dataclasses.dataclass(frozen=True)
 class Profile:
     name: str
-    dispatch: str
     ce_chunk: int
     fsdp: bool  # shard weight `embed` dims over `data` (beyond-paper)
     remat: str = "full"
@@ -53,22 +52,21 @@ class Profile:
 
 
 PROFILES = {
-    # Paper-faithful: DP + TP + expert partitioning, GShard one-hot einsum
-    # dispatch, full logits (§A.4 — "expert partitioning ... model
-    # partitioning" only; no weight-FSDP in 2022 T5X MoE).
-    "baseline": Profile("baseline", dispatch="einsum", ce_chunk=0,
-                        fsdp=False),
-    # Beyond-paper: FSDP weights, gather dispatch, chunked CE, head-padding
-    # TP for indivisible head counts (qwen2.5's 40 heads).
-    "optimized": Profile("optimized", dispatch="gather", ce_chunk=2048,
-                         fsdp=True, pad_heads_multiple=16),
+    # Paper-faithful: DP + TP + expert partitioning, full logits (§A.4 —
+    # "expert partitioning ... model partitioning" only; no weight-FSDP
+    # in 2022 T5X MoE).
+    "baseline": Profile("baseline", ce_chunk=0, fsdp=False),
+    # Beyond-paper: FSDP weights, chunked CE, head-padding TP for
+    # indivisible head counts (qwen2.5's 40 heads).
+    "optimized": Profile("optimized", ce_chunk=2048, fsdp=True,
+                         pad_heads_multiple=16),
     # Inference-only weight-stationary layout: no FSDP gathers — expert
     # weights shard (E -> model, F -> data) and stay resident; the second
     # expert matmul all-reduces ACTIVATIONS over `data` instead (far
     # smaller than weights at prefill batch sizes). Dense d_ff shards over
     # model (classic TP). SPerf iteration 2 for collective-bound serving.
     "serve_tp": Profile(
-        "serve_tp", dispatch="gather", ce_chunk=0, fsdp=False,
+        "serve_tp", ce_chunk=0, fsdp=False,
         pad_heads_multiple=16,
         param_overrides={
             "embed": (),
@@ -176,7 +174,6 @@ def build_cell(
     ac_kw = dict(
         compute_dtype="bfloat16",
         remat=prof.remat,
-        dispatch=prof.dispatch,
         ce_chunk=prof.ce_chunk,
         pad_heads_multiple=prof.pad_heads_multiple,
     )

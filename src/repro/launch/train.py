@@ -39,15 +39,8 @@ def main() -> None:
                     help="kernel implementation for MoE expert FFN and "
                          "attention; 'auto' = fused Pallas (fwd + "
                          "custom-VJP bwd) on TPU, XLA einsums on CPU")
-    ap.add_argument("--dispatch", default="gather",
-                    choices=["gather", "einsum", "sorted"],
-                    help="MoE dispatch: 'gather'/'einsum' build the "
-                         "padded (G, E, cap, d) capacity buffer; "
-                         "'sorted' routes via token-sorting into a "
-                         "ragged buffer + grouped-GEMM kernel (FFN "
-                         "FLOPs independent of capacity factor)")
     ap.add_argument("--ep", default="none", choices=["none", "a2a"],
-                    help="expert parallelism for --dispatch sorted: "
+                    help="expert parallelism of the MoE layer: "
                          "'none' = batch-sharded ragged buffer + FSDP "
                          "expert-weight gather (weights move); 'a2a' = "
                          "shard_map expert-parallel all-to-all over the "
@@ -151,10 +144,9 @@ def main() -> None:
 
     sig = PreemptionSignal().install()
     ac = zoo.ApplyCfg(remat=args.remat, moe_impl=args.impl,
-                      attn_impl=args.impl,
-                      dispatch=args.dispatch).resolve()
+                      attn_impl=args.impl).resolve()
     print(f"[train] kernels: moe={ac.moe_impl} attn={ac.attn_impl} "
-          f"dispatch={ac.dispatch} remat={ac.remat}")
+          f"sorted_block={ac.sorted_block} remat={ac.remat}")
     tracker = None
     if args.obs_jsonl:
         from repro.obs import JsonlSink, Tracker

@@ -45,13 +45,15 @@ class ApplyCfg:
     backend at trace time.
     """
 
-    dispatch: str = "gather"  # moe dispatch: gather | einsum | sorted
-    # Row-block alignment of the sorted dispatch's ragged buffer. 128
-    # matches the grouped-GEMM kernel's MXU tiles (training / TPU); the
-    # layout guarantees >= 1 block per expert, so tiny decode batches
-    # want a small block (the serve engine picks 8 on the XLA backend —
-    # E*128 floor rows for a 16-assignment decode batch otherwise).
-    sorted_block: int = 128
+    # The MoE layer has one path, the sorted ragged dispatch; the field
+    # accepts only its name and nothing reads it.
+    dispatch: str = "sorted"
+    # Row-block alignment of the MoE layer's ragged buffer; None lets
+    # resolve() pick it from moe_impl. 128 matches the Pallas grouped-
+    # GEMM kernel's MXU tiles; the layout guarantees >= 1 block per
+    # expert, so the XLA ragged_dot and ref paths take 8 (a 128 block
+    # would pad a 16-assignment decode batch to E*128 rows).
+    sorted_block: Optional[int] = None
     moe_impl: str = "auto"  # auto | xla | pallas | ref
     attn_impl: str = "auto"  # auto | xla | pallas | ref
     mixer_impl: str = "xla"
@@ -69,21 +71,29 @@ class ApplyCfg:
     def cdtype(self):
         return jnp.bfloat16 if self.compute_dtype == "bfloat16" else jnp.float32
 
+    def __post_init__(self):
+        if self.dispatch != "sorted":
+            raise ValueError(
+                f"unknown MoE dispatch {self.dispatch!r}: the MoE layer "
+                "has one path, the sorted ragged dispatch ('sorted')"
+            )
+
     def resolve(self) -> "ApplyCfg":
         """Pin "auto" impls to the backend default (pallas on TPU, xla on
-        CPU). Idempotent; called at every model entry point."""
+        CPU) and an unset ``sorted_block`` to the MoE implementation's
+        block. Idempotent; called at every model entry point."""
         from repro.kernels.ops import default_implementation
 
-        if self.moe_impl != "auto" and self.attn_impl != "auto":
-            return self
-        default = default_implementation()
-        return dataclasses.replace(
-            self,
-            moe_impl=default if self.moe_impl == "auto" else self.moe_impl,
-            attn_impl=(
-                default if self.attn_impl == "auto" else self.attn_impl
-            ),
-        )
+        moe_impl, attn_impl = self.moe_impl, self.attn_impl
+        if "auto" in (moe_impl, attn_impl):
+            default = default_implementation()
+            moe_impl = default if moe_impl == "auto" else moe_impl
+            attn_impl = default if attn_impl == "auto" else attn_impl
+        block = self.sorted_block
+        if block is None:
+            block = 128 if moe_impl == "pallas" else 8
+        return dataclasses.replace(self, moe_impl=moe_impl,
+                                   attn_impl=attn_impl, sorted_block=block)
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +195,7 @@ def _encode(params, batch, cfg: ArchConfig, ac: ApplyCfg, ctx):
         params["encoder"], x, cfg, stk.layer_descs(cfg, stack="encoder"),
         mode="train", causal=False,
         router_kind=stk.stack_router_kind(cfg, stack="encoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -216,7 +226,7 @@ def forward_train(
             stk.layer_descs(cfg, stack="decoder"),
             mode="train", causal=False,
             router_kind=stk.stack_router_kind(cfg, stack="encoder"),
-            dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+            sorted_block=ac.sorted_block,
             moe_impl=ac.moe_impl,
             attn_impl=ac.attn_impl,
             mixer_impl=ac.mixer_impl, ctx=ctx, remat=ac.remat,
@@ -240,7 +250,7 @@ def forward_train(
         params["stack"], x, cfg, stk.layer_descs(cfg, stack="decoder"),
         enc=enc, mode="train", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -427,7 +437,7 @@ def paged_prefill(
         block_tables=block_table,
         mode="prefill", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -477,7 +487,7 @@ def paged_decode_step(
         token_mask=live[:, None],
         mode="decode", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -523,7 +533,7 @@ def paged_mixed_step(
     flash-decode kernel, chunk rows via the paged prefill kernel
     (models/attention mixed mode). MoE routes with dead rows masked, so
     expert FLOPs track live tokens: decode rows ride the live-token
-    sorted dispatch, chunk rows keep expert work dense.
+    ragged dispatch, chunk rows keep expert work dense.
 
     Returns ``(cache, logits (B + NC, V))``: rows [:B] are the decode
     slots' next-token logits, rows [B:] each chunk lane's logits at its
@@ -570,7 +580,7 @@ def paged_mixed_step(
         ),
         mode="decode", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -675,7 +685,7 @@ def paged_verify_step(
         ),
         mode="decode", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -728,7 +738,7 @@ def prefill(
         enc=enc, cache=cache["stack"], cache_index=jnp.asarray(0, jnp.int32),
         mode="prefill", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,
@@ -764,7 +774,7 @@ def decode_step(
         cache=cache["stack"], cache_index=cache_index,
         mode="decode", causal=True,
         router_kind=stk.stack_router_kind(cfg, stack="decoder"),
-        dispatch=ac.dispatch, sorted_block=ac.sorted_block,
+        sorted_block=ac.sorted_block,
         moe_impl=ac.moe_impl,
         attn_impl=ac.attn_impl,
         mixer_impl=ac.mixer_impl,

@@ -189,7 +189,6 @@ def layer_apply(
     mode: str = "train",
     causal: bool = True,
     router_kind: str = "top_k",
-    dispatch: str = "gather",
     sorted_block: int = 128,
     moe_impl: str = "xla",
     mixer_impl: str = "xla",
@@ -248,7 +247,6 @@ def layer_apply(
         y, m = moe_apply(
             p["ffn"], h, cfg, cfg.moe,
             router_kind=router_kind,
-            dispatch=dispatch,
             sorted_block=sorted_block,
             ctx=ctx,
             implementation=moe_impl,
@@ -406,7 +404,6 @@ def stack_apply(
     mode: str = "train",
     causal: bool = True,
     router_kind: str = "top_k",
-    dispatch: str = "gather",
     sorted_block: int = 128,
     moe_impl: str = "xla",
     mixer_impl: str = "xla",
@@ -446,7 +443,6 @@ def stack_apply(
                     mode=mode,
                     causal=causal,
                     router_kind=router_kind,
-                    dispatch=dispatch,
                     sorted_block=sorted_block,
                     moe_impl=moe_impl,
                     mixer_impl=mixer_impl,
@@ -471,8 +467,8 @@ def stack_apply(
         elif remat == "moe":
             # MoE-block-boundary remat: save ONLY the combined MoE layer
             # outputs (tagged `moe_block` in core/moe.py). Everything else
-            # in the layer — attention activations, dispatched (G, E, cap,
-            # d) buffers, router tensors — is recomputed in the backward,
+            # in the layer — attention activations, the ragged expert
+            # buffers, router tensors — is recomputed in the backward,
             # so the step's memory high-water mark is set by the Pallas
             # VJP residuals (kernel inputs), not full activations.
             body = jax.checkpoint(

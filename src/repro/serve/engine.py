@@ -182,20 +182,6 @@ class ServeEngine:
         # would be one shared mutable instance across every engine.
         # (ApplyCfg is frozen, so its shared default is harmless.)
         sc = ServeConfig() if sc is None else sc
-        if sc.paged and cfg.moe is not None and ac.dispatch == "gather":
-            # The serving hot path: live-token ragged dispatch instead of
-            # the padded capacity buffer ("gather" is only ApplyCfg's
-            # generic default — pass einsum/gather explicitly via a
-            # non-default ac to override). The ragged row block follows
-            # the backend: the TPU grouped-GEMM kernel needs MXU-aligned
-            # 128 blocks (its compacted walk already skips dead blocks),
-            # while the XLA ragged_dot fallback wants the f32 sublane
-            # floor — a 128 block would pad a 16-assignment decode batch
-            # to E*128 rows.
-            blk = 128 if ac.resolve().moe_impl == "pallas" else 8
-            ac = dataclasses.replace(
-                ac, dispatch="sorted", sorted_block=blk
-            )
         if sc.paged and sc.admission not in ("chunked", "prefill_on_join"):
             raise ValueError(
                 f"unknown admission mode {sc.admission!r} "

@@ -52,7 +52,6 @@ ACT_RULES: Rules = {
     "head_dim": (),
     "mlp": (("model",),),
     "expert": (("model",),),
-    "cap": (),
     "vocab": (("model",),),
     # KV caches: shard the time dim over `model` (sequence parallelism for
     # decode); falls back to replication for short caches.

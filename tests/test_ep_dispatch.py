@@ -1,7 +1,6 @@
 """Expert-parallel sorted dispatch parity on a forced multi-device CPU
 mesh: sorted-EP (shard_map ragged all-to-all, core/ep.py) vs the
-single-device sorted path vs the padded gather path — outputs AND
-gradients, all three routers, uneven expert load and empty local
+single-device sorted path — outputs AND gradients, all three routers, uneven expert load and empty local
 experts.
 
 Needs >= 8 CPU devices:
@@ -60,9 +59,9 @@ def setup():
     return cfg, vals, x, ctx
 
 
-def _apply(vals, x, cfg, moe, router, dispatch, ctx, impl="xla"):
+def _apply(vals, x, cfg, moe, router, ctx, impl="xla"):
     return moe_apply(
-        vals, x, cfg, moe, router_kind=router, dispatch=dispatch,
+        vals, x, cfg, moe, router_kind=router,
         ctx=ctx, implementation=impl, sorted_block=8,
     )
 
@@ -70,16 +69,12 @@ def _apply(vals, x, cfg, moe, router, dispatch, ctx, impl="xla"):
 @pytest.mark.parametrize("router", ROUTERS)
 def test_ep_matches_single_device_sorted_and_gather(setup, router):
     """Sorted-EP over the 8-device mesh reproduces the single-device
-    sorted path and the padded gather path exactly (no EP drops)."""
+    sorted path exactly (no EP drops)."""
     cfg, vals, x, ctx = setup
-    y_ep, m_ep = _apply(vals, x, cfg, cfg.moe, router, "sorted", ctx)
-    y_1d, m_1d = _apply(vals, x, cfg, cfg.moe, router, "sorted", None)
-    y_g, _ = _apply(vals, x, cfg, cfg.moe, router, "gather", None)
+    y_ep, m_ep = _apply(vals, x, cfg, cfg.moe, router, ctx)
+    y_1d, m_1d = _apply(vals, x, cfg, cfg.moe, router, None)
     np.testing.assert_allclose(
         np.asarray(y_ep), np.asarray(y_1d), rtol=1e-4, atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(y_ep), np.asarray(y_g), rtol=1e-4, atol=1e-5
     )
     assert float(m_ep["ep_overflow_frac"]) == 0.0
     assert float(m_ep["dropped_frac"]) == float(m_1d["dropped_frac"])
@@ -93,7 +88,7 @@ def test_ep_gradients_match_single_device_sorted(setup, router):
     cfg, vals, x, ctx = setup
 
     def loss(v, xv, ctx_):
-        y, m = _apply(v, xv, cfg, cfg.moe, router, "sorted", ctx_)
+        y, m = _apply(v, xv, cfg, cfg.moe, router, ctx_)
         return jnp.sum(y ** 2) + m["aux_loss"]
 
     g_ep = jax.grad(loss, argnums=(0, 1))(vals, x, ctx)
@@ -121,7 +116,7 @@ def test_ep_empty_local_experts(setup, router):
     vals = dict(vals, router={"w": jnp.asarray(w)})
 
     def loss(v, ctx_):
-        y, m = _apply(v, x, cfg, cfg.moe, router, "sorted", ctx_)
+        y, m = _apply(v, x, cfg, cfg.moe, router, ctx_)
         return jnp.sum(y ** 2), y
 
     (l_ep, y_ep), g_ep = jax.value_and_grad(loss, has_aux=True)(vals, ctx)
@@ -150,8 +145,8 @@ def test_ep_uneven_load(setup, router):
     w = np.asarray(vals["router"]["w"]).copy()
     w[:, 0] += 3.0  # expert 0 draws most assignments
     vals = dict(vals, router={"w": jnp.asarray(w)})
-    y_ep, m_ep = _apply(vals, x, cfg, moe, router, "sorted", ctx)
-    y_1d, _ = _apply(vals, x, cfg, moe, router, "sorted", None)
+    y_ep, m_ep = _apply(vals, x, cfg, moe, router, ctx)
+    y_1d, _ = _apply(vals, x, cfg, moe, router, None)
     np.testing.assert_allclose(
         np.asarray(y_ep), np.asarray(y_1d), rtol=1e-4, atol=1e-5
     )
@@ -168,7 +163,7 @@ def test_ep_budget_overflow_drops(setup):
     w = np.asarray(vals["router"]["w"]).copy()
     w[:, 0] += 5.0  # pile onto one peer to force overflow
     vals = dict(vals, router={"w": jnp.asarray(w)})
-    y, m = _apply(vals, x, cfg, moe, "top_k", "sorted", ctx)
+    y, m = _apply(vals, x, cfg, moe, "top_k", ctx)
     assert bool(jnp.isfinite(y).all())
     assert float(m["ep_overflow_frac"]) > 0.0
 
@@ -183,7 +178,7 @@ def test_ep_pallas_kernel_through_shard_map(setup, router):
     cfg, vals, x, ctx = setup
 
     def loss(v, impl):
-        y, m = _apply(v, x, cfg, cfg.moe, router, "sorted", ctx, impl)
+        y, m = _apply(v, x, cfg, cfg.moe, router, ctx, impl)
         return jnp.sum(y ** 2), y
 
     (l_p, y_p), g_p = jax.value_and_grad(loss, has_aux=True)(
@@ -211,15 +206,15 @@ def test_ep_group_count_divisibility_error(setup):
         jax.random.PRNGKey(2), (3, 16, cfg.d_model)
     )  # 48 tokens -> G=3
     with pytest.raises(ValueError, match="divisible"):
-        _apply(vals, x_bad, cfg, cfg.moe, "top_k", "sorted", ctx)
+        _apply(vals, x_bad, cfg, cfg.moe, "top_k", ctx)
 
 
 def test_ep_fallback_without_capable_mesh(setup):
     """ep='a2a' with ctx=None (or an EP-incapable mesh) falls back to
     the single-device sorted path — same outputs, no error."""
     cfg, vals, x, _ = setup
-    y1, m1 = _apply(vals, x, cfg, cfg.moe, "top_k", "sorted", None)
+    y1, m1 = _apply(vals, x, cfg, cfg.moe, "top_k", None)
     moe_off = dataclasses.replace(cfg.moe, ep="none")
-    y2, _ = _apply(vals, x, cfg, moe_off, "top_k", "sorted", None)
+    y2, _ = _apply(vals, x, cfg, moe_off, "top_k", None)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2))
     assert float(m1["ep_overflow_frac"]) == 0.0

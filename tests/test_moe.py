@@ -1,4 +1,5 @@
-"""MoE layer tests: dispatch-path equivalence, gradients, grouping."""
+"""MoE layer tests: the sorted ragged dispatch against a plain dense
+reference (tests/_moe_ref.py), gradients, grouping."""
 import dataclasses
 import re
 
@@ -7,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _moe_ref import moe_reference
 from repro.configs import MoECfg, get_reduced
 from repro.core.moe import moe_apply, moe_init
 from repro.models import param as pm
@@ -19,19 +21,6 @@ def setup():
     vals, axes = pm.split(p)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
     return cfg, vals, x
-
-
-@pytest.mark.parametrize("router", ["top_k", "expert_choice", "switch"])
-def test_gather_equals_einsum(setup, router):
-    cfg, vals, x = setup
-    y1, m1 = moe_apply(vals, x, cfg, cfg.moe, router_kind=router,
-                       dispatch="gather")
-    y2, m2 = moe_apply(vals, x, cfg, cfg.moe, router_kind=router,
-                       dispatch="einsum")
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
-    np.testing.assert_allclose(
-        float(m1["dropped_frac"]), float(m2["dropped_frac"])
-    )
 
 
 def test_group_padding(setup):
@@ -82,35 +71,69 @@ def test_moe_capacity_increase_reduces_drops(setup):
 ROUTERS = ["top_k", "expert_choice", "switch"]
 
 
+@pytest.mark.parametrize("block", [8, 128])
 @pytest.mark.parametrize("router", ROUTERS)
-def test_sorted_matches_gather_and_einsum(setup, router):
-    """dispatch="sorted" (ragged grouped GEMM) reproduces the padded
-    paths' outputs for every router."""
+def test_sorted_matches_reference(setup, router, block):
+    """The sorted ragged dispatch reproduces the dense reference's
+    outputs and drops for every router, at a small block and at the
+    chip's 128-row block."""
     cfg, vals, x = setup
-    ys = {
-        d: moe_apply(vals, x, cfg, cfg.moe, router_kind=router,
-                     dispatch=d, sorted_block=8)[0]
-        for d in ("sorted", "gather", "einsum")
-    }
-    for d in ("gather", "einsum"):
-        np.testing.assert_allclose(
-            np.asarray(ys["sorted"]), np.asarray(ys[d]),
-            rtol=1e-4, atol=1e-5,
-        )
+    y, m = moe_apply(vals, x, cfg, cfg.moe, router_kind=router,
+                     sorted_block=block)
+    want, m_ref = moe_reference(vals, x, cfg, cfg.moe, router)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert float(m["dropped_frac"]) == float(m_ref["dropped_frac"])
+
+
+@pytest.mark.parametrize("router", ["top_k", "expert_choice"])
+def test_moe_reference_matches_token_loop(router):
+    """The reference itself, at a tiny size, against an explicit loop
+    over each token's kept assignments and its experts' FFNs."""
+    from repro.core import routing as R
+    from repro.models.layers import activation
+
+    cfg = get_reduced("grok-1-314b")
+    moe = MoECfg(num_experts=4, router=router, top_k=2, group_size=8,
+                 capacity_factor=0.75)
+    vals, _ = pm.split(moe_init(jax.random.PRNGKey(5), cfg, moe))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, cfg.d_model))
+    got, _ = moe_reference(vals, x, cfg, moe, router)
+
+    xg = np.asarray(x, np.float64)  # group_size 8: one group per row
+    logits = jnp.einsum("Ggd,de->Gge", x, vals["router"]["w"],
+                        preferred_element_type=jnp.float32)
+    r = R.route(logits, moe, router)
+    tok, eid, w = (np.asarray(a) for a in R.assignment_stream(r, 4, 8))
+    ex = {k: np.asarray(v, np.float64) for k, v in vals["experts"].items()}
+    act = activation(cfg.act)
+    want = np.zeros_like(xg)
+    for G in range(xg.shape[0]):
+        for t, e, wt in zip(tok[G], eid[G], w[G]):
+            if t >= 8 or e >= 4:
+                continue  # dropped assignment
+            h = np.asarray(act(jnp.asarray(xg[G, t] @ ex["wi"][e])),
+                           np.float64)
+            if "wg" in ex:
+                h = h * (xg[G, t] @ ex["wg"][e])
+            want[G, t] += wt * (h @ ex["wo"][e])
+    if router == "top_k":
+        assert (eid >= 4).any()  # capacity 0.75 drops assignments
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("router", ROUTERS)
-def test_sorted_matches_gather_dropped_tokens(setup, router):
+def test_sorted_matches_reference_dropped_tokens(setup, router):
     """Parity under capacity pressure (capacity_factor < 1): the sorted
     path must drop exactly the assignments the routers' capacity
     bookkeeping drops."""
     cfg, vals, _ = setup
     moe = dataclasses.replace(cfg.moe, capacity_factor=0.5)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.d_model))
-    y1, m1 = moe_apply(vals, x, cfg, moe, router_kind=router,
-                       dispatch="gather")
+    y1, m1 = moe_reference(vals, x, cfg, moe, router)
     y2, m2 = moe_apply(vals, x, cfg, moe, router_kind=router,
-                       dispatch="sorted", sorted_block=8)
+                       sorted_block=8)
     assert float(m1["dropped_frac"]) == float(m2["dropped_frac"])
     if router != "expert_choice":
         assert float(m1["dropped_frac"]) > 0.0  # pressure actually drops
@@ -120,16 +143,15 @@ def test_sorted_matches_gather_dropped_tokens(setup, router):
 
 
 @pytest.mark.parametrize("router", ROUTERS)
-def test_sorted_pad_tokens(setup, router):
+def test_sorted_pad_tokens_reference(setup, router):
     """Group padding (group_size does not divide the token count): padded
-    token rows round-trip the sorted path exactly like the gather path."""
+    token rows round-trip the sorted path as they do the reference."""
     cfg, vals, _ = setup
     moe = dataclasses.replace(cfg.moe, group_size=24)  # 64 tokens -> pad
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, cfg.d_model))
-    y1, _ = moe_apply(vals, x, cfg, moe, router_kind=router,
-                      dispatch="gather")
+    y1, _ = moe_reference(vals, x, cfg, moe, router)
     y2, _ = moe_apply(vals, x, cfg, moe, router_kind=router,
-                      dispatch="sorted", sorted_block=8)
+                      sorted_block=8)
     assert y2.shape == x.shape
     assert bool(jnp.isfinite(y2).all())
     np.testing.assert_allclose(
@@ -147,26 +169,27 @@ TOKEN_CHOICE = ["top_k", "switch"]
     + [pytest.param(c, r, True, id=f"{c}-{r}-dead_tokens")
        for r in TOKEN_CHOICE for c in (0.5, 2.0)],
 )
-def test_sorted_gradients_match_gather(setup, router, capacity_factor,
-                                       dead_tokens):
+def test_sorted_gradients_match_reference(setup, router, capacity_factor,
+                                          dead_tokens):
     """Full jax.grad parity (router + expert weights + input) between the
-    sorted and gather dispatches, with and without capacity drops, and
-    output parity. With a ``token_mask`` of dead tokens (token-choice
+    sorted dispatch and the reference, with and without capacity drops,
+    and output parity. With a ``token_mask`` of dead tokens (token-choice
     routers only), dead tokens claim no rows and give zero outputs."""
     cfg, vals, x = setup
     moe = dataclasses.replace(cfg.moe, capacity_factor=capacity_factor)
     mask = (jax.random.bernoulli(jax.random.PRNGKey(4), 0.7, x.shape[:-1])
             if dead_tokens else None)
 
-    def loss(v, xv, dispatch):
-        y, m = moe_apply(v, xv, cfg, moe, router_kind=router,
-                         dispatch=dispatch, sorted_block=8,
-                         token_mask=mask)
+    def loss(v, xv, layer):
+        y, m = layer(v, xv)
         return jnp.sum(y ** 2) + m["aux_loss"], y
 
     grad = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
-    (_, y1), g1 = grad(vals, x, "gather")
-    (_, y2), g2 = grad(vals, x, "sorted")
+    (_, y1), g1 = grad(vals, x, lambda v, xv: moe_reference(
+        v, xv, cfg, moe, router, token_mask=mask))
+    (_, y2), g2 = grad(vals, x, lambda v, xv: moe_apply(
+        v, xv, cfg, moe, router_kind=router, sorted_block=8,
+        token_mask=mask))
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=1e-4, atol=1e-5)
     if dead_tokens:
@@ -182,23 +205,22 @@ def test_sorted_gradients_match_gather(setup, router, capacity_factor,
 
 
 @pytest.mark.parametrize("router", ROUTERS)
-def test_sorted_pallas_impl_matches_gather(setup, router):
+def test_sorted_pallas_impl_matches_reference(setup, router):
     """The Pallas grouped-GEMM kernel (interpret mode on CPU) through the
-    full moe_apply sorted path: outputs AND gradients match the gather
-    path at rtol 1e-4 for every router."""
+    full moe_apply sorted path: outputs AND gradients match the
+    reference at rtol 1e-4 for every router."""
     cfg, vals, x = setup
 
-    def loss(v, dispatch, impl):
-        y, m = moe_apply(v, x, cfg, cfg.moe, router_kind=router,
-                         dispatch=dispatch, sorted_block=8,
-                         implementation=impl)
+    def loss(v, layer):
+        y, m = layer(v)
         return jnp.sum(y ** 2) + m["aux_loss"], y
 
     (l1, y1), g1 = jax.value_and_grad(loss, has_aux=True)(
-        vals, "gather", "xla"
+        vals, lambda v: moe_reference(v, x, cfg, cfg.moe, router)
     )
     (l2, y2), g2 = jax.value_and_grad(loss, has_aux=True)(
-        vals, "sorted", "pallas"
+        vals, lambda v: moe_apply(v, x, cfg, cfg.moe, router_kind=router,
+                                  sorted_block=8, implementation="pallas")
     )
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=1e-4, atol=1e-5)
@@ -292,11 +314,11 @@ def test_sorted_token_choice_path_has_no_scatter(setup, router):
 
     def fwd(v, xv):
         return moe_apply(v, xv, cfg, cfg.moe, router_kind=router,
-                         dispatch="sorted", sorted_block=8)[0]
+                         sorted_block=8)[0]
 
     def loss(v, xv):
         y, m = moe_apply(v, xv, cfg, cfg.moe, router_kind=router,
-                         dispatch="sorted", sorted_block=8)
+                         sorted_block=8)
         return jnp.sum(y ** 2) + m["aux_loss"]
 
     lowered = [jax.jit(f).lower(vals, x)
@@ -309,3 +331,35 @@ def test_sorted_token_choice_path_has_no_scatter(setup, router):
         assert [lo.as_text().count("stablehlo.scatter")
                 for lo in lowered] == [0, 0]
         assert ["scatter(" in text for text in compiled] == [False, False]
+
+
+# ---------------------------------------------------------------------------
+# ApplyCfg: one dispatch, its block resolved with the implementation
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kw, impl, block", [
+    pytest.param({"moe_impl": "pallas", "attn_impl": "xla"}, "pallas", 128,
+                 id="pallas"),
+    pytest.param({}, "xla", 8, id="xla"),  # "auto" on the CPU
+    pytest.param({"moe_impl": "xla", "attn_impl": "xla",
+                  "sorted_block": 128}, "xla", 128, id="explicit"),
+])
+def test_applycfg_sorted_block_follows_implementation(kw, impl, block):
+    """``resolve()`` gives an unset ``sorted_block`` the Pallas kernel's
+    128-row block or the XLA path's 8, also when both implementations
+    are already pinned; an explicit block wins. Resolving again changes
+    nothing."""
+    from repro.models.model_zoo import ApplyCfg
+
+    ac = ApplyCfg(**kw).resolve()
+    assert (ac.moe_impl, ac.sorted_block) == (impl, block)
+    assert ac.resolve() == ac
+
+
+def test_applycfg_accepts_only_sorted_dispatch():
+    from repro.models.model_zoo import ApplyCfg
+
+    assert ApplyCfg(dispatch="sorted").dispatch == "sorted"
+    for name in ("gather", "einsum"):
+        with pytest.raises(ValueError, match="'sorted'"):
+            ApplyCfg(dispatch=name)

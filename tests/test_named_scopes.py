@@ -2,7 +2,7 @@
 reach the compiled HLO: the train step and the paged mixed serve step,
 compiled at a tiny size, carry every scope in the ``op_name`` metadata
 of their instructions — the train step's forward, backward and
-recomputed forward alike, through every MoE dispatch path."""
+recomputed forward alike, under every router."""
 import dataclasses
 import functools
 import re
@@ -50,15 +50,23 @@ def _sds(tree):
                         tree)
 
 
-@pytest.mark.parametrize("dispatch", ["sorted", "gather", "einsum"])
-def test_train_step_hlo_names_every_scope(cfg, dispatch):
+@pytest.mark.parametrize("router", ["top_k", "switch", "expert_choice"])
+def test_train_step_hlo_names_every_scope(cfg, router):
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    if router == "expert_choice":
+        # Decoders route Top-K (paper §3.1): Expert Choice runs in the
+        # encoder of the upcycled T5.
+        cfg = get_reduced("t5-base-upcycled")
+        batch = {"enc_tokens": tok, "dec_tokens": tok, "targets": tok}
+    else:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, router=router))
+        batch = {"tokens": tok, "targets": tok}
     opt = adafactor(constant(1e-3))
-    ac = zoo.ApplyCfg(dispatch=dispatch, sorted_block=8, remat="full")
+    ac = zoo.ApplyCfg(sorted_block=8, remat="full")
     step = jax.jit(make_train_step(cfg, opt, ac=ac))
     state = jax.eval_shape(
         lambda: init_train_state(jax.random.PRNGKey(0), cfg, opt))
-    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
-    batch = {"tokens": tok, "targets": tok}
     text = step.lower(state, batch, jax.ShapeDtypeStruct((), jnp.float32)
                       ).compile().as_text()
     paths = _paths(text)
@@ -70,13 +78,14 @@ def test_train_step_hlo_names_every_scope(cfg, dispatch):
     rec = set().union(*(_scopes(p) for p in paths
                         if "rematted_computation" in p))
     assert {"moe.route", "moe.dispatch", "moe.combine"} <= bwd
-    if dispatch == "sorted":
-        # The token-choice sorted combine's VJP keeps only its inputs,
-        # so the recomputed forward has no combine op left to run.
+    if router == "expert_choice":
+        # Expert Choice's scatter-add combine is recomputed.
+        assert MOE <= rec, MOE - rec
+    else:
+        # The token-choice combine's VJP keeps only its inputs, so the
+        # recomputed forward has no combine op left to run.
         assert MOE - {"moe.combine"} <= rec, MOE - rec
         assert "moe.combine" not in rec
-    else:
-        assert MOE <= rec, MOE - rec
 
 
 def test_mixed_serve_step_hlo_names_every_scope(cfg):
@@ -88,7 +97,7 @@ def test_mixed_serve_step_hlo_names_every_scope(cfg):
     cache = _sds(zoo.init_paged_serve_cache(cfg, blocks, bs,
                                             dtype=jnp.float32))
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-    ac = zoo.ApplyCfg(dispatch="sorted", sorted_block=8)
+    ac = zoo.ApplyCfg(sorted_block=8)
     step = jax.jit(functools.partial(zoo.paged_mixed_step, cfg=cfg, ac=ac))
     text = step.lower(params, i32((B, 1)), i32((NC, C)), cache,
                       i32((B, nb)), i32((B,)), i32((NC, nb)), i32((NC,)),

@@ -279,7 +279,7 @@ def test_paged_prefill_decode_match_train_forward(arch):
     sp = -(-S // BS) * BS
     tp = np.zeros((1, sp), np.int32)
     tp[0, :S] = np.asarray(toks[0, :S])
-    ac = zoo.ApplyCfg(dispatch="sorted")
+    ac = zoo.ApplyCfg()
     cache, lg = zoo.paged_prefill(
         vals, jnp.asarray(tp), cache, bt, jnp.asarray(S, jnp.int32),
         cfg, ac=ac,
@@ -316,8 +316,7 @@ def test_paged_decode_step_pallas_matches_xla():
     )
     outs = {}
     for impl in ("xla", "pallas"):
-        ac = zoo.ApplyCfg(dispatch="sorted", attn_impl=impl,
-                          moe_impl="xla")
+        ac = zoo.ApplyCfg(attn_impl=impl, moe_impl="xla")
         c, _ = zoo.paged_prefill(
             vals, jnp.asarray(toks), cache, bt,
             jnp.asarray(S, jnp.int32), cfg, ac=ac,

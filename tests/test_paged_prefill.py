@@ -312,7 +312,7 @@ def test_mixed_step_matches_train_forward(arch, C):
     B, NC = 2, 2
     cache = zoo.init_paged_serve_cache(cfg, 1 + nb, BS, dtype=jnp.float32)
     bt = jnp.asarray([[3, 1, 4, 2]], jnp.int32)
-    ac = zoo.ApplyCfg(dispatch="sorted")
+    ac = zoo.ApplyCfg()
     prompt = list(np.asarray(toks[0, :S]))
     cache, lg = _mixed_prefill(vals, cfg, cache, prompt, bt,
                                B=B, NC=NC, C=C, ac=ac)
@@ -356,8 +356,7 @@ def test_mixed_step_pallas_matches_xla():
     prompt1 = list(range(60, 68))  # 8-token chunk, prefilling slot
     outs = {}
     for impl in ("xla", "pallas"):
-        ac = zoo.ApplyCfg(dispatch="sorted", attn_impl=impl,
-                          moe_impl="xla")
+        ac = zoo.ApplyCfg(attn_impl=impl, moe_impl="xla")
         cache = zoo.init_paged_serve_cache(cfg, 7, BS, dtype=jnp.float32)
         cache, _ = _mixed_prefill(
             vals, cfg, cache, prompt0, jnp.asarray(bt0[None]),

@@ -308,23 +308,23 @@ def test_moe_token_mask_drops_dead_tokens():
     vals, _ = pm.split(params)
     x = jax.random.normal(jax.random.PRNGKey(1), (6, 1, cfg.d_model))
     mask = jnp.asarray([1, 0, 1, 1, 0, 1], bool)[:, None]
-    for dispatch in ("sorted", "gather", "einsum"):
+    for block in (8, 128):
         y_all, _ = moe_apply(
-            vals, x, cfg, moe, router_kind="top_k", dispatch=dispatch
+            vals, x, cfg, moe, router_kind="top_k", sorted_block=block
         )
         y_m, mets = moe_apply(
-            vals, x, cfg, moe, router_kind="top_k", dispatch=dispatch,
+            vals, x, cfg, moe, router_kind="top_k", sorted_block=block,
             token_mask=mask,
         )
         np.testing.assert_allclose(
             np.asarray(y_m[mask[:, 0]]),
             np.asarray(y_all[mask[:, 0]]),
-            atol=1e-6, rtol=1e-6, err_msg=dispatch,
+            atol=1e-6, rtol=1e-6, err_msg=f"block {block}",
         )
-        assert float(jnp.abs(y_m[~mask[:, 0]]).max()) == 0.0, dispatch
+        assert float(jnp.abs(y_m[~mask[:, 0]]).max()) == 0.0, block
         # metrics normalize over live tokens: dropless => 0 dropped,
         # even with 2 of 6 slots dead
-        assert float(mets["dropped_frac"]) == 0.0, dispatch
+        assert float(mets["dropped_frac"]) == 0.0, block
 
 
 def test_moe_token_mask_shrinks_grouped_rows():

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.decode_attention import paged_decode_attention_pallas
-from repro.kernels.expert_mlp import expert_ffn_pallas_vjp
 from repro.kernels.flash_attention import flash_attention_pallas_vjp
 from repro.kernels.grouped_mlp import (
     grouped_mlp_pallas_vjp,
@@ -117,24 +116,6 @@ def test_grouped_mlp_forward(shape, dims):
 @GROUPED_SHAPES
 def test_grouped_mlp_backward(shape, dims):
     _compiled_kernel(_grad(_grouped, 4), *_grouped_args(shape, dims))
-
-
-def _expert(xe, wi, wg, wo):
-    return expert_ffn_pallas_vjp(xe, wi, wg, wo, interpret=False)
-
-
-def _expert_args(shape):
-    cap = B * S * TOP_K // E  # one routing group, capacity factor 1
-    return (shape((E, cap, D)), shape((E, D, F)), shape((E, D, F)),
-            shape((E, F, D)))
-
-
-def test_expert_mlp_forward(shape):
-    _compiled_kernel(_expert, *_expert_args(shape))
-
-
-def test_expert_mlp_backward(shape):
-    _compiled_kernel(_grad(_expert, 4), *_expert_args(shape))
 
 
 BS, NB = 16, 4  # serve block size; blocks per slot (max_len 64)

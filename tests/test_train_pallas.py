@@ -1,7 +1,7 @@
 """End-to-end training through the Pallas kernels (interpret on CPU).
 
 Acceptance: a full train_step under implementation="pallas" runs through
-the custom-VJP kernels — expert FFN and flash attention forward AND
+the custom-VJP kernels — grouped expert FFN and flash attention forward AND
 backward — without falling back to XLA einsums, and matches the XLA step.
 """
 import jax
@@ -73,12 +73,12 @@ def test_applycfg_auto_resolves_to_backend_default():
         assert ac.moe_impl == "xla"
 
 
-def test_train_step_sorted_dispatch_matches_gather(setup):
-    """A full train_step through dispatch="sorted" (ragged grouped-GEMM
-    path, XLA ragged_dot on CPU) matches the padded gather dispatch."""
+def test_train_step_sorted_dispatch_matches_ref(setup):
+    """A full train_step through the sorted dispatch's XLA ragged_dot
+    path matches the same step on the one-hot ``ref`` expert FFN."""
     cfg, batch = setup
-    _, m_s = _one_step(cfg, batch, zoo.ApplyCfg(dispatch="sorted"))
-    _, m_g = _one_step(cfg, batch, zoo.ApplyCfg(dispatch="gather"))
+    _, m_s = _one_step(cfg, batch, zoo.ApplyCfg(moe_impl="xla"))
+    _, m_g = _one_step(cfg, batch, zoo.ApplyCfg(moe_impl="ref"))
     assert np.isfinite(float(m_s["loss"]))
     np.testing.assert_allclose(
         float(m_s["loss"]), float(m_g["loss"]), rtol=1e-5
@@ -89,15 +89,15 @@ def test_train_step_sorted_dispatch_matches_gather(setup):
 
 
 def test_train_step_sorted_dispatch_pallas_kernels(setup):
-    """dispatch="sorted" + implementation="pallas": the grouped-GEMM
-    custom-VJP kernels (interpret mode on CPU) carry the train step."""
+    """implementation="pallas": the grouped-GEMM custom-VJP kernels
+    (interpret mode on CPU) carry the train step and match the one-hot
+    ``ref`` expert FFN."""
     cfg, batch = setup
     _, m_p = _one_step(
         cfg, batch,
-        zoo.ApplyCfg(dispatch="sorted", moe_impl="pallas",
-                     attn_impl="xla"),
+        zoo.ApplyCfg(moe_impl="pallas", attn_impl="xla", sorted_block=8),
     )
-    _, m_x = _one_step(cfg, batch, zoo.ApplyCfg(dispatch="gather"))
+    _, m_x = _one_step(cfg, batch, zoo.ApplyCfg(moe_impl="ref"))
     np.testing.assert_allclose(
         float(m_p["loss"]), float(m_x["loss"]), rtol=1e-5
     )
